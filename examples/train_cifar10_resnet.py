@@ -199,7 +199,9 @@ def parse_args(argv=None):
                    help="conv A-factor statistics kernel: pallas = fused "
                         "patch-covariance Pallas kernel (no im2col patch "
                         "tensor, enables large batches; docs/PERF.md), dense "
-                        "= im2col oracle, auto = pallas on TPU else dense")
+                        "= im2col oracle, auto = dense (the Pallas kernel is "
+                        "opt-in: the v5e compiler refuses it at ResNet-50 "
+                        "shapes, docs/PERF.md)")
     p.add_argument("--apply-kernel", default="auto",
                    choices=["auto", "pallas", "dense"],
                    help="preconditioned-update apply path: pallas = one "
@@ -207,8 +209,9 @@ def parse_args(argv=None):
                         "scale + back-rotate + KL-clip partial, plus the "
                         "momentum/weight-decay update when the step declares "
                         "sgd_hyper; docs/PERF.md 'Fused apply'), dense = "
-                        "einsum chain + optax oracle, auto = pallas on TPU "
-                        "else dense")
+                        "einsum chain + optax oracle, auto = dense (the Pallas "
+                        "kernel is opt-in: the v5e compiler refuses it for "
+                        "multi-layer shape groups, docs/PERF.md)")
     p.add_argument("--bf16", action="store_true",
                    help="bfloat16 conv/matmul compute (params + K-FAC factor "
                         "math stay f32)")

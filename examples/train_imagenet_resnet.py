@@ -25,6 +25,7 @@ import argparse
 import os
 import sys
 import time
+from typing import Callable, List, NamedTuple, Optional
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import _env  # noqa: F401  (platform forcing — must precede jax use)
@@ -35,6 +36,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kfac_pytorch_tpu import KFAC, KFACParamScheduler, capture, runtime
+from kfac_pytorch_tpu.compile_cache import RecompileMonitor, expected_step_variants
 from kfac_pytorch_tpu.models import imagenet_resnet
 from kfac_pytorch_tpu.parallel import launch
 from kfac_pytorch_tpu.parallel.mesh import data_parallel_mesh, put_global_batch
@@ -135,7 +137,9 @@ def parse_args(argv=None):
                    help="conv A-factor statistics kernel: pallas = fused "
                         "patch-covariance Pallas kernel (no im2col patch "
                         "tensor, enables large batches; docs/PERF.md), dense "
-                        "= im2col oracle, auto = pallas on TPU else dense")
+                        "= im2col oracle, auto = dense (the Pallas kernel is "
+                        "opt-in: the v5e compiler refuses it at ResNet-50 "
+                        "shapes, docs/PERF.md)")
     p.add_argument("--bf16", action="store_true",
                    help="bfloat16 conv/matmul compute (params + K-FAC factor "
                         "math stay f32)")
@@ -153,7 +157,99 @@ def _npy_shards(data_dir, split):
     return None
 
 
-def main(argv=None):
+class Training(NamedTuple):
+    """What :func:`build` makes of the flags on a mesh."""
+
+    kfac: Optional[KFAC]
+    init_state: Callable[[], TrainState]
+    train_step: Callable
+    eval_step: Callable
+
+
+class TrainRun(NamedTuple):
+    """What :func:`main` hands back: the final state and what the run saw."""
+
+    state: TrainState
+    step_losses: List[float]  # every step's loss, fetched from the device
+    kfac: Optional[KFAC]
+    train_step: Callable
+    batch_sharding: Optional[jax.sharding.Sharding]  # of the last batch fed
+
+
+def build(args, mesh) -> Training:
+    """Preconditioner, initial state and jitted step for ``args`` on ``mesh``
+    (global batch = ``--batch-size`` x mesh size). Without
+    ``--init-from-torch`` ``init_state`` is traceable, so
+    ``jax.eval_shape(init_state)`` gives the state's shapes without
+    materializing it (scripts/compile_for_chip.py)."""
+    world = mesh.devices.size
+    model = imagenet_resnet.get_model(
+        args.model, dtype=jnp.bfloat16 if args.bf16 else None
+    )
+    im = args.image_size
+    init_images = jnp.zeros((args.batch_size * world, im, im, 3), jnp.float32)
+    tx = make_sgd(momentum=args.momentum, weight_decay=args.wd)
+
+    kfac = None
+    if args.kfac_update_freq > 0:
+        kfac = KFAC(
+            layers=capture.discover_layers(model, init_images, train=True),
+            factor_decay=args.stat_decay,
+            damping=args.damping,
+            kl_clip=args.kl_clip,
+            fac_update_freq=args.kfac_cov_update_freq,
+            kfac_update_freq=args.kfac_update_freq,
+            diag_blocks=args.diag_blocks,
+            diag_warmup=args.diag_warmup,
+            distribute_layer_factors=args.distribute_layer_factors,
+            distribute_precondition=args.distribute_precondition,
+            mesh=mesh if world > 1 else None,
+            precond_precision=args.precond_precision,
+            precond_method=args.precond_method,
+            precond_comm_dtype=(jnp.bfloat16
+                                if args.precond_comm_dtype == "bf16" else None),
+            eigen_dtype=jnp.bfloat16 if args.eigen_dtype == "bf16" else jnp.float32,
+            factor_kernel=args.factor_kernel,
+        )
+
+    def init_state():
+        variables = model.init(
+            jax.random.PRNGKey(args.seed), init_images, train=True
+        )
+        params, batch_stats = variables["params"], variables.get("batch_stats", {})
+        if args.init_from_torch:
+            # migrate a reference/torchvision checkpoint; validation of
+            # paths/shapes/dtypes lives with the converter
+            # (torch_interop.init_params_from_checkpoint)
+            from kfac_pytorch_tpu import torch_interop
+
+            params, batch_stats = torch_interop.init_params_from_checkpoint(
+                args.init_from_torch, args.model, params, batch_stats
+            )
+            if launch.is_primary():
+                print(f"initialized weights from torch checkpoint "
+                      f"{args.init_from_torch}")
+        return TrainState(
+            step=jnp.zeros((), jnp.int32),
+            params=params,
+            batch_stats=batch_stats,
+            opt_state=tx.init(params),
+            kfac_state=kfac.init(params) if kfac else None,
+        )
+
+    train_step = make_train_step(
+        model, tx, kfac, label_smoothing=args.label_smoothing,
+        train_kwargs={"train": True}, accum_steps=args.batches_per_allreduce,
+        mesh=mesh if args.grad_comm_dtype else None,
+        grad_comm_dtype=jnp.bfloat16 if args.grad_comm_dtype == "bf16" else None,
+    )
+    eval_step = make_masked_eval_step(
+        model, label_smoothing=args.label_smoothing, eval_kwargs={"train": False}
+    )
+    return Training(kfac, init_state, train_step, eval_step)
+
+
+def main(argv=None) -> TrainRun:
     args = parse_args(argv)
     if args.val_resize < args.image_size:
         raise SystemExit(
@@ -176,60 +272,15 @@ def main(argv=None):
             + (f" x{accum} accum" if accum > 1 else "")
         )
 
-    model = imagenet_resnet.get_model(
-        args.model, dtype=jnp.bfloat16 if args.bf16 else None
-    )
+    kfac, init_state, train_step, eval_step = build(args, mesh)
+    state = init_state()
     im = args.image_size
-    init_images = jnp.zeros((global_bs, im, im, 3), jnp.float32)
-    variables = model.init(jax.random.PRNGKey(args.seed), init_images, train=True)
-    params, batch_stats = variables["params"], variables.get("batch_stats", {})
-    if args.init_from_torch:
-        # migrate a reference/torchvision checkpoint; validation of
-        # paths/shapes/dtypes lives with the converter
-        # (torch_interop.init_params_from_checkpoint)
-        from kfac_pytorch_tpu import torch_interop
-
-        params, batch_stats = torch_interop.init_params_from_checkpoint(
-            args.init_from_torch, args.model, params, batch_stats
-        )
-        if launch.is_primary():
-            print(f"initialized weights from torch checkpoint "
-                  f"{args.init_from_torch}")
-
-    use_kfac = args.kfac_update_freq > 0
     lr_base = args.base_lr * world
-    tx = make_sgd(momentum=args.momentum, weight_decay=args.wd)
-
-    kfac = None
     kfac_sched = None
-    if use_kfac:
-        kfac = KFAC(
-            layers=capture.discover_layers(model, init_images, train=True),
-            factor_decay=args.stat_decay,
-            damping=args.damping,
-            kl_clip=args.kl_clip,
-            fac_update_freq=args.kfac_cov_update_freq,
-            kfac_update_freq=args.kfac_update_freq,
-            diag_blocks=args.diag_blocks,
-            diag_warmup=args.diag_warmup,
-            distribute_layer_factors=args.distribute_layer_factors,
-            distribute_precondition=args.distribute_precondition,
-            mesh=mesh if world > 1 else None,
-            precond_precision=args.precond_precision,
-            precond_method=args.precond_method,
-            precond_comm_dtype=(jnp.bfloat16
-                                if args.precond_comm_dtype == "bf16" else None),
-            eigen_dtype=jnp.bfloat16 if args.eigen_dtype == "bf16" else jnp.float32,
-            factor_kernel=args.factor_kernel,
-        )
-
-    state = TrainState(
-        step=jnp.zeros((), jnp.int32),
-        params=params,
-        batch_stats=batch_stats,
-        opt_state=tx.init(params),
-        kfac_state=kfac.init(params) if kfac else None,
-    )
+    recompiles = RecompileMonitor()
+    # legitimate variant counts: plain/factors/factors+eigen, x2 while a
+    # diag_warmup schedule is active (compile_cache.expected_step_variants)
+    recompiles.watch("train_step", train_step, expected_step_variants(kfac))
 
     resume_from_epoch = 0
     if args.checkpoint_dir:
@@ -250,7 +301,7 @@ def main(argv=None):
             )
         if resume_from_epoch and launch.is_primary():
             print(f"resumed from epoch {resume_from_epoch - 1}")
-    if use_kfac:
+    if kfac:
         # scheduler restores its position from the resume epoch
         # (pytorch_imagenet_resnet.py:228-234)
         kfac_sched = KFACParamScheduler(
@@ -264,15 +315,6 @@ def main(argv=None):
 
     state = jax.device_put(state, NamedSharding(mesh, P()))
 
-    train_step = make_train_step(
-        model, tx, kfac, label_smoothing=args.label_smoothing,
-        train_kwargs={"train": True}, accum_steps=accum,
-        mesh=mesh if args.grad_comm_dtype else None,
-        grad_comm_dtype=jnp.bfloat16 if args.grad_comm_dtype == "bf16" else None,
-    )
-    eval_step = make_masked_eval_step(
-        model, label_smoothing=args.label_smoothing, eval_kwargs={"train": False}
-    )
     lr_factor = create_lr_schedule(world, args.warmup_epochs, args.lr_decay)
 
     train_data = None if args.synthetic else (
@@ -342,6 +384,8 @@ def main(argv=None):
 
     writer = ScalarWriter(args.log_dir, enabled=jax.process_index() == 0)
     step = int(jax.device_get(state.step))
+    step_losses = []
+    batch = None
 
     for epoch in range(resume_from_epoch, args.epochs):
         if kfac_sched:
@@ -386,6 +430,12 @@ def main(argv=None):
 
         t0 = time.perf_counter()
         loss_m, acc_m = Metric("train/loss"), Metric("train/accuracy")
+
+        def eat(m):
+            step_losses.append(float(m["loss"]))
+            loss_m.update(m["loss"])
+            acc_m.update(m["accuracy"])
+
         # lag-window metric fetch: async dispatch, bounded in-flight batches
         pending = []
         with profiling.maybe_trace(args.log_dir, args.profile_epoch == epoch):
@@ -402,12 +452,9 @@ def main(argv=None):
                 step += 1
                 pending.append(metrics)
                 if len(pending) > 2:
-                    m = jax.device_get(pending.pop(0))
-                    loss_m.update(m["loss"])
-                    acc_m.update(m["accuracy"])
+                    eat(jax.device_get(pending.pop(0)))
             for m in jax.device_get(pending):
-                loss_m.update(m["loss"])
-                acc_m.update(m["accuracy"])
+                eat(m)
         dt = time.perf_counter() - t0
         if launch.is_primary():
             print(
@@ -436,9 +483,15 @@ def main(argv=None):
 
         if args.checkpoint_dir:
             ckpt.save_checkpoint(args.checkpoint_dir, epoch, state)
+        excess = recompiles.check()
+        if excess and launch.is_primary():
+            print(f"WARNING: unexpected recompiles: {excess}")
 
     writer.close()
-    return state
+    return TrainRun(
+        state, step_losses, kfac, train_step,
+        None if batch is None else batch[0].sharding,
+    )
 
 
 if __name__ == "__main__":

@@ -185,7 +185,8 @@ def parse_args(argv=None):
                         "scale + back-rotate + KL-clip partial, plus the "
                         "momentum/weight-decay update; docs/PERF.md 'Fused "
                         "apply'), dense = einsum chain + optax oracle, auto "
-                        "= pallas on TPU else dense")
+                        "= dense (the Pallas kernels are opt-in: the v5e compiler "
+                        "refuses them at ResNet-50 shapes, docs/PERF.md)")
     p.add_argument("--solver", default="eigh",
                    choices=["eigh", "rsvd", "streaming"],
                    help="curvature eigensolver: eigh = full (dense) "

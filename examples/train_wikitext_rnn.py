@@ -127,7 +127,8 @@ def parse_args(argv=None):
                         "fused VMEM kernel per shape group, incl. the "
                         "momentum/weight-decay update (docs/PERF.md 'Fused "
                         "apply'); dense = einsum chain + optax oracle; auto "
-                        "= pallas on TPU else dense")
+                        "= dense (the Pallas kernels are opt-in: the v5e compiler "
+                        "refuses them at ResNet-50 shapes, docs/PERF.md)")
     p.add_argument("--solver", default="eigh",
                    choices=["eigh", "rsvd", "streaming"],
                    help="curvature eigensolver (rsvd: randomized truncated "

@@ -1,17 +1,6 @@
-"""JAX version compatibility shims.
-
-One place for API drift between the jax versions this repo runs under, so
-call sites stay written against the current public API.
-
-``shard_map``: public as ``jax.shard_map(..., check_vma=...)`` on recent
-jax; older versions (≤0.4.x) only ship
-``jax.experimental.shard_map.shard_map(..., check_rep=...)`` —
-``check_vma`` is the renamed ``check_rep`` (the replication/varying-
-manual-axes check), same semantics, so the flag maps through directly.
-
-``tpu_compiler_params``: Pallas-TPU compiler params are
-``pallas.tpu.CompilerParams`` on recent jax, ``TPUCompilerParams``
-(same constructor kwargs) on 0.4.x.
+"""The two JAX entry points whose spelling has moved between releases, kept
+under one name each so call sites do not churn. Written for the one
+installation there is (jax 0.9.0): no branch for any other.
 """
 
 from __future__ import annotations
@@ -20,30 +9,17 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with fallback to the experimental spelling.
-
-    Keyword-only after ``f`` (both spellings agree on that), so existing
+    """``jax.shard_map``; keyword-only after ``f``, so
     ``partial(shard_map, mesh=..., in_specs=..., out_specs=...,
-    check_vma=False)`` decorator usage works unchanged.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    check_vma=False)`` decorator usage works."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )
 
 
 def tpu_compiler_params(**kwargs):
-    """``pallas.tpu.CompilerParams`` with fallback to the 0.4.x spelling."""
+    """``pallas.tpu.CompilerParams``."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)

@@ -26,20 +26,21 @@ from typing import Dict
 _DEFAULT = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
 
 
-def enable_persistent_cache(path: str | None = None) -> str:
+def enable_persistent_cache() -> str:
     """Enable JAX's on-disk compilation cache; returns the cache directory.
 
-    ``KFAC_COMPILE_CACHE`` overrides the default (``<repo>/.jax_cache``);
-    set it to ``0``/``off`` to disable.
+    The directory is placed from outside: where ``JAX_COMPILATION_CACHE_DIR``
+    is set JAX reads it itself and no directory is set in code; where it is
+    not, the cache lives at the fixed ``<checkout>/.jax_cache`` (the path is
+    part of the cache key, so a directory that moves never hits).
     """
     import jax
 
-    env = os.environ.get("KFAC_COMPILE_CACHE")
-    if env in ("0", "off", "none"):
-        return ""
-    path = path or env or _DEFAULT
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = _DEFAULT
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     # Cache everything non-trivial: eigh buckets are the point, but full
     # train-step programs (30s+ compiles) benefit just as much.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)

@@ -4,10 +4,11 @@
 einsums per shape group — ``QGᵀ·grad·QA``, the damped eigenvalue divide,
 and the two back-rotations — and the optimizer step is a SEPARATE optax
 pass over every parameter leaf (``training/step.py``): each stage writes
-its intermediate to HBM and the next reads it back. At the amortized
-steady state those HBM round-trips ARE the remaining K-FAC overhead
-(BENCH_r02: 6.8 ms precondition-only vs 4.2 ms SGD). The kernels here fuse
-each stage chain into one VMEM-resident pass:
+its intermediate to HBM and the next reads it back (what that costs on
+the chip: not measured). The kernels here fuse each stage chain into one
+VMEM-resident pass. The v5e compiler refuses ``fused_precondition_stack``
+at most ResNet-50 shapes today (docs/PERF.md, "Refused by the v5e
+compiler"), so the kernels are an explicit opt-in:
 
 * :func:`fused_precondition_stack` — one grid step per layer of a shape
   group holds the layer's ``[g, a]`` gradient and its ``QA``/``QG`` bases
@@ -24,7 +25,7 @@ each stage chain into one VMEM-resident pass:
 
 The dense path stays untouched as the verbatim parity oracle
 (tests/test_fused_apply.py pins ``rtol 1e-6`` agreement in interpret
-mode). ``interpret=True`` (automatic off-TPU) is how CPU tier-1 validates
+mode). ``interpret=True`` (automatic on the CPU backend) is how CPU tier-1 validates
 the kernel math, same contract as ``ops/factor_kernels.py``.
 
 Dispatch: the preconditioner routes through
@@ -72,14 +73,19 @@ _ACTIVE_APPLY = "dense"
 
 
 def resolve_apply_kernel(kind: str) -> str:
-    """``auto`` → pallas on TPU, dense elsewhere; validate explicit kinds."""
+    """``auto`` → dense on every backend; validate explicit kinds.
+
+    The fused apply kernel is refused by the v5e compiler for every
+    multi-layer shape group and for ResNet-50's large layers (docs/PERF.md,
+    "Refused by the v5e compiler"), so it is opt-in: an explicit
+    ``"pallas"`` compiles or raises the compiler's own error — nothing
+    catches it and nothing gives way to dense.
+    """
     if kind not in APPLY_KERNELS:
         raise ValueError(
             f"Invalid apply_kernel: {kind!r} (choose from {APPLY_KERNELS})"
         )
-    if kind == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "dense"
-    return kind
+    return "dense" if kind == "auto" else kind
 
 
 def active_apply_kernel() -> str:
@@ -109,9 +115,17 @@ def apply_kernel_scope(kind: str):
 
 
 def _default_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    """Compile on a TPU, interpret on the CPU backend the tests force,
+    refuse any other backend (there is no Pallas-TPU lowering for it)."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas-TPU kernels compile on a TPU and interpret on the CPU "
+            f"backend only; default backend is {backend!r}"
+        )
+    return backend == "cpu"
 
 
 # ---------------------------------------------------------------------------

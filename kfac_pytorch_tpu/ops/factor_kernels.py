@@ -22,7 +22,7 @@ channel-major ``(c, kh, kw)`` feature order, so outputs are interchangeable
 with ``compute_a_conv`` — the dense path stays untouched as the parity
 oracle (tests/test_factor_kernels.py).
 
-``interpret=True`` (automatic off-TPU) runs the kernel through the Pallas
+``interpret=True`` (automatic on the CPU backend) runs the kernel through the Pallas
 interpreter — a lax.scan over the grid, still never materializing im2col —
 which is how CPU tier-1 validates the kernel math, same contract as
 ``ops/flash_attention.py``.
@@ -69,14 +69,18 @@ _ACTIVE_KERNEL = "dense"
 
 
 def resolve_factor_kernel(kind: str) -> str:
-    """``auto`` → pallas on TPU, dense elsewhere; validate explicit kinds."""
+    """``auto`` → dense on every backend; validate explicit kinds.
+
+    The Pallas capture kernels are refused by the v5e compiler at ResNet-50
+    shapes (docs/PERF.md, "Refused by the v5e compiler"), so they are
+    opt-in: an explicit ``"pallas"`` compiles or raises the compiler's own
+    error — nothing catches it and nothing gives way to dense.
+    """
     if kind not in FACTOR_KERNELS:
         raise ValueError(
             f"Invalid factor_kernel: {kind!r} (choose from {FACTOR_KERNELS})"
         )
-    if kind == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "dense"
-    return kind
+    return "dense" if kind == "auto" else kind
 
 
 def active_factor_kernel() -> str:
@@ -285,9 +289,17 @@ def _channel_major_perm(c: int, kk: int, tc: int) -> np.ndarray:
 
 
 def _default_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    """Compile on a TPU, interpret on the CPU backend the tests force,
+    refuse any other backend (there is no Pallas-TPU lowering for it)."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas-TPU kernels compile on a TPU and interpret on the CPU "
+            f"backend only; default backend is {backend!r}"
+        )
+    return backend == "cpu"
 
 
 def compute_a_conv_fused(

@@ -24,8 +24,8 @@ _HIGHEST = lax.Precision.HIGHEST
 # Eigenbasis rotations default to HIGH (3-pass bf16 error compensation,
 # ~f32-accurate for orthonormal Q): the rotations are the EVERY-STEP hot path
 # (4 matmuls x ~54 layers on ResNet-50, ~2.5e11 f32 FLOPs) and HIGHEST's
-# 6-pass emulation alone costs ~4 ms/step on v5e — most of the measured
-# r2 overhead (BENCH_r02.json). Factor/eigh math stays HIGHEST: those feed
+# 6-pass emulation doubles HIGH's MXU passes (time on the chip: not
+# measured). Factor/eigh math stays HIGHEST: those feed
 # eigendecompositions, where bf16 error is genuinely destructive, and they
 # amortize over fac/kfac_update_freq. Measured equal-convergence evidence:
 # logs/cifar10_resnet32_*.jsonl (K-FAC curves with HIGH rotations).

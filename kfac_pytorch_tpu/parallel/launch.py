@@ -37,7 +37,8 @@ def initialize(
     """Connect this process to the multi-host JAX runtime (``hvd.init`` analog).
 
     No-op for single-process runs (the common single-host case) and when
-    called twice. On Cloud TPU pods all arguments are discovered from the
+    called twice; raises what ``jax.distributed.initialize`` raises when a
+    multi-process start fails. On Cloud TPU pods all arguments are discovered from the
     metadata server; on other clusters pass them or set
     ``COORDINATOR_ADDRESS``/``NUM_PROCESSES``/``PROCESS_ID`` in the
     environment.
@@ -52,20 +53,17 @@ def initialize(
         process_id = int(os.environ["PROCESS_ID"])
     # Decide from env only — querying jax.devices()/default_backend() here
     # would instantiate the backend before distributed init, which is too late.
-    try:
-        if coordinator_address or num_processes:
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
-        elif len(os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")) > 1:
-            # Cloud TPU pod slice (multiple workers): auto-discovered.
-            jax.distributed.initialize()
-    except RuntimeError as e:
-        # Backend already up (e.g. an image that pre-imports jax) — continue
-        # single-process rather than dying; multi-host needs early init.
-        print(f"WARNING: jax.distributed.initialize skipped: {e}")
+    # A multi-process start that fails raises: a run that asked for several
+    # processes must not carry on as one.
+    if coordinator_address or num_processes:
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes,
+            process_id=process_id,
+        )
+    elif len(os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")) > 1:
+        # Cloud TPU pod slice (multiple workers): auto-discovered.
+        jax.distributed.initialize()
     _initialized = True
 
 

@@ -480,21 +480,10 @@ def _resolve_production(facts: ModelFacts, env: PlanEnv) -> Plan:
         ):
             plan = dataclasses.replace(plan, staleness_budget=1)
 
-    # kernel: pin the fused capture kernels where they are fast paths —
-    # the conv patch-covariance kernel and the embedding token-gather
-    # kernel both ride the same factor_kernel dispatch ("auto" already
-    # resolves to them on TPU; pinning records the decision in the plan
-    # so the snapshot shows it)
-    if (facts.has_conv or facts.has_diag_a) and env.on_tpu:
-        plan = dataclasses.replace(plan, factor_kernel="pallas")
-    # apply kernel: the fused eigenbasis apply (ops/apply_kernels.py) is a
-    # fast path on TPU for EVERY captured model — the dense rotate/scale/
-    # back-rotate chain it replaces runs per layer per step regardless of
-    # layer family. Off-TPU "auto" already resolves dense; pin only where
-    # it engages so the snapshot records the decision. Inverse-method envs
-    # degrade it via apply_pallas_vs_inverse.
-    if env.on_tpu:
-        plan = dataclasses.replace(plan, apply_kernel="pallas")
+    # kernels: factor_kernel / apply_kernel stay "auto" (= dense) on every
+    # backend. The Pallas capture and apply kernels are refused by the v5e
+    # compiler at ResNet-50 shapes (docs/PERF.md, "Refused by the v5e
+    # compiler"), so no profile pins them; they are an explicit opt-in.
     return plan
 
 

@@ -83,7 +83,7 @@ class Plan:
     # Fused Pallas apply (ops/apply_kernels.py): the whole per-layer
     # eigenbasis apply — rotate, damped scale, back-rotate, KL-clip term —
     # in one VMEM-resident kernel. "auto" resolves like factor_kernel
-    # (pallas on TPU, dense elsewhere); mirrors the constructor default.
+    # (dense on every backend); mirrors the constructor default.
     apply_kernel: str = "auto"
 
     def kfac_kwargs(self) -> Dict[str, object]:
@@ -250,10 +250,7 @@ class PlanEnv:
     the data-axis size, since owner shard stacks split over the data axis
     while tensor replicas hold identical rows. The model facts
     (``has_diag_a_layers``: any embedding/diagonal-A layer captured;
-    ``has_conv_layers``: any conv layer) feed the cost model's kernel
-    choices — both families have a fused Pallas capture path. ``on_tpu``
-    gates pinning those kernels (elsewhere they only run in interpret
-    mode, a test vehicle, not a fast path).
+    ``has_conv_layers``: any conv layer) feed the compatibility rules.
     """
 
     world: int = 1
@@ -271,7 +268,6 @@ class PlanEnv:
     # unchanged.
     has_shard_lens_layers: bool = False
     has_moe_layers: bool = False
-    on_tpu: bool = False
     fac_update_freq: int = 10
     kfac_update_freq: int = 100
     # The curvature-service carve the OPERATOR has offered (devices already

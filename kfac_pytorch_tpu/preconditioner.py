@@ -353,7 +353,6 @@ class KFAC:
                 has_conv_layers=(
                     facts.has_conv if facts is not None else True
                 ),
-                on_tpu=jax.default_backend() == "tpu",
                 fac_update_freq=fac_update_freq,
                 kfac_update_freq=kfac_update_freq,
                 # the curvature-service carve the operator has OFFERED (the
@@ -648,9 +647,10 @@ class KFAC:
         # (ops/factors.py::compute_a_conv, kept verbatim), "pallas" the fused
         # patch-covariance kernel that never materializes the im2col tensor
         # (ops/factor_kernels.py — ~kh·kw× less factor-step HBM traffic, the
-        # batch-128 lever of docs/PERF.md). "auto" resolves here: pallas on
-        # TPU, dense elsewhere (CPU/GPU run the kernel only in interpret
-        # mode, which is a test vehicle, not a fast path). Train steps open
+        # batch-128 lever of docs/PERF.md). "auto" resolves here, to dense on
+        # every backend: the v5e compiler refuses the Pallas kernel at
+        # ResNet-50 shapes (docs/PERF.md, "Refused by the v5e compiler"), so
+        # it is an explicit opt-in that compiles or raises. Train steps open
         # a factor_kernel_scope with this value around their capture forward.
         _validate(
             "factor_kernel",
@@ -662,8 +662,8 @@ class KFAC:
         # (ops/precondition.py::precondition_all + the separate optax step),
         # "pallas" the fused VMEM-resident rotate→divide→back-rotate kernel
         # that also emits the KL-clip partials and fuses the SGD update
-        # (ops/apply_kernels.py). "auto" resolves like factor_kernel: pallas
-        # on TPU, dense elsewhere. Train steps open an apply_kernel_scope
+        # (ops/apply_kernels.py). "auto" resolves like factor_kernel: dense
+        # on every backend. Train steps open an apply_kernel_scope
         # with this value around KFAC.update + the optimizer step; anything
         # traced outside a scope (eval_shape, state templates) pins dense.
         _validate(
@@ -674,9 +674,8 @@ class KFAC:
         apply_kernel = apply_kernel_ops.resolve_apply_kernel(apply_kernel)
         if apply_kernel == "pallas" and precond_method == "inverse":
             # Degrade, not refuse (planner rule apply_pallas_vs_inverse):
-            # "auto" legitimately lands here on TPU with the inverse method,
-            # and the inverse path's 2-matmul chain has no eigenbasis stage
-            # for the fused kernel to cover.
+            # the inverse path's 2-matmul chain has no eigenbasis stage for
+            # the fused kernel to cover.
             print(
                 "WARNING: apply_kernel='pallas' fuses the eigenbasis apply; "
                 "precond_method='inverse' preconditions with explicit "

@@ -229,7 +229,6 @@ def resolve_fixture(name: str) -> dict:
         world=fx["world"],
         data_world=fx.get("data_world", 0),
         mesh_axes=tuple(fx["mesh_axes"]),
-        on_tpu=True,
         has_diag_a_layers=facts.has_diag_a,
         has_conv_layers=facts.has_conv,
         has_shard_lens_layers=facts.has_shard_lens,

@@ -1,9 +1,9 @@
 """Test harness: force an 8-device virtual CPU mesh (default).
 
 Multi-device collective/sharding paths (pmean/psum/shard_map) are exercised on
-fake CPU devices — real SPMD semantics, no TPU pod needed (SURVEY.md §4).
-See kfac_pytorch_tpu/platform_override.py for why env vars alone are too late
-on this image.
+virtual CPU devices — real SPMD semantics, no TPU pod needed (SURVEY.md §4).
+``platform_override.force_cpu_devices(8)`` sets ``JAX_PLATFORMS=cpu`` and the
+``xla_force_host_platform_device_count`` flag before first device use.
 
 ``KFAC_TEST_TPU=1`` skips the CPU override so the TPU-gated tests (the
 ``test_tpu_hardware_*`` Mosaic validations in test_flash_attention.py, which

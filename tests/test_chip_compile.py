@@ -1,0 +1,146 @@
+"""The main path's kernels, compiled for a DESCRIBED v5e at ResNet-50 widths.
+
+The TPU compiler is installed next to the CPU backend and compiles for a
+chip that is described and not attached (on-chip-measurement guide, section
+2). A compile that passes here is not a chip run: nothing executes, so this
+says nothing about results or times — only that the chip's compiler accepts
+the default (dense XLA) path at real shapes, which interpret-mode tests
+cannot show. The Pallas kernels it refuses are listed in docs/PERF.md,
+"Refused by the v5e compiler".
+
+This is the ONLY test file that describes a chip: one process at a time may
+load the TPU library, so the topology is described inside a module-scoped
+fixture (never at import, in a skipif or in parametrize arguments), every
+compile runs in this process, and whole step programs — minutes each — stay
+in scripts/compile_for_chip.py.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from kfac_pytorch_tpu.ops import apply_kernels, eigh, factors
+from kfac_pytorch_tpu.ops import precondition as precond_ops
+from kfac_pytorch_tpu.ops.flash_attention import flash_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-chip executable can be written to the persistent cache but
+    # not read back without a chip: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes, **static):
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes,
+    )
+    compiled = jax.jit(lambda *a: fn(*a, **static)).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+    return compiled.as_text()
+
+
+def _f32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+
+@pytest.mark.parametrize("g,a", [(512, 4608), (2048, 512)])
+def test_dense_precondition_compiles(one_chip, g, a):
+    """ops/precondition.py::precondition_all for ResNet-50's widest conv
+    (layer4 3x3: 512 x 4608) and widest 1x1 (2048 x 512), one singleton and
+    one stacked pair each — the every-step path under the default kernels."""
+    entry = {"QA": _f32(a, a), "QG": _f32(g, g), "dA": _f32(a), "dG": _f32(g)}
+    grads = {n: _f32(g, a) for n in ("l0", "l1", "solo")}
+    # same-shape layers are batched; a third layer of another shape is not
+    grads["solo"] = _f32(g, a + 1)
+    eigen = {
+        "l0": entry, "l1": entry,
+        "solo": {**entry, "QA": _f32(a + 1, a + 1), "dA": _f32(a + 1)},
+    }
+    hlo = _compile(
+        lambda gm, e, d: precond_ops.precondition_all(gm, e, d),
+        one_chip, grads, eigen, _f32(),
+    )
+    assert "tpu_custom_call" not in hlo  # dense XLA, no Mosaic kernel
+
+
+def test_dense_conv_factor_compiles(one_chip):
+    """ops/factors.py::compute_a_conv (im2col) for the 3x3 conv on 56x56x64
+    at batch 32 — the capture the Pallas kernel was meant to replace."""
+    hlo = _compile(
+        factors.compute_a_conv, one_chip, _f32(32, 56, 56, 64),
+        kernel_size=(3, 3), strides=(1, 1), padding="SAME", has_bias=False,
+    )
+    assert "tpu_custom_call" not in hlo
+
+
+def test_eigh_smallest_bucket_compiles(one_chip):
+    """ops/eigh.py::batched_eigh at the 128 bucket. Larger buckets take
+    minutes each to compile (docs/PERF.md): scripts/compile_for_chip.py."""
+    _compile(eigh.batched_eigh, one_chip, _f32(1, 128, 128))
+
+
+def test_fused_sgd_apply_compiles(one_chip):
+    """ops/apply_kernels.py::fused_sgd_apply — the one Pallas kernel of the
+    apply path the compiler accepts at ResNet-50's largest leaves; an
+    explicit opt-in (interpret=False: compile through Mosaic)."""
+    tree = {"conv": _f32(3, 3, 512, 512), "fc": _f32(2048, 1000), "bias": _f32(1000)}
+    hlo = _compile(
+        lambda p, g, m, lr: apply_kernels.fused_sgd_apply(
+            p, g, m, lr, 0.9, 5e-5, interpret=False
+        ),
+        one_chip, tree, tree, tree, _f32(),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+def test_explicit_pallas_apply_raises_the_compilers_error(one_chip):
+    """An explicit Pallas request compiles or raises the compiler's own
+    error; nothing catches it and nothing gives way to dense. k > 1 stacks
+    are refused today (block (1, a) of the [k, a] eigenvalue array)."""
+    k, g, a = 3, 64, 576
+    with pytest.raises(Exception) as err:
+        _compile(
+            lambda gm, qa, da, qg, dg, d: apply_kernels.fused_precondition_stack(
+                gm, qa, da, qg, dg, d, interpret=False
+            ),
+            one_chip, _f32(k, g, a), _f32(k, a, a), _f32(k, a),
+            _f32(k, g, g), _f32(k, g), _f32(),
+        )
+    assert "block shape" in str(err.value).lower()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_fwd_bwd_compiles(one_chip, dtype):
+    """ops/flash_attention.py at the transformer example's head sizes
+    (4 heads of 64, seq 128): kept by best_attention_fn because the chip's
+    compiler accepts forward and backward."""
+    x = jax.ShapeDtypeStruct((8, 128, 4, 64), dtype)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip, x, x, x)
+    assert hlo.count("tpu_custom_call") >= 3  # fwd + two bwd kernels

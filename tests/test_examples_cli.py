@@ -36,7 +36,7 @@ def test_imagenet_trainer_end_to_end(imagenet_shards, tmp_path):
     import train_imagenet_resnet as t
 
     log_dir = tmp_path / "logs"
-    state = t.main([
+    run = t.main([
         "--data-dir", str(imagenet_shards),
         "--image-size", "32", "--val-resize", "36",
         "--model", "resnet18",
@@ -47,8 +47,9 @@ def test_imagenet_trainer_end_to_end(imagenet_shards, tmp_path):
         "--checkpoint-dir", str(tmp_path / "ckpt"),
         "--log-dir", str(log_dir),
     ])
-    assert state is not None
+    state = run.state
     assert int(state.step) == 3
+    assert len(run.step_losses) == 3 and run.kfac.precond_method == "eigen"
     scalars = log_dir / "scalars.jsonl"
     assert scalars.is_file()
     tags = {json.loads(l)["tag"] for l in scalars.open()}

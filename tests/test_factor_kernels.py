@@ -116,10 +116,7 @@ def test_fused_under_jit_and_stop_gradient():
 def test_resolve_and_scope():
     assert factor_kernels.resolve_factor_kernel("dense") == "dense"
     assert factor_kernels.resolve_factor_kernel("pallas") == "pallas"
-    # auto resolves by backend; on the CPU test runner that is dense
-    assert factor_kernels.resolve_factor_kernel("auto") == (
-        "pallas" if jax.default_backend() == "tpu" else "dense"
-    )
+    assert factor_kernels.resolve_factor_kernel("auto") == "dense"
     with pytest.raises(ValueError):
         factor_kernels.resolve_factor_kernel("im2col")
 
@@ -333,3 +330,26 @@ def test_fused_compiled_memory_beats_dense_im2col():
     )
     # the headline claim: the fused program needs no O(B·OH·OW·C·kh·kw) temp
     assert m_fused.temp_size_in_bytes < patch_bytes // 2
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu", "gpu"])
+def test_auto_is_dense_on_every_backend(monkeypatch, backend):
+    """The Pallas capture kernels are opt-in: the v5e compiler refuses them
+    at ResNet-50 shapes (docs/PERF.md), so "auto" never picks them."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert factor_kernels.resolve_factor_kernel("auto") == "dense"
+    from kfac_pytorch_tpu import KFAC
+
+    assert KFAC(damping=0.01).factor_kernel == "dense"
+
+
+def test_default_interpret_by_backend(monkeypatch):
+    """Compile on a TPU, interpret on the tests' CPU backend, refuse the
+    rest; an explicit argument is taken as given."""
+    assert factor_kernels._default_interpret(None) is True  # CPU tier-1
+    assert factor_kernels._default_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert factor_kernels._default_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        factor_kernels._default_interpret(None)

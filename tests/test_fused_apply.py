@@ -109,9 +109,9 @@ def test_fused_precondition_stack_matches_dense_oracle(k, g, a):
 
 
 def test_scope_routing_and_resolution():
-    """auto resolves to dense off-TPU; the scope is trace-time state; the
-    fused SGD dispatcher refuses to engage under a dense scope."""
-    assert resolve_apply_kernel("auto") == "dense"  # CPU tier-1
+    """auto resolves to dense; the scope is trace-time state; the fused SGD
+    dispatcher refuses to engage under a dense scope."""
+    assert resolve_apply_kernel("auto") == "dense"
     assert resolve_apply_kernel("pallas") == "pallas"
     assert resolve_apply_kernel("dense") == "dense"
     with pytest.raises(ValueError):
@@ -360,3 +360,22 @@ def test_apply_kernel_and_int8_wire_do_not_widen_variant_budget():
         kfac, plan=Plan(factor_comm_freq=2, factor_comm_dtype="int8",
                         apply_kernel="pallas")
     )
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu", "gpu"])
+def test_auto_is_dense_on_every_backend(monkeypatch, backend):
+    """The fused apply kernel is opt-in: the v5e compiler refuses it for
+    every multi-layer shape group (docs/PERF.md), so "auto" never picks it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_apply_kernel("auto") == "dense"
+    assert KFAC(damping=0.01).apply_kernel == "dense"
+
+
+def test_default_interpret_by_backend(monkeypatch):
+    assert apply_kernels._default_interpret(None) is True  # CPU tier-1
+    assert apply_kernels._default_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert apply_kernels._default_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        apply_kernels._default_interpret(None)

@@ -79,7 +79,7 @@ def test_bigger_sides_engage_streaming():
     """Where truncation wins, production now engages the streaming solver
     (rsvd layout + per-step folds) rather than periodic rsvd — the refresh
     spike disappears instead of shrinking."""
-    env = _env(world=8, on_tpu=True)
+    env = _env(world=8)
     small, _, _ = resolve_profile("production", _SMALL_FACTS, env)
     big, report, _ = resolve_profile("production", _BIG_FACTS, env)
     assert small.solver == "eigh"
@@ -128,7 +128,7 @@ def test_production_resolves_composed_plan_at_scale():
     """The acceptance bar: ≥3 non-default levers on big shapes at world
     32 (the exact ResNet-50 plan is pinned by check_plan_snapshot.py)."""
     plan, _, dropped = resolve_profile(
-        "production", _BIG_FACTS, _env(world=32, on_tpu=True)
+        "production", _BIG_FACTS, _env(world=32)
     )
     assert len(plan.non_default_levers()) >= 3
     assert not dropped
@@ -492,7 +492,7 @@ def test_unknown_profile_refused():
 
 
 def test_autotune_deterministic_under_fixed_timings():
-    env = _env(world=8, on_tpu=True)
+    env = _env(world=8)
     plan, _, _ = resolve_profile("production", _BIG_FACTS, env)
     cands = candidate_plans(plan, env)
     assert 2 <= len(cands) <= 3
@@ -528,7 +528,7 @@ def test_plan_round_trips_through_checkpoint(tmp_path):
     from kfac_pytorch_tpu.training import checkpoint as ckpt
 
     plan, _, _ = resolve_profile(
-        "production", _BIG_FACTS, _env(world=32, on_tpu=True)
+        "production", _BIG_FACTS, _env(world=32)
     )
     assert plan != Plan()
     payload = {"plan": plan.to_state(), "epoch": np.asarray(3, np.int32)}
@@ -568,13 +568,13 @@ def test_service_engages_only_past_carve_bar():
 
     # no offer → no service, whatever the shapes
     plan, report, _ = resolve_profile(
-        "production", _BIG_FACTS, _env(world=32, on_tpu=True)
+        "production", _BIG_FACTS, _env(world=32)
     )
     assert plan.service_devices == 0 and report.service_carve_cost == 0
 
     # offered + aggressive refresh (K=10): dense refresh clears the bar
     hot = _env(
-        world=32, on_tpu=True, service_devices=2,
+        world=32, service_devices=2,
         fac_update_freq=1, kfac_update_freq=10,
     )
     plan, report, dropped = resolve_profile("production", _BIG_FACTS, hot)
@@ -593,7 +593,7 @@ def test_service_engages_only_past_carve_bar():
 
     # offered but lazy refresh (default K=100): amortized in-step refresh
     # is cheaper than the carve — the offer is declined, streaming engages
-    cold = _env(world=32, on_tpu=True, service_devices=2)
+    cold = _env(world=32, service_devices=2)
     plan, report, _ = resolve_profile("production", _BIG_FACTS, cold)
     assert plan.service_devices == 0
     assert report.service_devices == 0 and report.service_carve_cost > 0

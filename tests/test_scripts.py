@@ -215,37 +215,44 @@ def test_no_scratch_files_tracked():
     assert not bad, f"scratch files tracked by git: {bad}"
 
 
-def test_bench_cpu_fallback_emits_json():
-    """bench.py must emit parseable, schema-complete JSON with rc=0 even
-    when the TPU backend never comes up: the probe subprocess (stubbed here
-    with a sleeper) times out per attempt, the retry budget is wall-clock,
-    and exhaustion falls back to the CPU backend instead of hanging to
-    rc=124 (the BENCH_r03 failure mode)."""
-    import json
-
+def _run_bench(env_update, env_drop=()):
     env = dict(os.environ)
-    env.pop("KFAC_FORCE_PLATFORM", None)  # forcing a platform skips the probe
+    for k in env_drop:
+        env.pop(k, None)
     env.update(
         JAX_PLATFORMS="cpu",
-        KFAC_BENCH_PROBE_CMD=(
-            f'{sys.executable} -c "import time; time.sleep(30)"'
-        ),
-        KFAC_BENCH_PROBE_TIMEOUT_S="1",
-        KFAC_BENCH_RETRY_S="2",
         KFAC_BENCH_ARMS="none",  # no arm keys match: skip all measurements
         KFAC_BENCH_SKIP_TRANSFORMER="1",
         KFAC_BENCH_WALL_S="120",
+        **env_update,
     )
-    res = subprocess.run(
+    return subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py")],
         capture_output=True, text=True, timeout=110, env=env, cwd=REPO,
     )
+
+
+def test_bench_refuses_to_run_without_a_chip():
+    """No chip and no forced platform: bench.py exits non-zero, names the
+    platform it found, and prints no result line — there is no probe child,
+    no retry loop and no CPU fallback to hide the device."""
+    res = _run_bench({}, env_drop=("KFAC_FORCE_PLATFORM",))
+    assert res.returncode != 0, res.stdout[-2000:]
+    assert "platform='cpu'" in res.stderr, res.stderr[-2000:]
+    assert not res.stdout.strip(), res.stdout[-2000:]
+
+
+def test_bench_forced_cpu_is_an_explicit_request():
+    """KFAC_FORCE_PLATFORM is the one way onto the CPU backend, set by a
+    test on purpose; the run then says which device it saw."""
+    import json
+
+    res = _run_bench({"KFAC_FORCE_PLATFORM": "cpu:1"})
     assert res.returncode == 0, f"rc={res.returncode}\n{res.stderr[-2000:]}"
-    lines = [l for l in res.stdout.strip().splitlines() if l.strip()]
-    assert lines, f"no stdout lines\n{res.stderr[-2000:]}"
-    rec = json.loads(lines[-1])
+    rec = json.loads(res.stdout.strip().splitlines()[-1])
     assert rec["metric"] and "value" in rec and "vs_baseline" in rec
-    assert rec["detail"]["backend_fallback"] == "cpu"
+    assert "cpu" in rec["detail"]["device"].lower()
+    assert "backend_fallback" not in rec["detail"]
 
 
 def test_summarize_curves_compare_fallback(tmp_path):
