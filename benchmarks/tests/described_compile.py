@@ -1,0 +1,103 @@
+"""Compile a cell's step programs at full size for a DESCRIBED v5e, no chip
+(rehearsal 3 of the on-chip-measurement guide), with ``memory_analysis()``.
+Nothing runs: this gives no time and no result, only what the chip's compiler
+accepts and how much memory each program needs.
+
+    JAX_PLATFORMS=cpu python benchmarks/tests/described_compile.py <cell> [program ...]
+
+Programs: refresh, factors, plain, twin, and the reference's device half of a step
+with and without capture (reference_capture, reference_next), its inverses
+(reference_inverses) and its second half (reference_update).
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import resource
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(HERE)
+sys.path[:0] = [ROOT, os.path.join(ROOT, "examples"), HERE]
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+
+import run as bench  # noqa: E402
+
+FLAGS = {
+    "plain": dict(update_factors=False, update_eigen=False),
+    "factors": dict(update_factors=True, update_eigen=False),
+    "refresh": dict(update_factors=True, update_eigen=True),
+}
+
+
+def main(argv):
+    from jax.experimental import topologies
+
+    from kfac_pytorch_tpu.parallel.mesh import data_parallel_mesh
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cell = bench.load_cell(argv[0])
+    programs = argv[1:] or ["refresh", "factors", "plain", "twin", "reference_capture", "reference_next",
+                            "reference_inverses", "reference_update"]
+    cfg, mix = cell["cfg"], cell["traffic_mix"]
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    mesh = data_parallel_mesh(topo.devices[: cell["chips"]])
+    replicated = NamedSharding(mesh, P())
+    shard = lambda sharding: (lambda t: jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), t))
+    builder = bench.load_module(bench.HERE, "configs", cfg["builder"] + ".py")
+    built = builder.build(cfg, mix, mesh)
+    batch = shard(NamedSharding(mesh, P("data")))(built["batch_struct"])
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)
+    state = shard(replicated)(jax.eval_shape(built["init_state"]))
+
+    def report(name, lowered):
+        t0 = time.perf_counter()
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        print(json.dumps({
+            "cell": cell["name"], "program": name, "compile_seconds": round(time.perf_counter() - t0, 1),
+            "argument_bytes": mem.argument_size_in_bytes, "output_bytes": mem.output_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes, "alias_bytes": mem.alias_size_in_bytes,
+            "live_bytes": mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes,
+            "tpu_custom_calls": compiled.as_text().count("tpu_custom_call"),
+            "host_peak_rss_gib": round(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20, 2),
+        }), flush=True)
+
+    for name in programs:
+        if name in FLAGS:
+            flags = dict(FLAGS[name], diag_warmup_done=True)
+            report(name, built["train_step"].lower(state, batch, scalar, scalar, **flags))
+        elif name == "twin":
+            twin = builder.build(cfg, mix, mesh, kfac_on=False)
+            tstate = shard(replicated)(jax.eval_shape(twin["init_state"]))
+            report(name, twin["train_step"].lower(tstate, batch, scalar, scalar, **FLAGS["plain"]))
+        else:
+            kf = bench.load_module(bench.HERE, "reference", "kfac_sgd.py")
+            model = bench.load_module(bench.HERE, "reference", cfg["reference"] + ".py").Model(cfg, mix)
+            hyper = {**cfg["kfac"], "momentum": cfg["momentum"],
+                     "weight_decay": cfg["weight_decay"], "grad_clip": cfg["grad_clip"]}
+            params = jax.eval_shape(built["init_state"]).params
+            rstate = shard(replicated)(jax.eval_shape(lambda p: kf.init_state(model, p), params))
+            if name == "reference_inverses":
+                fn = jax.jit(lambda f: kf.damped_inverses(f, hyper["damping"]))
+                report(name, fn.lower(rstate.factors))
+            elif name == "reference_update":
+                fn = jax.jit(lambda st, g, f, i, lr: kf.precondition_and_update(model, hyper, st, g, f, i, lr))
+                report(name, fn.lower(rstate, rstate.params, rstate.factors, rstate.inverses, scalar))
+            else:
+                uf = name == "reference_capture" or 1 % mix["fac_update_freq"] == 0
+                fn = jax.jit(lambda st, b: kf.forward_backward(
+                    model, hyper, st, b, update_factors=uf,
+                    row_blocks=cell["file"].get("reference_row_blocks", 1)))
+                report(name, fn.lower(rstate, shard(replicated)(built["batch_struct"])))
+
+if __name__ == "__main__":
+    main(sys.argv[1:])
