@@ -27,6 +27,7 @@ from kfac_pytorch_tpu.models.layers import (
     OUT_PERTURB,
     OUT_TIED,
 )
+from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.ops import factor_kernels, factors
 
 PyTree = Any
@@ -278,6 +279,7 @@ def _unwrap_sown(leaf: Any) -> Any:
     return leaf[-1] if isinstance(leaf, tuple) else leaf
 
 
+@phase("kfac_capture")
 def a_contribs(
     captured: PyTree,
     names: List[str],
@@ -409,6 +411,7 @@ def a_contribs(
     return out
 
 
+@phase("kfac_capture")
 def g_factors(
     perturb_grads: PyTree,
     names: List[str],

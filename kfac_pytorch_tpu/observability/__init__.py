@@ -6,6 +6,11 @@
 * :mod:`.diagnostics` — the in-graph K-FAC health-key vocabulary.
 * :mod:`.trace` — the flight recorder: per-host append-only structured
   event log with cross-process correlation keys (no-op when disabled).
+* :mod:`.phases` — the step's phase vocabulary: ``phase(name)`` puts a
+  ``jax.named_scope`` on the ops traced inside it (always on, metadata only).
+* :mod:`.device_phases` — device time by program run and phase from a
+  profiler trace (``python -m kfac_pytorch_tpu.observability.device_phases``);
+  imported only where a trace is read.
 
 The recompile detector (``RecompileMonitor``) lives in
 :mod:`kfac_pytorch_tpu.compile_cache` next to the compilation-cache setup
@@ -23,6 +28,7 @@ from kfac_pytorch_tpu.observability.export import (  # noqa: F401
     summary_table,
     write_prometheus,
 )
+from kfac_pytorch_tpu.observability.phases import PHASES, phase  # noqa: F401
 from kfac_pytorch_tpu.observability.telemetry import (  # noqa: F401
     Span,
     Telemetry,

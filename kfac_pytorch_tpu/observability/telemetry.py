@@ -21,7 +21,8 @@ Design constraints, in priority order:
   time comes from host-side spans that ``block()`` on a step output, and
   in-graph health numbers flow out of the step as the diagnostics pytree
   (preconditioner.py) — so the compiled program is bit-identical with
-  telemetry on or off.
+  telemetry on or off. Device time by phase is read from a profiler trace
+  by the ops' own scope names (phases.py, device_phases.py).
 * **Fixed metric names.** Every span/counter/gauge name is a string
   literal registered in docs/OBSERVABILITY.md (enforced by
   scripts/check_metric_names.py); no f-string names, so exporter output
@@ -71,20 +72,30 @@ class Span:
     to ``jax.block_until_ready`` on exit, so the recorded duration includes
     the device work an async dispatch would otherwise hide. Without it a
     span around a jitted call times only dispatch.
+
+    The span also enters a ``jax.profiler.TraceAnnotation`` of its name, so
+    that under a profiler trace (``--profile-epoch``) the trainers' host
+    spans lie on ``/host:CPU`` of the same ``.xplane.pb`` as the device ops,
+    on one clock; with no trace running the annotation records nothing.
     """
 
-    __slots__ = ("_telemetry", "_name", "_t0", "_sync")
+    __slots__ = ("_telemetry", "_name", "_t0", "_sync", "_annotation")
 
     def __init__(self, telemetry: "Telemetry", name: str):
         self._telemetry = telemetry
         self._name = name
         self._t0 = 0.0
         self._sync = None
+        self._annotation = None
 
     def block(self, obj) -> None:
         self._sync = obj
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -94,6 +105,7 @@ class Span:
 
             jax.block_until_ready(self._sync)
         self._telemetry.observe(self._name, time.perf_counter() - self._t0)
+        self._annotation.__exit__(*exc)
         return False
 
 

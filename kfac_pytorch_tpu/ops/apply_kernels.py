@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from kfac_pytorch_tpu import compat
+from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.observability.telemetry import get_telemetry
 
 PyTree = Any
@@ -253,7 +254,7 @@ def dispatch_precondition_stack(
     """
     tel = get_telemetry()
     tel.set_gauge("kfac/apply_kernel", 1.0)
-    with tel.span("trace/kfac/apply_kernel"):
+    with phase("kfac_apply", "trace/kfac/apply_kernel"):
         return fused_precondition_stack(
             jax.lax.stop_gradient(gm),
             jax.lax.stop_gradient(qa),
@@ -392,7 +393,7 @@ def dispatch_sgd_apply(
     tel.set_gauge("kfac/apply_kernel", 1.0 if kind == "pallas" else 0.0)
     if kind != "pallas":
         return None
-    with tel.span("trace/kfac/apply_kernel"):
+    with phase("optimizer", "trace/kfac/apply_kernel"):
         return fused_sgd_apply(
             jax.lax.stop_gradient(params),
             jax.lax.stop_gradient(grads),

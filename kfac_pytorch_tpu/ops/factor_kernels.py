@@ -47,6 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kfac_pytorch_tpu import compat
+from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.observability.telemetry import get_telemetry
 from kfac_pytorch_tpu.ops import factors
 
@@ -511,7 +512,7 @@ def dispatch_compute_a_conv(
     tel = get_telemetry()
     kind = active_factor_kernel()
     tel.set_gauge("kfac/factor_kernel", 1.0 if kind == "pallas" else 0.0)
-    with tel.span("trace/kfac/factor_kernel"):
+    with phase("kfac_capture", "trace/kfac/factor_kernel"):
         if kind == "pallas":
             # A is a statistics by-product, never differentiated — cut the
             # tangent path so autodiff of the capture forward does not need
@@ -543,7 +544,7 @@ def dispatch_compute_a_embed(ids: jnp.ndarray, vocab: int) -> jnp.ndarray:
     tel = get_telemetry()
     kind = active_factor_kernel()
     tel.set_gauge("kfac/embedding_capture_kernel", 1.0 if kind == "pallas" else 0.0)
-    with tel.span("trace/kfac/factor_kernel"):
+    with phase("kfac_capture", "trace/kfac/factor_kernel"):
         if kind == "pallas":
             return compute_a_embed_fused(ids, vocab)
         return factors.compute_a_embed(ids, vocab)
@@ -563,7 +564,7 @@ def dispatch_compute_a_moe(
     tel = get_telemetry()
     kind = active_factor_kernel()
     tel.set_gauge("kfac/moe_dispatch_kernel", 1.0 if kind == "pallas" else 0.0)
-    with tel.span("trace/kfac/factor_kernel"):
+    with phase("kfac_capture", "trace/kfac/factor_kernel"):
         if kind == "pallas":
             return compute_a_embed_fused(expert_ids, num_experts)
         return factors.compute_a_embed(expert_ids, num_experts)
@@ -582,7 +583,7 @@ def dispatch_compute_a_conv_grouped(
     tel = get_telemetry()
     kind = active_factor_kernel()
     tel.set_gauge("kfac/factor_kernel", 1.0 if kind == "pallas" else 0.0)
-    with tel.span("trace/kfac/factor_kernel"):
+    with phase("kfac_capture", "trace/kfac/factor_kernel"):
         if kind == "pallas":
             return compute_a_conv_grouped_fused(
                 jax.lax.stop_gradient(a),

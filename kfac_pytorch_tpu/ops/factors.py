@@ -22,6 +22,11 @@ Layout conventions (TPU/flax native, NOT torch):
 
 All matmuls feeding factors use ``lax.Precision.HIGHEST`` so TPU bf16 matmul
 defaults cannot corrupt the eigendecompositions downstream.
+
+Every ``compute_a_*`` / ``compute_g_*`` runs under the ``kfac_capture`` phase
+scope (observability/phases.py): the A products are sown from inside the
+model's forward pass, and a device trace tells them from the model's own ops
+by that name alone.
 """
 
 from __future__ import annotations
@@ -31,6 +36,8 @@ from typing import Any, Dict, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from kfac_pytorch_tpu.observability.phases import phase
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -77,6 +84,7 @@ def _flatten_leading(x: jnp.ndarray) -> jnp.ndarray:
     return x.reshape(-1, x.shape[-1])
 
 
+@phase("kfac_capture")
 def compute_a_dense(a: jnp.ndarray, has_bias: bool) -> jnp.ndarray:
     """Input covariance for a dense layer: ``A = aᵀ (a / N)``.
 
@@ -91,6 +99,7 @@ def compute_a_dense(a: jnp.ndarray, has_bias: bool) -> jnp.ndarray:
     return jnp.matmul(a.T, a / n, precision=_HIGHEST)
 
 
+@phase("kfac_capture")
 def compute_a_conv(
     a: jnp.ndarray,
     kernel_size: Tuple[int, int],
@@ -117,6 +126,7 @@ def compute_a_conv(
     return jnp.matmul(p.T, p / batch_size, precision=_HIGHEST)
 
 
+@phase("kfac_capture")
 def compute_a_conv_grouped(
     a: jnp.ndarray,
     groups: int,
@@ -149,6 +159,7 @@ def compute_a_conv_grouped(
     )(xg)
 
 
+@phase("kfac_capture")
 def compute_a_row_sharded(a: jnp.ndarray, shards: int) -> jnp.ndarray:
     """Per-shard input covariances for a ROW-sharded dense kernel: ``[T, a/T, a/T]``.
 
@@ -166,6 +177,7 @@ def compute_a_row_sharded(a: jnp.ndarray, shards: int) -> jnp.ndarray:
     return jnp.einsum("nti,ntj->tij", am, am / n, precision=_HIGHEST)
 
 
+@phase("kfac_capture")
 def compute_a_moe(
     x: jnp.ndarray, expert_ids: jnp.ndarray, num_experts: int
 ) -> jnp.ndarray:
@@ -192,6 +204,7 @@ def compute_a_moe(
     return jnp.stack([_one(e) for e in range(num_experts)])
 
 
+@phase("kfac_capture")
 def compute_a_moe_onehot(
     x: jnp.ndarray, expert_ids: jnp.ndarray, num_experts: int
 ) -> jnp.ndarray:
@@ -213,6 +226,7 @@ def compute_a_moe_onehot(
     return jnp.stack(out)
 
 
+@phase("kfac_capture")
 def compute_a_embed(ids: jnp.ndarray, vocab: int) -> jnp.ndarray:
     """Input-covariance DIAGONAL for an embedding layer: token frequencies.
 
@@ -229,6 +243,7 @@ def compute_a_embed(ids: jnp.ndarray, vocab: int) -> jnp.ndarray:
     return counts / n
 
 
+@phase("kfac_capture")
 def compute_a_embed_onehot(ids: jnp.ndarray, vocab: int) -> jnp.ndarray:
     """Dense one-hot oracle for :func:`compute_a_embed` (parity/memory baseline).
 
@@ -246,6 +261,7 @@ def compute_a_embed_onehot(ids: jnp.ndarray, vocab: int) -> jnp.ndarray:
     return jnp.diagonal(dense_a)
 
 
+@phase("kfac_capture")
 def compute_g_dense(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     """Grad-output covariance for a dense layer.
 
@@ -260,6 +276,7 @@ def compute_g_dense(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     return jnp.matmul(g.T, g / n, precision=_HIGHEST)
 
 
+@phase("kfac_capture")
 def compute_g_diag(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     """DIAGONAL of the grad-output covariance: ``diag(GᵀG·s)`` without GᵀG.
 
@@ -276,6 +293,7 @@ def compute_g_diag(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     return jnp.sum(g * g, axis=0) * scale
 
 
+@phase("kfac_capture")
 def compute_g_dense_sharded(
     g: jnp.ndarray, shards: int, batch_averaged: bool
 ) -> jnp.ndarray:
@@ -295,6 +313,7 @@ def compute_g_dense_sharded(
     return jnp.einsum("nti,ntj->tij", gm, gm * scale, precision=_HIGHEST)
 
 
+@phase("kfac_capture")
 def compute_g_moe(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     """Per-expert UNNORMALIZED grad-output covariance sums: ``[E, m, m]``.
 
@@ -311,6 +330,7 @@ def compute_g_moe(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     return jnp.einsum("nei,nej->eij", g, g * scale, precision=_HIGHEST)
 
 
+@phase("kfac_capture")
 def compute_g_conv(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     """Grad-output covariance for a conv layer from NHWC output-grads.
 
@@ -328,6 +348,7 @@ def compute_g_conv(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     return jnp.matmul(gm.T, gm / gm.shape[0], precision=_HIGHEST)
 
 
+@phase("kfac_capture")
 def compute_g_conv_grouped(
     g: jnp.ndarray, groups: int, batch_averaged: bool
 ) -> jnp.ndarray:

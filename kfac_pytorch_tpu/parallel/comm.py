@@ -46,6 +46,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from kfac_pytorch_tpu import capture, compat
+from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.observability.telemetry import get_telemetry
 from kfac_pytorch_tpu.ops import factors as factor_ops
 from kfac_pytorch_tpu.parallel.assignment import (
@@ -367,7 +368,7 @@ class FactorComm:
                 "reduce int8 codes"
             )
         leaves, treedef = jax.tree_util.tree_flatten(tree)
-        with get_telemetry().span("trace/kfac/factor_comm"):
+        with phase("kfac_exchange", "trace/kfac/factor_comm"):
             plan = self._plan_for(leaves)
             wire_dtype = None if self.comm_dtype == _F32 else self.comm_dtype
             bufs = flatten_buckets(leaves, plan)
@@ -462,8 +463,7 @@ class FactorComm:
         axis = self.axis_name
         world = self._axis_world(axis)
         leaves, treedef = jax.tree_util.tree_flatten(tree)
-        tel = get_telemetry()
-        with tel.span("trace/kfac/factor_comm"):
+        with phase("kfac_exchange", "trace/kfac/factor_comm"):
             plan = self._plan_for(leaves)
             bufs = flatten_buckets(leaves, plan)
             base = jax.random.fold_in(
@@ -598,7 +598,7 @@ class FactorComm:
                     )
                 groups[key] = flat.reshape(world, rows * elems)
             new_shard = dict(shard)
-            with get_telemetry().span("trace/kfac/factor_comm"):
+            with phase("kfac_exchange", "trace/kfac/factor_comm"):
                 for bucket in plan.wire_buckets:
                     parts = [
                         groups[wgroups[e.index][0]] for e in bucket.entries

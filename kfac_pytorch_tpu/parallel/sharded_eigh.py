@@ -36,7 +36,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kfac_pytorch_tpu import compat
-from kfac_pytorch_tpu.observability.telemetry import get_telemetry
+from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.ops.eigh import (
     batched_eigh,
     bucket_size,
@@ -300,17 +300,17 @@ def sharded_eigen_update(
         check_vma=False,
     )
     def _inner(facs):
-        # trace-time spans only (we are inside shard_map/jit): they cost
-        # nothing in the compiled program but let the telemetry view show
-        # how much of an eigen-step's TRACE time is eigh vs exchange logic
-        tel = get_telemetry()
+        # phase() marks eigh vs exchange on both clocks (observability/
+        # phases.py): a named scope on the ops for the device trace, and a
+        # trace-time telemetry span (we are inside shard_map/jit); neither
+        # costs anything in the compiled program
         # flat device index over ALL mesh axes, row-major in axis_names order
         dev = lax.axis_index(axes[0])
         for a in axes[1:]:
             dev = dev * mesh.shape[a] + lax.axis_index(a)
         per_slot: Dict[int, Tuple[jnp.ndarray, ...]] = {}
         for m, idxs in groups.items():
-            with tel.span("trace/eigh/compute"):
+            with phase("kfac_refresh", "trace/eigh/compute"):
                 all_blocks = _padded_stack(facs, slots, idxs, m)  # [k, m, m]
                 idx_tab, valid = tables[m]
                 mine = jnp.take(idx_tab, dev, axis=0)  # [rows]
@@ -320,7 +320,7 @@ def sharded_eigen_update(
                 q = q * vmask[:, None, None]
                 d = d * vmask[:, None]
             k = len(idxs)
-            with tel.span("trace/eigh/exchange"):
+            with phase("kfac_exchange", "trace/eigh/exchange"):
                 # Sum-of-zeros exchange: scatter-add my rows, psum the rest in.
                 kq = jnp.zeros((k, m, m), jnp.float32).at[mine].add(q)
                 kd = jnp.zeros((k, m), jnp.float32).at[mine].add(d)
@@ -329,7 +329,7 @@ def sharded_eigen_update(
             for row, i in enumerate(idxs):
                 per_slot[i] = unpad_eigh(kq[row], kd[row], slots[i].size, eps)
         for (m, rank), idxs in lr_groups.items():
-            with tel.span("trace/eigh/compute"):
+            with phase("kfac_refresh", "trace/eigh/compute"):
                 all_blocks = _rsvd_stack(facs, slots, idxs, m)  # [k, m, m]
                 idx_tab, valid = lr_tables[(m, rank)]
                 mine = jnp.take(idx_tab, dev, axis=0)
@@ -339,7 +339,7 @@ def sharded_eigen_update(
                 q = q * vmask[:, None, None]
                 d = d * vmask[:, None]
             k = len(idxs)
-            with tel.span("trace/eigh/exchange"):
+            with phase("kfac_exchange", "trace/eigh/exchange"):
                 kq = jnp.zeros((k, m, rank), jnp.float32).at[mine].add(q)
                 kd = jnp.zeros((k, rank), jnp.float32).at[mine].add(d)
                 kq = lax.psum(kq, axes)
@@ -436,13 +436,12 @@ def sharded_eigen_chunk_update(
         check_vma=False,
     )
     def _inner(facs):
-        tel = get_telemetry()
         dev = lax.axis_index(axes[0])
         for a in axes[1:]:
             dev = dev * mesh.shape[a] + lax.axis_index(a)
         per_slot: Dict[int, Tuple[jnp.ndarray, ...]] = {}
         for m, idxs in groups.items():
-            with tel.span("trace/eigh/compute"):
+            with phase("kfac_refresh", "trace/eigh/compute"):
                 all_blocks = _padded_stack(facs, slots, idxs, m)  # [k, m, m]
                 idx_tab, valid = tables[m]
                 mine = jnp.take(idx_tab, dev, axis=0)
@@ -452,7 +451,7 @@ def sharded_eigen_chunk_update(
                 q = q * vmask[:, None, None]
                 d = d * vmask[:, None]
             k = len(idxs)
-            with tel.span("trace/eigh/exchange"):
+            with phase("kfac_exchange", "trace/eigh/exchange"):
                 kq = jnp.zeros((k, m, m), jnp.float32).at[mine].add(q)
                 kd = jnp.zeros((k, m), jnp.float32).at[mine].add(d)
                 kq = lax.psum(kq, axes)
@@ -460,7 +459,7 @@ def sharded_eigen_chunk_update(
             for row, i in enumerate(idxs):
                 per_slot[i] = unpad_eigh(kq[row], kd[row], slots[i].size, eps)
         for (m, rank), idxs in lr_groups.items():
-            with tel.span("trace/eigh/compute"):
+            with phase("kfac_refresh", "trace/eigh/compute"):
                 all_blocks = _rsvd_stack(facs, slots, idxs, m)
                 idx_tab, valid = lr_tables[(m, rank)]
                 mine = jnp.take(idx_tab, dev, axis=0)
@@ -470,7 +469,7 @@ def sharded_eigen_chunk_update(
                 q = q * vmask[:, None, None]
                 d = d * vmask[:, None]
             k = len(idxs)
-            with tel.span("trace/eigh/exchange"):
+            with phase("kfac_exchange", "trace/eigh/exchange"):
                 kq = jnp.zeros((k, m, rank), jnp.float32).at[mine].add(q)
                 kd = jnp.zeros((k, rank), jnp.float32).at[mine].add(d)
                 kq = lax.psum(kq, axes)
@@ -613,11 +612,10 @@ def owner_eigen_update(
         check_vma=False,
     )
     def _inner(shard):
-        tel = get_telemetry()
         out = {}
         for n in plan.group_sizes:
             rank = rank_fn(n) if rank_fn is not None else None
-            with tel.span("trace/eigh/compute"):
+            with phase("kfac_refresh", "trace/eigh/compute"):
                 out[f"n{n}"] = _owner_group_solve(
                     shard[f"n{n}"], n, rank, eps, granularity, minimum,
                     eigen_dtype,
@@ -679,12 +677,11 @@ def owner_eigen_chunk_update(
         check_vma=False,
     )
     def _inner(shard, pending):
-        tel = get_telemetry()
         out = {k: dict(v) for k, v in pending.items()}
         for n in sorted(by_group):
             rows = jnp.asarray(sorted(by_group[n]), jnp.int32)
             rank = rank_fn(n) if rank_fn is not None else None
-            with tel.span("trace/eigh/compute"):
+            with phase("kfac_refresh", "trace/eigh/compute"):
                 sub = jnp.take(shard[f"n{n}"], rows, axis=0)
                 res = _owner_group_solve(
                     sub, n, rank, eps, granularity, minimum, eigen_dtype

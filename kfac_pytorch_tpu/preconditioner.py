@@ -41,6 +41,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from kfac_pytorch_tpu import capture, shardwise
+from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.observability.telemetry import get_telemetry
 from kfac_pytorch_tpu.ops import apply_kernels as apply_kernel_ops
 from kfac_pytorch_tpu.ops import factor_kernels as factor_kernel_ops
@@ -1745,11 +1746,12 @@ class KFAC:
                 node = node[k]
             is_conv[name] = "kernel" in node and node["kernel"].ndim == 4
 
-        # Spans here run at TRACE time (update() executes inside jit): they
-        # measure per-phase tracing cost and emit NO ops into the program —
-        # device-side phase costs come from the host-side step-variant spans
-        # plus bench.py's variant deltas (docs/OBSERVABILITY.md).
-        tel = get_telemetry()
+        # phase() marks each K-FAC phase on both clocks (observability/
+        # phases.py): a named scope on the ops traced inside, from which a
+        # device trace is split by phase (observability/device_phases.py),
+        # and a telemetry span that runs at TRACE time (update() executes
+        # inside jit) and measures per-phase tracing cost. Neither emits an
+        # op into the program (docs/OBSERVABILITY.md).
 
         facs = state["factors"]
         if update_factors:
@@ -1770,7 +1772,7 @@ class KFAC:
             # the column/row shard stacks (update_running_avg broadcasts
             # over the stack dim). Only MoE diverges: its token-count-
             # weighted per-expert decay routes through shardwise.ema_update.
-            with tel.span("trace/kfac/factor_update"):
+            with phase("kfac_capture", "trace/kfac/factor_update"):
                 old_facs = facs
                 facs = {}
                 for name in names:
@@ -1834,7 +1836,7 @@ class KFAC:
             self.comm_overlap and eigen_chunk is not None and not swap_eigen
         )
         if precond_early:
-            with tel.span("trace/kfac/precondition"):
+            with phase("kfac_apply", "trace/kfac/precondition"):
                 new_grads, gmats, updates, nu = self._precondition_replicated(
                     grads, names, facs, eigen, stacked, lr, damping
                 )
@@ -1846,7 +1848,7 @@ class KFAC:
             # kfac_update_freq amortization sharding it is not worth an
             # exchange; the EVERY-STEP solve still shards via
             # distribute_precondition.
-            with tel.span("trace/kfac/eigh"):
+            with phase("kfac_refresh", "trace/kfac/eigh"):
                 inv = precond_ops.factored_inverse_all(
                     facs, jnp.asarray(damping, jnp.float32), self.eps
                 )
@@ -1869,7 +1871,7 @@ class KFAC:
             diag_blocks = self.diag_blocks if diag_warmup_done else 1
             world = self._world()
             norm_facs = {n: facs[n] for n in norm_names}
-            with tel.span("trace/kfac/eigh"):
+            with phase("kfac_refresh", "trace/kfac/eigh"):
                 if not norm_facs:
                     eigen = {}
                 elif world > 1:
@@ -1972,7 +1974,7 @@ class KFAC:
                 # off-block regions must not inherit a previous interval's
                 # values when diag_blocks (warmup) shifts block boundaries.
                 pending = jax.tree_util.tree_map(jnp.zeros_like, pending)
-            with tel.span("trace/kfac/eigh"):
+            with phase("kfac_refresh", "trace/kfac/eigh"):
                 if chunk_slots:
                     if world > 1:
                         pending = sharded_eigen_chunk_update(
@@ -2046,7 +2048,7 @@ class KFAC:
             elif update_factors and (
                 not self.factor_comm.defer or flush_factors
             ):
-                with tel.span("trace/kfac/stream_fold"):
+                with phase("kfac_refresh", "trace/kfac/stream_fold"):
                     eigen, stacked, stream_residual = (
                         streaming_ops.fold_replicated(
                             facs, eigen, stacked, self.eps
@@ -2057,7 +2059,7 @@ class KFAC:
         # Precondition every layer's gradient, every step
         # (kfac_preconditioner.py:401-404) — batched over same-shape layers.
         if not precond_early:
-            with tel.span("trace/kfac/precondition"):
+            with phase("kfac_apply", "trace/kfac/precondition"):
                 new_grads, gmats, updates, nu = self._precondition_replicated(
                     grads, names, facs, eigen, stacked, lr, damping
                 )
@@ -2226,7 +2228,6 @@ class KFAC:
           ``precondition_all``'s emission order so the KL-clip summation
           reassociates identically.
         """
-        tel = get_telemetry()
         names = list(state["factors"].keys())
         lgrads = capture.layer_grads(grads, names)
         gmats = {
@@ -2262,7 +2263,7 @@ class KFAC:
                     "capture-aware — construct KFAC(layers=capture."
                     "discover_layers(model, ...)) so init() matches capture."
                 )
-            with tel.span("trace/kfac/factor_update"):
+            with phase("kfac_capture", "trace/kfac/factor_update"):
                 if self.factor_comm.defer:
                     # local-only EMA delta since the last flush (starts from
                     # zero, NOT from the master copy — non-owners hold none)
@@ -2313,12 +2314,12 @@ class KFAC:
             self.comm_overlap and eigen_chunk is not None and not swap_eigen
         )
         if precond_early:
-            with tel.span("trace/kfac/precondition"):
+            with phase("kfac_apply", "trace/kfac/precondition"):
                 new_grads = self._precondition_owner(
                     grads, gmats, eigen_shard, lr, damping, plan
                 )
         if update_eigen:
-            with tel.span("trace/kfac/eigh"):
+            with phase("kfac_refresh", "trace/kfac/eigh"):
                 eigen_shard = {
                     **owner_eigen_update(
                         shard,
@@ -2347,7 +2348,7 @@ class KFAC:
                 # fresh interval: zero the double buffer, mirroring the
                 # replicated chunk path's from-zeros _assemble contract
                 pending = jax.tree_util.tree_map(jnp.zeros_like, pending)
-            with tel.span("trace/kfac/eigh"):
+            with phase("kfac_refresh", "trace/kfac/eigh"):
                 pending = owner_eigen_chunk_update(
                     shard,
                     pending,
@@ -2404,7 +2405,7 @@ class KFAC:
             elif update_factors and (
                 not self.factor_comm.defer or flush_factors
             ):
-                with tel.span("trace/kfac/stream_fold"):
+                with phase("kfac_refresh", "trace/kfac/stream_fold"):
                     eigen_shard, stream_residual = owner_stream_fold(
                         shard,
                         eigen_shard,
@@ -2417,7 +2418,7 @@ class KFAC:
                 stream_fold_steps = state["stream_fold_steps"] + 1
 
         if not precond_early:
-            with tel.span("trace/kfac/precondition"):
+            with phase("kfac_apply", "trace/kfac/precondition"):
                 new_grads = self._precondition_owner(
                     grads, gmats, eigen_shard, lr, damping, plan
                 )

@@ -20,6 +20,7 @@ import optax
 from kfac_pytorch_tpu import capture, compat
 from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
+from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels
 from kfac_pytorch_tpu.preconditioner import KFAC
 from kfac_pytorch_tpu.training.step import (
@@ -93,9 +94,10 @@ def make_lm_train_step(
             )
             return loss, new_carry
 
-        (loss, new_carry), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params
-        )
+        with phase("model"):
+            (loss, new_carry), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(params)
         return loss, grads, None, None, new_carry
 
     def _compute_captured(params, tokens, targets, carry, rngs):
@@ -115,9 +117,10 @@ def make_lm_train_step(
             )
             return loss, (mut, new_carry)
 
-        (loss, (mut, new_carry)), (grads, gperts) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1), has_aux=True
-        )(params, perts)
+        with phase("model"):
+            (loss, (mut, new_carry)), (grads, gperts) = jax.value_and_grad(
+                loss_fn, argnums=(0, 1), has_aux=True
+            )(params, perts)
         names = (
             kfac.layers
             if kfac.layers is not None
@@ -218,7 +221,8 @@ def make_lm_train_step(
         )
 
         if grad_clip:
-            grads = _clip_by_global_norm(grads, grad_clip)
+            with phase("grad_clip"):
+                grads = _clip_by_global_norm(grads, grad_clip)
 
         kfac_state = state.kfac_state
         if kfac is not None:
@@ -260,11 +264,12 @@ def make_lm_train_step(
                 for i, s in enumerate(state.opt_state)
             )
         else:
-            updates, opt_state = tx.update(
-                grads, state.opt_state, state.params
-            )
-            updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
-            params = optax.apply_updates(state.params, updates)
+            with phase("optimizer"):
+                updates, opt_state = tx.update(
+                    grads, state.opt_state, state.params
+                )
+                updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
+                params = optax.apply_updates(state.params, updates)
 
         metrics = {"loss": loss, "ppl": jnp.exp(loss)}
         if kfac is not None and kfac.track_diagnostics:

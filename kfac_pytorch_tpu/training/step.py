@@ -27,6 +27,7 @@ from jax import lax
 from kfac_pytorch_tpu import capture, compat
 from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
+from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels
 from kfac_pytorch_tpu.preconditioner import KFAC
 
@@ -364,9 +365,10 @@ def make_train_step(
             loss = softmax_cross_entropy(logits, labels, label_smoothing)
             return loss, (mut, logits)
 
-        (loss, (mut, logits)), (grads, gperts) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1), has_aux=True
-        )(params, perts)
+        with phase("model"):
+            (loss, (mut, logits)), (grads, gperts) = jax.value_and_grad(
+                loss_fn, argnums=(0, 1), has_aux=True
+            )(params, perts)
         if kfac is not None and kfac.layers is not None:
             names = kfac.layers
         else:
@@ -382,7 +384,10 @@ def make_train_step(
             gperts, names, batch_averaged=ba, captured=mut[KFAC_ACTS]
         )
         new_bs = mut.get("batch_stats", batch_stats)
-        acc = jnp.mean((jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))
+        with phase("model"):  # the accuracy reads the forward pass's logits
+            acc = jnp.mean(
+                (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
+            )
         return loss, acc, grads, new_bs, a_c, g_s
 
     def loss_and_grads_plain(params, batch_stats, images, labels):
@@ -409,13 +414,18 @@ def make_train_step(
             loss = softmax_cross_entropy(logits, labels, label_smoothing)
             return loss, (mut, logits)
 
-        (loss, (mut, logits)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params
-        )
+        with phase("model"):
+            (loss, (mut, logits)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(params)
         new_bs = mut.get("batch_stats", batch_stats)
-        acc = jnp.mean((jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))
+        with phase("model"):  # the accuracy reads the forward pass's logits
+            acc = jnp.mean(
+                (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
+            )
         return loss, acc, grads, new_bs, None, None
 
+    @phase("model")
     def accum_loss_and_grads(params, batch_stats, images, labels, capture_stats):
         # images/labels: [accum_steps, microbatch, ...]; BN stats thread
         # sequentially through microbatches like the reference's sub-batch
@@ -451,6 +461,7 @@ def make_train_step(
         grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
         return lsum * inv, asum * inv, grads, bs, a_c, g_s
 
+    @phase("model")
     def accum_loss_and_grads_all_stats(params, batch_stats, images, labels):
         # stats_all_microbatches path: capture runs in EVERY scan iteration
         # and the per-microbatch factor statistics are averaged (== the
@@ -470,8 +481,9 @@ def make_train_step(
                 params, bs, im, lb
             )
             gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
-            a_sum = jax.tree_util.tree_map(jnp.add, a_sum, a_c)
-            g_sum = jax.tree_util.tree_map(jnp.add, g_sum, g_s)
+            with phase("kfac_capture"):
+                a_sum = jax.tree_util.tree_map(jnp.add, a_sum, a_c)
+                g_sum = jax.tree_util.tree_map(jnp.add, g_sum, g_s)
             return (new_bs, gsum, lsum + loss, asum + acc, a_sum, g_sum), None
 
         carry = (
@@ -487,8 +499,9 @@ def make_train_step(
         )
         inv = 1.0 / accum_steps
         grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
-        a_c = jax.tree_util.tree_map(lambda a: a * inv, a_sum)
-        g_s = jax.tree_util.tree_map(lambda g: g * inv, g_sum)
+        with phase("kfac_capture"):
+            a_c = jax.tree_util.tree_map(lambda a: a * inv, a_sum)
+            g_s = jax.tree_util.tree_map(lambda g: g * inv, g_sum)
         return lsum * inv, asum * inv, grads, bs, a_c, g_s
 
     def train_step(
@@ -543,7 +556,8 @@ def make_train_step(
         if grad_clip:
             # between grad averaging and preconditioning, the reference's
             # clip point (pytorch_wikitext_rnn.py:297-300)
-            grads = clip_by_global_norm(grads, grad_clip)
+            with phase("grad_clip"):
+                grads = clip_by_global_norm(grads, grad_clip)
 
         kfac_state = state.kfac_state
         if kfac is not None:
@@ -586,11 +600,12 @@ def make_train_step(
                 for i, s in enumerate(state.opt_state)
             )
         else:
-            updates, opt_state = tx.update(
-                grads, state.opt_state, state.params
-            )
-            updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
-            params = optax.apply_updates(state.params, updates)
+            with phase("optimizer"):
+                updates, opt_state = tx.update(
+                    grads, state.opt_state, state.params
+                )
+                updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
+                params = optax.apply_updates(state.params, updates)
 
         metrics = {"loss": loss, "accuracy": acc}
         if kfac is not None and kfac.track_diagnostics:

@@ -2,6 +2,7 @@
 """Lint: emitted telemetry names ↔ docs/OBSERVABILITY.md registry, both ways.
 
 Every metric name passed to ``span(``/``inc(``/``set_gauge(``/``observe(``
+(or as the span of ``phase(<phase>, <span>)``, observability/phases.py)
 anywhere in ``kfac_pytorch_tpu/``, ``examples/``, or ``bench.py`` must be a
 string LITERAL (policy — keeps this lint sound) and must appear in the
 registry table between the ``metric-registry:start``/``end`` markers of
@@ -23,7 +24,10 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 DOC = ROOT / "docs" / "OBSERVABILITY.md"
 SCAN = ["kfac_pytorch_tpu", "examples", "bench.py"]
 
-CALL_RE = re.compile(r"\b(?:span|inc|set_gauge|observe)\(\s*['\"]([^'\"]+)['\"]")
+CALL_RE = re.compile(
+    r"\b(?:(?:span|inc|set_gauge|observe)\(|phase\(\s*['\"][^'\"]+['\"]\s*,)"
+    r"\s*['\"]([^'\"]+)['\"]"
+)
 ROW_RE = re.compile(r"^\|\s*`([^`]+)`\s*\|")
 
 
