@@ -23,6 +23,20 @@ Layout conventions (TPU/flax native, NOT torch):
 All matmuls feeding factors use ``lax.Precision.HIGHEST`` so TPU bf16 matmul
 defaults cannot corrupt the eigendecompositions downstream.
 
+Every factor is a Gram matrix ``yᵀ·(y·s)`` and so symmetric. The dense and
+conv forms (``compute_a_dense``, ``compute_a_conv``, ``compute_g_dense``,
+``compute_g_conv``) are formed in one helper, :func:`_gram`: below
+``_GRAM_MIN_SIDE`` columns the single product the reference has, bit for
+bit; from there on :func:`gram_blocks` (two Pallas kernels) multiplies only
+the column-block pairs on and above the diagonal (``(k+1)/2k`` of the
+multiply-adds for ``k`` blocks, same precision, same rows) and mirrors them,
+and a bias column is not multiplied at all: its row and column are column
+sums. Block width and row tile follow the operand's shape alone; there is no
+option. The batched einsum forms (row / column shards, MoE experts, grouped
+convs' G side) keep their full products: their sides are small by
+construction. Rationale in counts: docs/PERF.md, "Symmetric factor
+products"; the chip's readings: root PERF.md, PR 26.
+
 Every ``compute_a_*`` / ``compute_g_*`` runs under the ``kfac_capture`` phase
 scope (observability/phases.py): the A products are sown from inside the
 model's forward pass, and a device trace tells them from the model's own ops
@@ -31,15 +45,32 @@ by that name alone.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Sequence, Tuple, Union
+import functools
+import operator
+from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from kfac_pytorch_tpu.observability.phases import phase
+from kfac_pytorch_tpu.observability.telemetry import get_telemetry
 
 _HIGHEST = lax.Precision.HIGHEST
+
+# A factor whose operand has at least _GRAM_MIN_SIDE columns is formed from
+# blocks of _GRAM_BLOCK columns, over row tiles of the largest of
+# _GRAM_ROW_TILES that divides the rows; a narrower one by the single product it
+# always was. Chosen on the v5e over 8192 rows (scripts/gram_block_sweep.py;
+# root PERF.md, PR 26): blocks of 256 columns over 1024 rows read fastest at the
+# sides measured (0.59 of the full product's time at 3072, 0.61 at 2304, 0.75 at
+# 768; 384, 512, 768 and 1024 columns and tiles of 512 rows all read slower),
+# and a side of 512 read 0.92 of it, which is not worth the second kernel.
+_GRAM_MIN_SIDE = 768
+_GRAM_BLOCK = 256
+_GRAM_ROW_TILES = (1024, 512, 256, 128)
 
 Padding = Union[str, Sequence[Tuple[int, int]]]
 
@@ -84,6 +115,199 @@ def _flatten_leading(x: jnp.ndarray) -> jnp.ndarray:
     return x.reshape(-1, x.shape[-1])
 
 
+def _gram_tiles(n: int, d: int) -> Optional[Tuple[int, int]]:
+    """``(columns per block, rows per tile)`` for an ``[n, d]`` operand, or
+    None where the factor stays one product: a narrow side, rows that no tile
+    divides, or a program over several devices (a Mosaic call has no
+    partitioning rule, so GSPMD would gather the operand onto every device;
+    cf. ``flash_attention.best_attention_fn``)."""
+    if d < _GRAM_MIN_SIDE or jax.device_count() != 1:
+        return None
+    if n <= _GRAM_ROW_TILES[0]:
+        return _GRAM_BLOCK, n  # one tile: the whole dimension is always a legal block
+    for rows in _GRAM_ROW_TILES:
+        if n % rows == 0:
+            return _GRAM_BLOCK, rows
+    return None
+
+
+# Trace-time counts of the factor products formed since the last
+# :func:`reset_capture_tally`: how many took the blocked form, and the
+# multiply-adds issued against those of the full ``side x side`` products.
+_TALLY = {"blocked": 0, "issued": 0, "full": 0}
+
+
+def reset_capture_tally() -> None:
+    """Start the counts behind ``kfac/capture_gram_blocked`` and
+    ``kfac/capture_flops_share`` anew. The step builders call it where the
+    capture of one step program starts to trace, so the gauges describe that
+    program."""
+    _TALLY.update(blocked=0, issued=0, full=0)
+
+
+Scaling = Tuple[Tuple[str, float], ...]  # (("div", n), ("mul", s), ...), applied in order
+
+
+def _scaled(y: jnp.ndarray, steps: Scaling) -> jnp.ndarray:
+    for op, s in steps:
+        y = {"mul": operator.mul, "div": operator.truediv}[op](y, s)
+    return y
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "block", "rows", "pre", "border", "interpret")
+)
+def gram_blocks(
+    x: jnp.ndarray,
+    scale: Scaling,
+    block: int,
+    rows: int,
+    pre: Scaling = (),
+    border: int = 0,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """``yᵀ · scaled(y, scale)``, ``y = scaled(x, pre)``, from the column-block
+    pairs on and above the diagonal (the scalings are applied to the blocks as
+    they are read, so ``y`` is never written out).
+
+    Two Pallas kernels. The first visits the ``k(k+1)/2`` pairs ``i <= j`` of
+    the ``k = ⌈d/block⌉`` column blocks and, for each, sums
+    ``y[r, i]ᵀ · scale(y[r, j])`` over the row tiles ``r`` into block
+    ``(i, j)`` of the result, at ``HIGHEST`` in float32 like the full product;
+    a diagonal block is mirrored about its own diagonal when its last tile is
+    in. The second writes the transpose of every block above the diagonal
+    into its place below it, in the same buffer. The result is exactly
+    symmetric. ``rows`` divides ``x.shape[0]``; the last column block may
+    overhang (what it reads past the edge lands past the edge and is dropped).
+    ``border`` leaves that many rows and columns past ``d`` in the result for
+    the caller to fill (the bias row and column; unwritten, or holding what
+    overhung). ``interpret`` defaults to the Pallas interpreter off the TPU.
+    Jitted with the scalings static: a step program lowers each distinct
+    kernel once, not once per layer (lowering 192 Pallas calls cost GPT-2's
+    cell 16 s of set-up).
+    """
+    n, d = x.shape
+    side = d + border
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    k = -(-d // block)
+    pairs = [(i, j) for i in range(k) for j in range(i, k)]
+    above = [(i, j) for i, j in pairs if i < j]
+
+    def table(ps, axis):
+        return jnp.asarray([p[axis] for p in ps], jnp.int32)
+
+    def products(ii, jj, a_ref, c_ref, o_ref):
+        p, r = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(r == 0)
+        def _():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        o_ref[...] += lax.dot_general(
+            _scaled(a_ref[...], pre),
+            _scaled(c_ref[...], pre + scale),
+            (((0,), (0,)), ((), ())),
+            precision=_HIGHEST,
+            preferred_element_type=o_ref.dtype,
+        )
+
+        @pl.when((r == pl.num_programs(1) - 1) & (ii[p] == jj[p]))
+        def _():
+            o = o_ref[...]
+            row = lax.broadcasted_iota(jnp.int32, o.shape, 0)
+            col = lax.broadcasted_iota(jnp.int32, o.shape, 1)
+            o_ref[...] = jnp.where(row <= col, o, o.T)
+
+    upper = pl.pallas_call(
+        products,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(len(pairs), n // rows),
+            in_specs=[
+                pl.BlockSpec((rows, block), lambda p, r, ii, jj: (r, ii[p])),
+                pl.BlockSpec((rows, block), lambda p, r, ii, jj: (r, jj[p])),
+            ],
+            out_specs=pl.BlockSpec((block, block), lambda p, r, ii, jj: (ii[p], jj[p])),
+        ),
+        out_shape=jax.ShapeDtypeStruct((side, side), x.dtype),
+        interpret=interpret,
+    )(table(pairs, 0), table(pairs, 1), x, x)
+    if not above:
+        return upper
+
+    def mirror(ii, jj, u_ref, o_ref):
+        o_ref[...] = u_ref[...].T
+
+    return pl.pallas_call(
+        mirror,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(len(above),),
+            in_specs=[pl.BlockSpec((block, block), lambda p, ii, jj: (ii[p], jj[p]))],
+            out_specs=pl.BlockSpec((block, block), lambda p, ii, jj: (jj[p], ii[p])),
+        ),
+        out_shape=jax.ShapeDtypeStruct((side, side), x.dtype),
+        input_output_aliases={2: 0},  # the blocks it does not visit stay
+        interpret=interpret,
+    )(table(above, 0), table(above, 1), upper)
+
+
+def _gram(
+    x: jnp.ndarray,
+    scale: Scaling,
+    *,
+    pre: Scaling = (),
+    corner: Optional[float] = None,
+    tiles: Optional[Tuple[int, int]] = None,
+) -> jnp.ndarray:
+    """``yᵀ · scaled(y, scale)`` for ``y = scaled([x, 1], pre)``: the one place
+    a factor is formed.
+
+    ``x`` is ``[N, d]``; ``scale`` and ``pre`` multiply or divide by scalars.
+    ``corner`` says that the factor has a bias column (a column of ones
+    appended to ``x`` before ``pre``) and gives the closed form of its
+    bias-bias entry ``N · pre(1) · scale(pre(1))``.
+
+    Where :func:`_gram_tiles` gives no tiling this is the single product at
+    ``HIGHEST`` it always was, bit for bit. Where it does, the result, a Gram
+    matrix and so symmetric, is formed by :func:`gram_blocks` from the
+    column-block pairs on and above the diagonal alone: ``(k+1)/2k`` of the
+    multiply-adds for ``k`` blocks, at the same precision over the same rows.
+    The bias column is then not multiplied: its row and column are the column
+    sums of ``y``. ``tiles`` overrides :func:`_gram_tiles` (the sweep that
+    chose the constants, scripts/gram_block_sweep.py; ``()`` = one product).
+    """
+    n, d = x.shape
+    side = d + (corner is not None)
+    if tiles is None:
+        tiles = _gram_tiles(n, d)
+    _TALLY["full"] += n * side * side
+    if not tiles:
+        if corner is not None:
+            x = jnp.concatenate([x, jnp.ones((n, 1), dtype=x.dtype)], axis=1)
+        y = _scaled(x, pre)
+        out = jnp.matmul(y.T, _scaled(y, scale), precision=_HIGHEST)
+        _TALLY["issued"] += n * side * side
+    else:
+        block = tiles[0]
+        k = -(-d // block)
+        # a factor is a statistic, never differentiated: the A side is traced
+        # inside the differentiated forward pass, and the kernels have no JVP
+        x = lax.stop_gradient(x)
+        out = gram_blocks(x, scale, *tiles, pre=pre, border=side - d)
+        if corner is not None:
+            one = jnp.ones((), dtype=x.dtype)
+            sums = jnp.sum(_scaled(x, pre), axis=0) * _scaled(one, pre + scale)
+            out = out.at[:d, d].set(sums).at[d, :d].set(sums).at[d, d].set(corner)
+        _TALLY["issued"] += n * block * block * k * (k + 1) // 2
+        _TALLY["blocked"] += 1
+    tel = get_telemetry()
+    tel.set_gauge("kfac/capture_gram_blocked", _TALLY["blocked"])
+    tel.set_gauge("kfac/capture_flops_share", _TALLY["issued"] / _TALLY["full"])
+    return out
+
+
 @phase("kfac_capture")
 def compute_a_dense(a: jnp.ndarray, has_bias: bool) -> jnp.ndarray:
     """Input covariance for a dense layer: ``A = aᵀ (a / N)``.
@@ -93,10 +317,7 @@ def compute_a_dense(a: jnp.ndarray, has_bias: bool) -> jnp.ndarray:
     """
     a = _flatten_leading(a)
     n = a.shape[0]
-    if has_bias:
-        ones = jnp.ones((n, 1), dtype=a.dtype)
-        a = jnp.concatenate([a, ones], axis=1)
-    return jnp.matmul(a.T, a / n, precision=_HIGHEST)
+    return _gram(a, (("div", n),), corner=1.0 if has_bias else None)
 
 
 @phase("kfac_capture")
@@ -119,11 +340,12 @@ def compute_a_conv(
     patches = extract_patches(a, kernel_size, strides, padding, kernel_dilation)
     spatial_size = patches.shape[1] * patches.shape[2]
     p = patches.reshape(-1, patches.shape[-1])
-    if has_bias:
-        ones = jnp.ones((p.shape[0], 1), dtype=p.dtype)
-        p = jnp.concatenate([p, ones], axis=1)
-    p = p / spatial_size
-    return jnp.matmul(p.T, p / batch_size, precision=_HIGHEST)
+    return _gram(
+        p,
+        (("div", batch_size),),
+        pre=(("div", spatial_size),),
+        corner=1.0 / spatial_size if has_bias else None,
+    )
 
 
 @phase("kfac_capture")
@@ -271,9 +493,7 @@ def compute_g_dense(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     """
     g = _flatten_leading(g)
     n = g.shape[0]
-    if batch_averaged:
-        return jnp.matmul(g.T, g * n, precision=_HIGHEST)
-    return jnp.matmul(g.T, g / n, precision=_HIGHEST)
+    return _gram(g, (("mul" if batch_averaged else "div", n),))
 
 
 @phase("kfac_capture")
@@ -345,7 +565,8 @@ def compute_g_conv(g: jnp.ndarray, batch_averaged: bool) -> jnp.ndarray:
     if batch_averaged:
         gm = gm * batch_size
     gm = gm * spatial_size
-    return jnp.matmul(gm.T, gm / gm.shape[0], precision=_HIGHEST)
+    rows = gm.shape[0]
+    return _gram(gm, (("div", rows),))
 
 
 @phase("kfac_capture")

@@ -21,7 +21,7 @@ from kfac_pytorch_tpu import capture, compat
 from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
 from kfac_pytorch_tpu.observability.phases import phase
-from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels
+from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels, factors
 from kfac_pytorch_tpu.preconditioner import KFAC
 from kfac_pytorch_tpu.training.step import (
     TrainState,
@@ -102,6 +102,7 @@ def make_lm_train_step(
 
     def _compute_captured(params, tokens, targets, carry, rngs):
         perts = capture.perturbation_zeros(model, tokens, train=True)
+        factors.reset_capture_tally()  # the gauges count this program's products
 
         def loss_fn(params, perts):
             (logits, new_carry), mut = model.apply(

@@ -15,7 +15,9 @@ compile runs in this process, and whole step programs — minutes each — stay
 in scripts/compile_for_chip.py.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +96,36 @@ def test_dense_conv_factor_compiles(one_chip):
         kernel_size=(3, 3), strides=(1, 1), padding="SAME", has_bias=False,
     )
     assert "tpu_custom_call" not in hlo
+
+
+@pytest.mark.parametrize("rows,side,has_bias", [
+    (8192, 3072, False),  # GPT-2's widest factor: G of `fc`
+    (8192, 3072, True),  # A of `mlp proj`, 3073 with its bias column
+    (8192, 768, True),  # the narrowest side that takes the blocked form
+    (6272, 4608, False),  # ResNet-50's widest: 128 x 7 x 7 rows in tiles of 128
+    (8192, 1152, False),  # a last block of 128 columns that overhangs
+    (392, 2304, True),  # few rows (8 x 7 x 7): one tile of all of them
+])
+def test_blocked_factor_product_compiles_and_copies_no_slice(one_chip, monkeypatch, rows, side, has_bias):
+    """ops/factors.py::_gram where the factor is formed from the column-block
+    pairs on and above the diagonal: two Mosaic calls (products, mirror) the
+    chip's compiler accepts, no product outside them, and nothing that would
+    eat the gain: no column slice, no scaled or transposed copy of the operand
+    written out. The kernels read their blocks of the operand in place, so the
+    only values with `rows` rows are the operand and a prefetch of the whole of it."""
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    # a described chip leaves jax.default_backend() at "cpu": compile, not interpret
+    monkeypatch.setattr(factors, "gram_blocks", functools.partial(factors.gram_blocks, interpret=False))
+    assert factors._gram_tiles(rows, side) is not None
+    hlo = _compile(factors.compute_a_dense, one_chip, _f32(rows, side), has_bias=has_bias)
+    entry = hlo[hlo.index("\nENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 2
+    assert "convolution" not in hlo and " dot(" not in hlo
+    tall = re.findall(rf"^\s*(?:ROOT )?%\S+ = \(?f32\[{rows},(\d+)\]\S* ([\w-]+)\(", entry, re.M)
+    # (with a bias column the compiler prefetches the whole operand, asynchronously,
+    # into the nearer memory space for the column sums: not a slice, not a transpose)
+    assert {cols for cols, _ in tall} == {str(side)}, tall
+    assert {op for _, op in tall} <= {"parameter", "copy-start", "copy-done"}, tall
 
 
 def test_eigh_smallest_bucket_compiles(one_chip):
