@@ -1,6 +1,6 @@
 """Builder for decoder-only LM configurations. ``examples/
 train_transformer_lm.py`` has no ``build()`` the benchmark can hold (PERF.md,
-Open questions), so this assembles the same objects with the arguments its
+section 7, "left for PRs of their own"), so this assembles the same with the arguments its
 ``main`` passes at :439-:600: ``models/transformer_lm.get_model``,
 ``capture.discover_layers``, ``KFAC(...)``, ``make_sgd`` and
 ``make_train_step``."""

@@ -159,7 +159,9 @@ def drive(step_fn, state, mesh, feed, flags_for, lr, damping, first_step,
     metrics fetched two steps behind so that the device stays fed. Returns
     the state and one record per step: the program it ran, the milliseconds
     between the host's receipt of the previous step's metrics and of this
-    one's (the first: since the loop began), the loss, the put's milliseconds.
+    one's (the first: since the loop began), the loss, the put's milliseconds,
+    and under ``counters`` every scalar the step's metrics hold (the loss
+    too), fetched in the one ``device_get``.
     Ends after ``n_steps`` steps, or at the first whole ``period`` of steps
     once ``seconds`` have passed: a window holds whole periods of the K-FAC
     schedule, so that every run of a cell does the same work."""
@@ -175,10 +177,11 @@ def drive(step_fn, state, mesh, feed, flags_for, lr, damping, first_step,
         nonlocal last
         step, kind, metrics, put_ms = item
         with TraceAnnotation("metric_fetch"):
-            loss = float(jax.device_get(metrics["loss"]))
+            counters = {k: float(v) for k, v in jax.device_get(
+                {k: v for k, v in metrics.items() if getattr(v, "ndim", None) == 0}).items()}
         now = time.perf_counter()
         records.append({"step": step, "kind": kind, "ms": (now - last) * 1e3,
-                        "loss": loss, "put_ms": put_ms})
+                        "loss": counters["loss"], "put_ms": put_ms, "counters": counters})
         last = now
 
     step = first_step
@@ -239,27 +242,19 @@ _REFERENCES = {}  # (cell, precision) -> reference_programs(): one process may f
 
 
 def reference_programs(cell, precision):
-    """The reference's model and its jitted parts for one cell: the first
-    half of a step with and without capture, the inverses, the second half,
-    the leaves' norms."""
+    """The reference's model and the jitted parts of its step for one cell
+    (``reference/kfac_sgd.py::Steps``: the batch in the cell's row blocks,
+    the K-FAC layers in its groups), and the leaves' norms."""
     import jax
 
     kf = load_module(HERE, "reference", "kfac_sgd.py")
     cfg, mix = cell["cfg"], cell["traffic_mix"]
     model = load_module(HERE, "reference", cfg["reference"] + ".py").Model(cfg, mix)
-    hyper = {**cfg["kfac"], "momentum": cfg["momentum"], "weight_decay": cfg["weight_decay"],
-             "grad_clip": cfg["grad_clip"]}
-    prec = kf.Precision(precision)
-    blocks = cell["file"].get("reference_row_blocks", 1)
-    first_half = {
-        capture: jax.jit(lambda st, b, capture=capture: kf.forward_backward(
-            model, hyper, st, b, update_factors=capture, prec=prec, row_blocks=blocks))
-        for capture in (True, False)
-    }
-    second_half = jax.jit(lambda st, g, f, i, lr: kf.precondition_and_update(
-        model, hyper, st, g, f, i, lr, prec=prec))
-    inverses = jax.jit(lambda f: kf.damped_inverses(f, hyper["damping"]))
-    return model, first_half, second_half, inverses, jax.jit(norms_of_leaves)
+    hyper = kf.hyper_of(cfg)
+    steps = kf.Steps(model, hyper, kf.Precision(precision),
+                     row_blocks=cell["file"].get("reference_row_blocks", 1),
+                     groups=cell["file"].get("reference_layer_groups", 1))
+    return steps, jax.jit(norms_of_leaves)
 
 
 def run_reference(cell, p0, pool, lr, steps=CHECKED_STEPS, precision="float32"):
@@ -270,27 +265,24 @@ def run_reference(cell, p0, pool, lr, steps=CHECKED_STEPS, precision="float32"):
     import jax.numpy as jnp
 
     weights = load_module(HERE, "weights.py")
-    kf = load_module(HERE, "reference", "kfac_sgd.py")
     mix = cell["traffic_mix"]
     key = cell["name"], precision
     if key not in _REFERENCES:
         _REFERENCES[key] = reference_programs(cell, precision)
-    model, first_half, second_half, inverses, norms = _REFERENCES[key]
+    reference, norms = _REFERENCES[key]
     names = [n for n, _ in weights.leaf_paths(p0)]
-    state = kf.init_state(model, p0)
+    state = reference.init(p0)
     out = {"loss": []}
     for k in range(steps):
-        batch = pool[k % len(pool)]
-        loss, grads, facs = first_half[k % mix["fac_update_freq"] == 0](state, batch)
-        invs = state.inverses
-        if k % mix["kfac_update_freq"] == 0:
-            invs, resid = inverses(facs)
+        state, loss, grads, resid = reference.step(
+            state, pool[k % len(pool)], jnp.float32(lr),
+            capture=k % mix["fac_update_freq"] == 0, refresh=k % mix["kfac_update_freq"] == 0)
+        if resid is not None:
             out["inverse_residual"] = max(out.get("inverse_residual", 0.0), float(resid))
-        state, grads = second_half(state, grads, facs, invs, jnp.float32(lr))
         out["loss"].append(float(loss))
         if k == 0:
             out["grad1"] = leaf_norms(names, norms(grads))
-        del grads, facs, invs
+        del grads
     delta = jax.tree_util.tree_map(lambda a, b: a - b, state.params, p0)
     out["delta3"] = leaf_norms(names, norms(delta))
     return out
@@ -525,6 +517,9 @@ def run_cell(cell, seed, seconds, trace, devices, peak, *, break_step=None,
             print(f"run.py: the trace's program runs do not match the {len(dispatched)} steps "
                   "dispatched under it: no device time by kind", file=log)
         run["device_ms"] = {k: [sec * 1e3 for sec in v] for k, v in by_kind.items()}
+        phases = load_module(HERE, "trace_phases.py")
+        run["phase_ms"] = phases.by_kind(trace_dir, dispatched)
+        run["phase_median_ms"] = lambda names, **how: phases.median_ms(run, names, **how)
         stage("trace reduced")
         shutil.rmtree(trace_dir, ignore_errors=True)
         common = load_module(HERE, "work", "common.py")
@@ -549,7 +544,8 @@ def run_cell(cell, seed, seconds, trace, devices, peak, *, break_step=None,
         device["busy_s"] = run["trace"]["busy_s"]
         device["window_s"] = run["trace"]["window_s"]
         result["breakdown"] = {"device_ops": run["trace"]["top_ops"],
-                               "idle_gaps": run["trace"]["idle_gaps"]}
+                               "idle_gaps": run["trace"]["idle_gaps"],
+                               "device_phases": phases.window_seconds(run["phase_ms"], cell["file"]["traced_kinds"])}
     else:
         result["metrics"] = {
             m["name"]: {"value": end_to_end[m["name"]][0], "unit": m["unit"]}
@@ -567,6 +563,7 @@ def run_cell(cell, seed, seconds, trace, devices, peak, *, break_step=None,
         "cache_hits": clock.cache_hits,
         "step_ms_median_by_kind": {k: statistics.median(v) for k, v in kinds.items()},
         "steps_by_kind": {k: len(v) for k, v in kinds.items()},
+        "slowest_steps": [[r["step"], r["kind"], r["ms"]] for r in sorted(records, key=lambda r: -r["ms"])[:3]],
         "samples_per_s": end_to_end["samples_per_s"][0],
         "worst_leaves": where,
     }

@@ -8,6 +8,14 @@ accepts and how much memory each program needs.
 Programs: refresh, factors, plain, twin, and the reference's device half of a step
 with and without capture (reference_capture, reference_next), its inverses
 (reference_inverses) and its second half (reference_update).
+
+    JAX_PLATFORMS=cpu python benchmarks/tests/described_compile.py --rehearsal <config> [groups]
+
+compiles the programs of the reference alone (``reference/kfac_sgd.py::Steps``)
+over the rehearsal stack of ``tests/configs/<config>.json`` with its layers in
+``groups`` groups: the plain first half, the first group's capture pass (with
+the gradients), the second group's (without), one group's inverses and
+preconditioning, and the KL clip with SGD.
 """
 
 from __future__ import annotations
@@ -36,12 +44,64 @@ FLAGS = {
 }
 
 
+def report_compiled(label, name, lowered):
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    print(json.dumps({
+        **label, "program": name, "compile_seconds": round(time.perf_counter() - t0, 1),
+        "argument_bytes": mem.argument_size_in_bytes, "output_bytes": mem.output_size_in_bytes,
+        "temp_bytes": mem.temp_size_in_bytes, "alias_bytes": mem.alias_size_in_bytes,
+        "live_bytes": mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes,
+        "tpu_custom_calls": compiled.as_text().count("tpu_custom_call"),
+        "host_peak_rss_gib": round(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20, 2),
+    }), flush=True)
+
+
+def rehearsal(name, groups=None):
+    """The reference's own programs over the rehearsal stack, a group at a time."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    tests = os.path.join(HERE, "tests")
+    cfg = bench.load_json(tests, "configs", name + ".json")
+    kf = bench.load_module(bench.HERE, "reference", "kfac_sgd.py")
+    model = bench.load_module(tests, "reference", cfg["reference"] + ".py").Model(cfg)
+    hyper = kf.hyper_of(cfg)
+    steps = kf.Steps(model, hyper, groups=int(groups or cfg.get("reference_layer_groups", 1)))
+    chip = SingleDeviceSharding(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), t)
+    params = on_chip(model.param_shapes())
+    state = on_chip(jax.eval_shape(lambda p: kf.init_state(model, p), params))
+    ids = jax.ShapeDtypeStruct((cfg["per_chip_batch"], cfg["seq_len"]), jnp.int32, sharding=chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=chip)
+    label = {"config": name, "groups": len(steps.groups)}
+    if len(steps.groups) == 1:
+        report_compiled(label, "capture", steps.first_half[True].lower(state, (ids, ids)))
+        report_compiled(label, "inverses", steps.inverses.lower(state.factors))
+        report_compiled(label, "update", steps.second_half.lower(state, params, state.factors, state.inverses, scalar))
+        return
+    of_group = lambda tree, i: {layer["name"]: tree[layer["name"]] for layer in steps.groups[i]}
+    bare = kf.RefState(params, None, None, None)
+    report_compiled(label, "plain", steps.plain.lower(bare, (ids, ids)))
+    for i in (0, 1):
+        report_compiled(label, f"capture_group{i}",
+                        steps.capture[i].lower(bare._replace(factors=of_group(state.factors, i)), (ids, ids)))
+    report_compiled(label, "inverses_group0", steps.inverses.lower(of_group(state.factors, 0)))
+    report_compiled(label, "precondition_group0", steps.precondition[0].lower(params, of_group(state.inverses, 0)))
+    report_compiled(label, "clip_and_sgd", steps.finish.lower(params, params, params, scalar, scalar))
+
+
 def main(argv):
     from jax.experimental import topologies
 
     from kfac_pytorch_tpu.parallel.mesh import data_parallel_mesh
 
     jax.config.update("jax_enable_compilation_cache", False)
+    if argv[0] == "--rehearsal":
+        return rehearsal(*argv[1:])
     cell = bench.load_cell(argv[0])
     programs = argv[1:] or ["refresh", "factors", "plain", "twin", "reference_capture", "reference_next",
                             "reference_inverses", "reference_update"]
@@ -57,19 +117,7 @@ def main(argv):
     scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)
     state = shard(replicated)(jax.eval_shape(built["init_state"]))
 
-    def report(name, lowered):
-        t0 = time.perf_counter()
-        compiled = lowered.compile()
-        mem = compiled.memory_analysis()
-        print(json.dumps({
-            "cell": cell["name"], "program": name, "compile_seconds": round(time.perf_counter() - t0, 1),
-            "argument_bytes": mem.argument_size_in_bytes, "output_bytes": mem.output_size_in_bytes,
-            "temp_bytes": mem.temp_size_in_bytes, "alias_bytes": mem.alias_size_in_bytes,
-            "live_bytes": mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes,
-            "tpu_custom_calls": compiled.as_text().count("tpu_custom_call"),
-            "host_peak_rss_gib": round(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20, 2),
-        }), flush=True)
+    report = lambda name, lowered: report_compiled({"cell": cell["name"]}, name, lowered)
 
     for name in programs:
         if name in FLAGS:
@@ -82,8 +130,7 @@ def main(argv):
         else:
             kf = bench.load_module(bench.HERE, "reference", "kfac_sgd.py")
             model = bench.load_module(bench.HERE, "reference", cfg["reference"] + ".py").Model(cfg, mix)
-            hyper = {**cfg["kfac"], "momentum": cfg["momentum"],
-                     "weight_decay": cfg["weight_decay"], "grad_clip": cfg["grad_clip"]}
+            hyper = kf.hyper_of(cfg)
             params = jax.eval_shape(built["init_state"]).params
             rstate = shard(replicated)(jax.eval_shape(lambda p: kf.init_state(model, p), params))
             if name == "reference_inverses":

@@ -100,3 +100,34 @@ def test_metrics():
         assert reader.LAYER == m["layer"] and moves == m["moves"] and callable(reader.read)
     # every cell reports at least one per-layer metric and the whole step's share of the peak
     assert any("mfu" in re.split(r"[_.]", n) for n in names)
+
+
+SCOPE_LINES = {"fwd_bwd_scope_ms": "step builder", "capture_scope_ms": "capture", "capture_scope_roofline": "capture",
+               "apply_scope_ms": "apply", "apply_scope_roofline": "apply", "refresh_scope_ms": "refresh",
+               "optimizer_scope_ms": "step builder", "unscoped_pct": "whole step"}
+
+
+def test_the_lines_read_from_the_programs_phases():
+    by_name = {m["name"]: m for m in B["per_layer"]}
+    for name, layer in SCOPE_LINES.items():
+        m = by_name[name]
+        assert m["source"] == "program_span" and m["layer"] == layer
+        assert m["unit"] == ("%" if name.endswith(("_roofline", "_pct")) else "ms")
+        assert m["better"] == ("higher" if name.endswith("_roofline") else "lower")
+    # a refresh step is the tail of the first cell and rare in the amortized one: the quantity is split
+    assert by_name["refresh_scope_ms"]["workloads"] == by_name["refresh_extra_ms.tail"]["workloads"] == ["gpt2s_t1024_f1_k10"]
+    assert by_name["refresh_extra_ms.rare"]["workloads"] == ["gpt2s_t1024_f10_k100"]
+    assert by_name["refresh_extra_ms.rare"]["moves"] == "samples_per_s"
+    # the lines by difference of programs stay beside them, under their names
+    assert {"capture_extra_ms", "capture_roofline", "apply_extra_ms", "apply_roofline"} <= set(by_name)
+    assert not any(w["chips"] == 4 for w in B["workloads"])
+
+
+def test_a_traced_stretch_runs_every_kind_of_its_schedule_twice():
+    for w in B["workloads"]:
+        cell = bench.load_cell(w["name"])
+        mix, kinds = cell["traffic_mix"], cell["file"]["traced_kinds"]
+        scheduled = {"refresh", "factors"} | ({"plain"} if mix["fac_update_freq"] > 1 else set())
+        assert {k: kinds.count(k) >= 2 for k in scheduled} == dict.fromkeys(scheduled, True)
+        # warm-up compiles every program of the window: it has to reach the first capture step after step 0
+        assert cell["file"]["warmup_steps"] > (mix["fac_update_freq"] if mix["fac_update_freq"] > 1 else 1)

@@ -42,6 +42,23 @@ def test_same_seed_same_readings():
     assert run_tiny(seed=6)["checks"] != a["checks"]
 
 
+def test_records_carry_every_scalar_of_the_steps_metrics():
+    cell = tiny_cell("lm_tiny_t32")
+    traffic = bench.load_module(bench.HERE, "traffic.py")
+    program = bench.Program(cell, jax.devices()[:1])
+    pool = traffic.make_pool(cell["traffic_mix"], cell["cfg"], 1, 4)
+    state, p0 = program.start(4)
+    _, records, _ = program.first_steps(state, p0, traffic.feed(pool), bench.CHECKED_STEPS)
+    state, _ = program.start(4)
+    _, metrics = program.step_fn(state, pool[0], program.lr, program.damping, **program.flags_for(0))
+    scalars = {k for k, v in metrics.items() if v.ndim == 0}
+    assert {"loss", "accuracy"} <= scalars
+    for record in records:
+        assert set(record["counters"]) == scalars and record["counters"]["loss"] == record["loss"]
+        assert all(isinstance(v, float) for v in record["counters"].values())
+    assert records[0]["counters"]["loss"] == pytest.approx(float(metrics["loss"]), rel=1e-6)
+
+
 def unchanged_state(step):
     def broken(state, *args, **flags):
         _, metrics = step(jax.tree_util.tree_map(jnp.copy, state), *args, **flags)

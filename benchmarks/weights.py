@@ -43,8 +43,8 @@ def make_weights(shapes, seed, rules):
         if kind == "kernel" and len(s.shape) == 4:
             kh, kw, cin, _ = s.shape
             w = _std(rules["conv_kernel"], kh * kw * cin) * jax.random.normal(key, s.shape)
-        elif kind == "kernel":
-            w = _std(rules["dense_kernel"], s.shape[0]) * jax.random.normal(key, s.shape)
+        elif kind == "kernel":  # [fan-in, fan-out], or a bank of them, [experts, fan-in, fan-out]
+            w = _std(rules["dense_kernel"], s.shape[-2]) * jax.random.normal(key, s.shape)
         elif kind == "embedding":
             w = _std(rules["embedding"], s.shape[1]) * jax.random.normal(key, s.shape)
         elif kind in ("scale", "bias"):
