@@ -44,7 +44,7 @@ from kfac_pytorch_tpu.compile_cache import (
     RecompileMonitor,
     expected_step_variants,
 )
-from kfac_pytorch_tpu.models import transformer_lm
+from kfac_pytorch_tpu.models import glm_moe_lite, transformer_lm
 from kfac_pytorch_tpu.parallel import launch
 from kfac_pytorch_tpu.parallel.context import make_context_parallel_attention
 from kfac_pytorch_tpu.parallel.mesh import put_sharded_batch
@@ -58,6 +58,80 @@ from kfac_pytorch_tpu.training.step import (
     make_sgd,
     make_train_step,
 )
+
+
+MODELS = ("transformer", "glm_moe_lite")
+
+
+def build(model_name, sizes, *, global_batch, seq_len, attention_fn,
+          momentum=0.9, weight_decay=1e-5, grad_clip=0.25, remat=False,
+          kfac_kwargs=None, step_kwargs=None):
+    """The LM trainer's objects for one model, as ``main`` wires them (and as
+    the benchmark's builder holds them: benchmarks/configs/moe_lm.py).
+
+    ``model_name`` is one of :data:`MODELS`; ``sizes`` the keyword arguments
+    of its ``get_model`` (``transformer``: ``vocab_size``, ``d_model``,
+    ``n_heads``, ``n_layers``, ...; ``glm_moe_lite``: the fields of
+    ``models/glm_moe_lite.py::GLMMoELiteConfig``). ``kfac_kwargs`` are
+    ``KFAC``'s (schedule, damping, mesh, ``profile``, ...; ``None`` trains by
+    plain SGD); the layer list, a profile's factor shapes and, for a model
+    with shared inputs, ``shared_a`` are derived here. ``step_kwargs`` go to ``make_train_step`` beside the
+    gradient clip and the declared SGD.
+
+    Returns ``model``, ``init_toks``, ``layers``, ``tx``, ``make_kfac``
+    (``**overrides -> KFAC``, for the autotuner), ``kfac``, ``make_step``
+    (``kfac -> step``), ``train_step`` and ``init_state(seed) -> TrainState``
+    (not yet placed on a mesh)."""
+    if model_name == "transformer":
+        model = transformer_lm.get_model(
+            max_len=seq_len, attention_fn=attention_fn, remat=remat, **sizes)
+    elif model_name == "glm_moe_lite":
+        model = glm_moe_lite.get_model(attention_fn=attention_fn, remat=remat, **sizes)
+    else:
+        raise ValueError(f"unknown model {model_name!r}: one of {MODELS}")
+    init_toks = jnp.zeros((global_batch, seq_len), jnp.int32)
+    tx = make_sgd(momentum=momentum, weight_decay=weight_decay)
+    layers, make_kfac, kfac = None, None, None
+    if kfac_kwargs is not None:
+        layers = capture.discover_layers(model, init_toks, train=True)
+        shared = glm_moe_lite.shared_inputs(layers) if model_name == "glm_moe_lite" else {}
+
+        def make_kfac(**overrides):
+            kwargs = {**kfac_kwargs, **overrides}
+            if kwargs.get("profile"):
+                # factor shapes for the planner's cost model (the discovered
+                # layer list includes --kfac-embedding's diag-A entry)
+                from kfac_pytorch_tpu import planner
+
+                shapes = jax.eval_shape(
+                    lambda: model.init(jax.random.PRNGKey(0), init_toks, train=True))
+                kwargs["profile_shapes"] = planner.model_facts(shapes["params"], layers=layers)
+            return KFAC(layers=layers, **({"shared_a": shared} if shared else {}), **kwargs)
+
+        kfac = make_kfac()
+
+    def make_step(kfac):
+        return make_train_step(
+            model, tx, kfac, train_kwargs={"train": True}, grad_clip=grad_clip,
+            # tx IS make_sgd(momentum, wd): the declaration lets a pallas
+            # apply_kernel fuse the optimizer pass; inert under dense
+            sgd_hyper=(momentum, weight_decay) if kfac is not None else None,
+            **(step_kwargs or {}),
+        )
+
+    def init_state(seed, kfac=kfac):
+        params = model.init(jax.random.PRNGKey(seed), init_toks, train=True)["params"]
+        return TrainState(
+            step=jnp.zeros((), jnp.int32), params=params, batch_stats={},
+            opt_state=tx.init(params),
+            kfac_state=kfac.init(params) if kfac else None,
+        )
+
+    return {
+        "model": model, "init_toks": init_toks, "layers": layers, "tx": tx,
+        "make_kfac": make_kfac, "kfac": kfac, "make_step": make_step,
+        "train_step": make_step(kfac), "init_state": init_state,
+    }
 
 
 def parse_args(argv=None):
@@ -76,6 +150,13 @@ def parse_args(argv=None):
     p.add_argument("--snapshot-every", type=int, default=0,
                    help="elastic: also snapshot every N steps "
                         "(needs --preempt-save-dir; 0 = emergency-only)")
+    p.add_argument("--model", choices=MODELS, default="transformer",
+                   help="transformer: models/transformer_lm.py from the flags "
+                        "below; glm_moe_lite: models/glm_moe_lite.py from "
+                        "--model-config")
+    p.add_argument("--model-config", default=None,
+                   help="JSON file holding the fields of GLMMoELiteConfig "
+                        "(--model glm_moe_lite; vocab_size is the corpus's)")
     p.add_argument("--d-model", type=int, default=256)
     p.add_argument("--n-heads", type=int, default=4)
     p.add_argument("--n-layers", type=int, default=2)
@@ -435,59 +516,73 @@ def main(argv=None):
         splits, words = data_lib.synthetic_corpus(vocab_size=1000)
     vocab = len(words)
 
-    model = transformer_lm.get_model(
-        vocab, max_len=args.seq_len, d_model=args.d_model,
-        n_heads=args.n_heads, n_layers=args.n_layers, attention_fn=attn,
-        kfac_embedding=args.kfac_embedding, qkv_lens=args.qkv_lens,
-        tie_embeddings=args.tie_embeddings, remat=args.remat,
-        # legacy --tensor-parallel replicates compute, so the model stays
-        # dense; the shardwise regime makes it a genuine Megatron MLP split
-        tensor_parallel=tp if shardwise_regime else 1,
-        moe_experts=args.moe_experts,
-    )
-    init_toks = jnp.zeros((global_bs, args.seq_len), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(args.seed), init_toks, train=True)
-    params = variables["params"]
+    if args.model == "glm_moe_lite":
+        import json
 
+        if not args.model_config:
+            raise SystemExit("--model glm_moe_lite needs --model-config")
+        if sp > 1 or tp > 1 or shardwise_regime or args.moe_experts:
+            raise SystemExit(
+                "--model glm_moe_lite runs data-parallel (its expert-parallel "
+                "share is in --model-config's held); no --seq-parallel, "
+                "--tensor-parallel, --fsdp or --moe-experts"
+            )
+        with open(args.model_config) as f:
+            sizes = {**json.load(f), "vocab_size": vocab}
+    else:
+        sizes = dict(
+            vocab_size=vocab, d_model=args.d_model,
+            n_heads=args.n_heads, n_layers=args.n_layers,
+            kfac_embedding=args.kfac_embedding, qkv_lens=args.qkv_lens,
+            tie_embeddings=args.tie_embeddings,
+            # legacy --tensor-parallel replicates compute, so the model stays
+            # dense; the shardwise regime makes it a genuine Megatron MLP split
+            tensor_parallel=tp if shardwise_regime else 1,
+            moe_experts=args.moe_experts,
+        )
     use_kfac = args.kfac_update_freq > 0
-    tx = make_sgd(momentum=args.momentum, weight_decay=args.wd)
+    built = build(
+        args.model, sizes, global_batch=global_bs, seq_len=args.seq_len,
+        attention_fn=attn, momentum=args.momentum, weight_decay=args.wd,
+        grad_clip=args.grad_clip, remat=args.remat,
+        kfac_kwargs=dict(
+            factor_decay=args.stat_decay,
+            damping=args.damping,
+            kl_clip=args.kl_clip,
+            fac_update_freq=args.kfac_cov_update_freq,
+            kfac_update_freq=args.kfac_update_freq,
+            mesh=mesh if devices.size > 1 else None,
+            track_diagnostics=args.kfac_diagnostics,
+            eigh_chunks=args.eigh_chunks,
+            apply_kernel=args.apply_kernel,
+            factor_comm_dtype=args.factor_comm_dtype,
+            factor_comm_freq=args.factor_comm_freq,
+            solver=args.solver,
+            solver_rank=args.solver_rank,
+            solver_auto_threshold=args.solver_auto_threshold,
+            stream_drift_threshold=args.stream_drift_threshold,
+            factor_sharding=args.factor_sharding,
+            comm_overlap=args.comm_overlap,
+            staleness_budget=args.staleness_budget,
+            service_devices=args.service_devices,
+            profile=args.profile,
+            # the bank model's factors run on the inverse path alone
+            **({"precond_method": "inverse"} if args.model == "glm_moe_lite" else {}),
+        ) if use_kfac else None,
+        step_kwargs=dict(
+            mesh=mesh if args.grad_comm_dtype else None,
+            grad_comm_dtype=jnp.bfloat16 if args.grad_comm_dtype == "bf16" else None,
+        ),
+    )
+    model, init_toks, tx = built["model"], built["init_toks"], built["tx"]
+    params = model.init(jax.random.PRNGKey(args.seed), init_toks, train=True)["params"]
+
     kfac = None
     kfac_sched = None
     if use_kfac:
-        kfac_layers = capture.discover_layers(model, init_toks, train=True)
-        profile_shapes = None
-        if args.profile:
-            # factor shapes for the cost model, from the live params (the
-            # discovered layer list includes --kfac-embedding's diag-A entry)
-            profile_shapes = planner.model_facts(params, layers=kfac_layers)
-
-        def build_kfac(profile=args.profile):
-            return KFAC(
-                layers=kfac_layers,
-                factor_decay=args.stat_decay,
-                damping=args.damping,
-                kl_clip=args.kl_clip,
-                fac_update_freq=args.kfac_cov_update_freq,
-                kfac_update_freq=args.kfac_update_freq,
-                mesh=mesh if devices.size > 1 else None,
-                track_diagnostics=args.kfac_diagnostics,
-                eigh_chunks=args.eigh_chunks,
-                apply_kernel=args.apply_kernel,
-                factor_comm_dtype=args.factor_comm_dtype,
-                factor_comm_freq=args.factor_comm_freq,
-                solver=args.solver,
-                solver_rank=args.solver_rank,
-                solver_auto_threshold=args.solver_auto_threshold,
-                stream_drift_threshold=args.stream_drift_threshold,
-                factor_sharding=args.factor_sharding,
-                comm_overlap=args.comm_overlap,
-                staleness_budget=args.staleness_budget,
-                service_devices=args.service_devices,
-                profile=profile,
-                profile_shapes=profile_shapes,
-            )
-
-        kfac = build_kfac()
+        kfac_layers = built["layers"]
+        build_kfac = lambda profile=args.profile: built["make_kfac"](profile=profile)
+        kfac = built["kfac"]
         if kfac.plan is not None and launch.is_primary():
             drop = (
                 f" (dropped: {', '.join(kfac.plan_dropped)})"
@@ -516,17 +611,7 @@ def main(argv=None):
                     return s.replace(kfac_state=kstate)
                 return jax.device_put(s, NamedSharding(mesh, P()))
 
-            def _build_step(k):
-                return make_train_step(
-                    model, tx, k, train_kwargs={"train": True},
-                    grad_clip=args.grad_clip,
-                    mesh=mesh if args.grad_comm_dtype else None,
-                    grad_comm_dtype=(
-                        jnp.bfloat16 if args.grad_comm_dtype == "bf16"
-                        else None
-                    ),
-                    sgd_hyper=(args.momentum, args.wd),
-                )
+            _build_step = built["make_step"]
 
             warm_rng = np.random.RandomState(args.seed)
             rows_local = global_bs // n_proc
@@ -597,14 +682,7 @@ def main(argv=None):
             "(--seq-parallel 1): a sequence axis would make the per-device "
             "local forward see a partial example"
         )
-    step_fn = make_train_step(
-        model, tx, kfac, train_kwargs={"train": True}, grad_clip=args.grad_clip,
-        mesh=mesh if args.grad_comm_dtype else None,
-        grad_comm_dtype=jnp.bfloat16 if args.grad_comm_dtype == "bf16" else None,
-        # tx IS make_sgd(momentum, wd): the declaration lets a pallas
-        # apply_kernel fuse the optimizer pass; inert under dense
-        sgd_hyper=(args.momentum, args.wd) if kfac is not None else None,
-    )
+    step_fn = built["make_step"](kfac)
     eval_fn = make_eval_step(model, eval_kwargs={"train": False})
 
     # [B_total, N] contiguous streams; segments of seq_len become samples.

@@ -16,11 +16,15 @@ import jax
 import jax.numpy as jnp
 
 from kfac_pytorch_tpu.models.layers import (
+    A_BANK,
     A_COL,
     A_CONTRIB,
     A_MOE,
     A_ROW,
+    A_SHARED,
     A_SPLIT,
+    BANK_ROWS,
+    BANK_TOKENS,
     G_TIED,
     N_MOE,
     OUT_MOE,
@@ -62,6 +66,21 @@ COL_SEP = "#c"
 ROW_SEP = "#r"
 MOE_SEP = "#e"
 _SHARD_SEPS = {"c": COL_SEP, "r": ROW_SEP, "e": MOE_SEP}
+
+
+# Expert-bank naming (models/layers.py::KFACBankDense): "path#b{E}" is ONE
+# layer whose kernel is [E, a, m] and whose factors and inverses stay stacked
+# [E, ., .]. Not a shard-lens name: a bank runs through the generic flow
+# (plain decay, the inverse method), with a leading expert dimension.
+BANK_SEP = "#b"
+
+
+def split_bank_name(name: str) -> Tuple[str, Any]:
+    """``"path#b8" -> ("path", 8)``; any other name ``-> (name, None)``."""
+    base, sep, count = name.rpartition(BANK_SEP)
+    if sep and count.isdigit():
+        return base, int(count)
+    return name, None
 
 
 def split_shard_name(name: str) -> Tuple[str, Any, Any]:
@@ -108,6 +127,9 @@ def layer_base(name: str) -> str:
         return base
     base, form, _ = split_shard_name(name)
     if form is not None:
+        return base
+    base, count = split_bank_name(name)
+    if count is not None:
         return base
     return split_lens_name(name)[0]
 
@@ -177,7 +199,14 @@ def layer_names_from_capture(captured: PyTree) -> List[str]:
     name carrying the stack size in the suffix (shard stacks stay stacked).
     """
     shard_keys = {A_COL: COL_SEP, A_ROW: ROW_SEP, A_MOE: MOE_SEP}
-    a_keys = (A_CONTRIB, A_SPLIT) + tuple(shard_keys)
+    # a bank says so by its row counts (its A stack may be a sibling's);
+    # a_shared marks a plain layer whose A statistic a sibling owns
+    a_keys = (A_CONTRIB, A_SPLIT, A_SHARED, BANK_ROWS) + tuple(shard_keys)
+    bank_paths = {
+        keys[: -1 if keys[-1] == BANK_ROWS else -2]
+        for keys, _ in _flatten_with_paths(captured)
+        if BANK_ROWS in keys[-2:]
+    }
     names = []
     for keys, leaf in _flatten_with_paths(captured):
         # sow may wrap the leaf in a tuple (path gains an index key)
@@ -187,8 +216,13 @@ def layer_names_from_capture(captured: PyTree) -> List[str]:
         )
         if key is None:
             continue
-        name = "/".join(keys[: -1 if keys[-1] == key else -2])
-        if key in shard_keys:
+        path = keys[: -1 if keys[-1] == key else -2]
+        name = "/".join(path)
+        if path in bank_paths:
+            if key != BANK_ROWS:
+                continue
+            expanded = [f"{name}{BANK_SEP}{leaf.shape[0]}"]
+        elif key in shard_keys:
             expanded = [f"{name}{shard_keys[key]}{leaf.shape[0]}"]
         elif key == A_SPLIT:
             expanded = [f"{name}{SPLIT_SEP}{k}" for k in range(leaf.shape[0])]
@@ -249,6 +283,10 @@ def layer_grads(grads: PyTree, names: List[str]) -> Dict[str, Dict[str, jnp.ndar
             if form == "c" and "bias" in node:
                 entry["bias"] = node["bias"]
             out[name] = entry
+            continue
+        bbase, bcount = split_bank_name(name)
+        if bcount is not None:
+            out[name] = {"kernel": _get_path(grads, bbase)["kernel"]}
             continue
         base, gi = split_group_name(name)
         si = None
@@ -341,6 +379,12 @@ def a_contribs(
                     "f": _unwrap_sown(node[N_MOE]),
                 }
             continue
+        bbase, bcount = split_bank_name(name)
+        if bcount is not None:
+            node = _get_path(captured, bbase)
+            if A_SHARED not in node:  # else the owner's statistic serves
+                out[name] = _unwrap_sown(node[A_BANK])
+            continue
         base, gi = split_group_name(name)
         if gi is None:
             sbase, si = split_lens_name(name)
@@ -361,6 +405,8 @@ def a_contribs(
                 out[name] = leaf[si]
                 continue
         node = _get_path(captured, base)
+        if A_SHARED in node:
+            continue  # a sibling owns this input's A (KFAC(shared_a=...))
         leaf = _unwrap_sown(node[A_CONTRIB])
         if gi is None:
             if G_TIED in node:
@@ -470,6 +516,21 @@ def g_factors(
                     batch_averaged=batch_averaged,
                 )
             continue
+        bbase, bcount = split_bank_name(name)
+        if bcount is not None:
+            if captured is None:
+                raise ValueError(
+                    f"expert bank {bbase!r}: g_factors needs captured= (the "
+                    "bank's row counts ride in the kfac_acts collection)"
+                )
+            cap_node = _get_path(captured, bbase)
+            out[name] = factors.compute_g_bank(
+                _get_path(perturb_grads, bbase)[OUT_PERTURB].astype(jnp.float32),
+                _unwrap_sown(cap_node[BANK_ROWS]),
+                _unwrap_sown(cap_node[BANK_TOKENS]),
+                batch_averaged=batch_averaged,
+            )
+            continue
         base, gi = split_group_name(name)
         if gi is not None:
             out[name] = stacked[base][gi]
@@ -531,7 +592,7 @@ def grad_mats(
     """
     out = {}
     for name, g in lgrads.items():
-        if split_shard_name(name)[1] == "e":
+        if split_shard_name(name)[1] == "e" or split_bank_name(name)[1] is not None:
             out[name] = jnp.transpose(g["kernel"], (0, 2, 1))
         else:
             out[name] = factors.grads_to_mat(g)
@@ -556,6 +617,14 @@ def write_back(
     grouped: Dict[str, Dict[int, jnp.ndarray]] = {}
     lensed: Dict[str, Dict[int, jnp.ndarray]] = {}
     for name, mat in updates.items():
+        bbase, bcount = split_bank_name(name)
+        if bcount is not None:
+            # stacked [E, m, a] expert updates back to the [E, a, m] bank
+            node = _get_path(grads, bbase)
+            node["kernel"] = jnp.transpose(mat * nu, (0, 2, 1)).astype(
+                node["kernel"].dtype
+            )
+            continue
         shbase, form, _ = split_shard_name(name)
         if form is not None:
             node = _get_path(grads, shbase)

@@ -54,6 +54,9 @@ KFAC_STATE_KEYS: Dict[str, str] = {
              "(QA/dA[/rhoA], QG/dG[/rhoG] or iA/iG; rsvd tables included)",
     "eigen_stacked": "batched eigen entries for same-shape layer groups "
                      "(<g>x<a> stacks)",
+    "inverse_tables": "the inverse method's iA/iG of one side in one table "
+                      "{side: [K, side, side]} (expert banks or shared_a; "
+                      "eigen / eigen_stacked are empty then)",
     "eigen_pending": "chunked-refresh double buffer in full per-layer form "
                      "(eigh_chunks > 1, replicated mode)",
     "factor_shard": "owner-sharded factor stacks n<size>/v<size>, leading "

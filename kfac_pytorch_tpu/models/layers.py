@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from kfac_pytorch_tpu.ops import factor_kernels, factors
+from kfac_pytorch_tpu.ops import factor_kernels, factors, grouped
 
 Dtype = Any
 Padding = Union[str, int, Sequence[Tuple[int, int]]]
@@ -68,6 +68,20 @@ A_ROW = "a_row"
 A_MOE = "a_moe"
 N_MOE = "n_moe"
 OUT_MOE = "out_moe"
+# Expert-bank capture (KFACBankDense): the rows arrive routed and sorted by
+# expert, so the statistics are per-expert Grams over each expert's own rows.
+# A_BANK is the [E, a, a] stack (plain 1/T scaling: an expert is a layer of
+# its own whose unrouted rows are zero); BANK_ROWS the [E] row counts and
+# BANK_TOKENS the token count T, which the G side needs beside the [M, m]
+# cotangent. A_SHARED marks a layer that reads the same input as a sibling
+# and keeps no A statistic of its own (KFAC(shared_a=...) names the owner).
+A_BANK = "a_bank"
+BANK_ROWS = "bank_rows"
+BANK_TOKENS = "bank_tokens"
+A_SHARED = "a_shared"
+# Scalars a model reports beside the loss (routing load, ...): sown here,
+# ``make_train_step`` puts them into the step's metrics.
+STEP_SCALARS = "step_scalars"
 
 
 def _overwrite(old: Any, new: Any) -> Any:
@@ -98,6 +112,15 @@ class _KFACLayer(nn.Module):
         # plain steps the matmul never enters the program.
         if self._capturing():
             self.sow(KFAC_ACTS, A_CONTRIB, contrib_fn(), reduce_fn=_overwrite)
+
+    def _sow_a_shared(self) -> None:
+        # a sibling layer owns the A statistic of this input: leave a mark so
+        # that discovery still finds the layer, and multiply nothing
+        if self._capturing():
+            self.sow(
+                KFAC_ACTS, A_SHARED, jnp.zeros((0,), jnp.float32),
+                reduce_fn=_overwrite,
+            )
 
     def _maybe_perturb(self, y: jnp.ndarray, name: str = OUT_PERTURB) -> jnp.ndarray:
         # Gate so the model also applies cleanly WITHOUT a perturbations
@@ -134,9 +157,19 @@ class KFACDense(_KFACLayer):
     param_dtype: Dtype = jnp.float32
     kernel_init: Callable = nn.initializers.lecun_normal()
     bias_init: Callable = nn.initializers.zeros_init()
+    # ``a_shared``: a sibling layer reads the same input and owns its A
+    # statistic (``KFAC(shared_a={this: sibling})``); nothing is multiplied
+    # here. ``precision`` is the forward product's (None: the default).
+    a_shared: bool = False
+    precision: Any = None
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        if self.a_shared and (self.lens_splits > 1 or self.use_bias):
+            raise ValueError(
+                "a_shared layers are plain bias-free projections (the owner's "
+                "A factor has no bias column and no lens split)"
+            )
         if self.lens_splits > 1 and self.features % self.lens_splits:
             raise ValueError(
                 f"lens_splits={self.lens_splits} must divide "
@@ -168,6 +201,8 @@ class KFACDense(_KFACLayer):
                     ),
                     reduce_fn=_overwrite,
                 )
+        elif self.a_shared:
+            self._sow_a_shared()
         else:
             self._sow_a(
                 lambda: factors.compute_a_dense(
@@ -176,10 +211,68 @@ class KFACDense(_KFACLayer):
             )
 
         x, kernel = nn.dtypes.promote_dtype(x, kernel, dtype=self.dtype)
-        y = jnp.matmul(x, kernel)
+        y = jnp.matmul(x, kernel, precision=self.precision)
         if bias is not None:
             y = y + bias.astype(y.dtype)
         return self._maybe_perturb(y)
+
+
+class KFACBankDense(_KFACLayer):
+    """One projection of a bank of experts, over rows already routed.
+
+    ``rows`` is ``[M, a]``: the token-expert pairs' inputs sorted by expert,
+    the held experts' groups first and in order; ``group_sizes`` ``[E]`` says
+    how many rows each held expert has (rows past their sum belong to no held
+    expert: their output is zero and they count for nothing). The product is
+    a grouped one (``ops/grouped.py``), so the work follows the rows
+    routed and no ``[T, E, .]`` tensor exists. ``kernel`` is ``[E, a, m]``,
+    no bias.
+
+    Curvature: E layers of their own (Martens & Grosse's layer-wise
+    independence; ``benchmarks/reference/kfac_sgd.py``'s ``bank`` kind):
+    ``A_e = (1/T) sum_{t in e} x_t x_t^T`` and, from the perturbation's
+    cotangent, ``G_e = T sum_{t in e} g_t g_t^T`` with ``T = n_tokens``,
+    running averages with the plain decay. Captured as ONE ``name#bE`` layer
+    whose factors stay stacked ``[E, ., .]``.
+    """
+
+    features: int
+    num_experts: int
+    a_shared: bool = False
+    # ``kfac=False`` leaves the bank to plain SGD: the grouped product alone,
+    # nothing sown and no perturbation
+    kfac: bool = True
+    dtype: Optional[Dtype] = None
+    param_dtype: Dtype = jnp.float32
+    kernel_init: Callable = nn.initializers.lecun_normal(batch_axis=(0,))
+
+    @nn.compact
+    def __call__(
+        self, rows: jnp.ndarray, group_sizes: jnp.ndarray, n_tokens: int
+    ) -> jnp.ndarray:
+        kernel = self.param(
+            "kernel", self.kernel_init,
+            (self.num_experts, rows.shape[-1], self.features), self.param_dtype,
+        )
+        if self.kfac and self._capturing():
+            self.sow(KFAC_ACTS, BANK_ROWS, group_sizes, reduce_fn=_overwrite)
+            self.sow(
+                KFAC_ACTS, BANK_TOKENS, jnp.full((), n_tokens, jnp.int32),
+                reduce_fn=_overwrite,
+            )
+            if self.a_shared:
+                self._sow_a_shared()
+            else:
+                self.sow(
+                    KFAC_ACTS, A_BANK,
+                    factors.compute_a_bank(
+                        rows.astype(jnp.float32), group_sizes, n_tokens
+                    ),
+                    reduce_fn=_overwrite,
+                )
+        rows, kernel = nn.dtypes.promote_dtype(rows, kernel, dtype=self.dtype)
+        y = grouped.grouped_matmul(rows, kernel, group_sizes)
+        return self._maybe_perturb(y) if self.kfac else y
 
 
 class KFACShardedDense(_KFACLayer):

@@ -32,6 +32,12 @@ PHASES = (
     "kfac_refresh",  # inverses / eigendecompositions of the factors
     "kfac_apply",  # precondition every layer's gradient, KL clip
     "optimizer",  # the SGD tail: momentum, weight decay, parameter step
+    # entered in models/glm_moe_lite.py alone, inside "model" (the innermost
+    # phase of an op's path wins): in a model without them "model" is the whole
+    # forward and backward, with them it is the model less these three
+    "attention",  # the attention call with the rotary and latent reshapes round it
+    "moe_route",  # router product, top-k, sort by expert, gather, combine
+    "moe_experts",  # the grouped products over the held experts' rows
 )
 
 

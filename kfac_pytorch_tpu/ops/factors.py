@@ -56,6 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kfac_pytorch_tpu.observability.phases import phase
+from kfac_pytorch_tpu.ops import grouped
 from kfac_pytorch_tpu.observability.telemetry import get_telemetry
 
 _HIGHEST = lax.Precision.HIGHEST
@@ -424,6 +425,28 @@ def compute_a_moe(
         return jnp.matmul(xm.T, xm / n, precision=_HIGHEST)
 
     return jnp.stack([_one(e) for e in range(num_experts)])
+
+
+@phase("kfac_capture")
+def compute_a_bank(
+    rows: jnp.ndarray, group_sizes: jnp.ndarray, n_tokens: int
+) -> jnp.ndarray:
+    """Input covariances of an expert bank over routed rows:
+    ``A_e = (1/T) sum_{t in e} x_t x_t^T``, ``[E, a, a]``: those of E dense
+    layers over all T rows whose unrouted rows are zero."""
+    return grouped.grouped_gram(rows, group_sizes) / n_tokens
+
+
+@phase("kfac_capture")
+def compute_g_bank(
+    g: jnp.ndarray, group_sizes: jnp.ndarray, n_tokens, batch_averaged: bool
+) -> jnp.ndarray:
+    """Grad-output covariances of an expert bank, ``[E, m, m]``, from the
+    cotangent of its routed rows' outputs: :func:`compute_g_dense`'s scaling
+    with ``N = n_tokens``, the rows the loss is a mean over."""
+    n = jnp.asarray(n_tokens, jnp.float32)
+    gram = grouped.grouped_gram(g, group_sizes)
+    return gram * n if batch_averaged else gram / n
 
 
 @phase("kfac_capture")

@@ -1008,6 +1008,148 @@ def factored_inverse_all(
     return out
 
 
+# ---------------------------------------------------------------------------
+# Inverse tables: the inverse method's state for models with expert banks.
+# Every inverse of one side lives in ONE array ``[K, side, side]`` (a bank's E
+# inverses in a row), so that a refresh writes the damped factors into the
+# table and inverts it where it lies, a batch at a time: the refresh holds no
+# second copy of some hundred factors of side 2048. ``layout`` (static, from
+# shapes alone) says where each layer's ``iA`` and ``iG`` lie.
+# ---------------------------------------------------------------------------
+
+
+# Bytes of float32 matrices that one Cholesky batch of a table's refresh holds
+# (the compiler keeps some seven times that while it inverts a batch): 4
+# factors of side 2048, 7 of side 1536.
+TABLE_BATCH_BYTES = 64 * 2**20
+
+
+def _batches(count: int, side: int) -> Tuple[int, int]:
+    """``(batches, matrices per batch)`` for ``count`` float32 matrices of
+    ``side`` in batches of at most ``TABLE_BATCH_BYTES``, as equal as may be."""
+    per = max(1, min(count, TABLE_BATCH_BYTES // (4 * side * side)))
+    batches = -(-count // per)
+    return batches, -(-count // batches)
+
+
+def _pis(factors, shared_a, eps):
+    """π = sqrt((tr A / dim A) / (tr G / dim G)) per layer (per expert for a
+    bank's stacks), a ``shared_a`` layer reading its owner's ``A``."""
+    mean_diag = lambda m: jnp.trace(m, axis1=-2, axis2=-1) / m.shape[-1]
+    pis = {}
+    for n, f in factors.items():
+        tr_a = jnp.maximum(mean_diag(factors[shared_a.get(n, n)]["A"]), eps)
+        tr_g = jnp.maximum(mean_diag(f["G"]), eps)
+        pis[n] = jnp.sqrt(tr_a / tr_g)
+    return pis
+
+
+def inverse_table_layout(
+    shapes: Dict[str, Dict[str, Tuple[int, ...]]],
+    shared_a: Dict[str, str],
+) -> Tuple[Dict[str, Dict[str, Tuple[int, int, int]]], Dict[int, int]]:
+    """``({layer: {'iA'|'iG': (side, first row, rows)}}, {side: rows of its
+    table})`` from ``{layer: {'A'?, 'G': shape}}``; ``rows`` is 1 for a plain
+    layer and E for a bank. A table's row count is a whole number of refresh
+    batches (its last rows may be padding, kept at the identity)."""
+    layout: Dict[str, Dict[str, Tuple[int, int, int]]] = {}
+    used: Dict[int, int] = {}
+    for name, f in shapes.items():
+        layout[name] = {}
+        for key, shape in (("iA", shapes[shared_a.get(name, name)]["A"]), ("iG", f["G"])):
+            side, rows = shape[-1], (shape[0] if len(shape) == 3 else 1)
+            layout[name][key] = (side, used.get(side, 0), rows)
+            used[side] = used.get(side, 0) + rows
+    table_rows = {}
+    for side, k in used.items():
+        batches, per = _batches(k, side)
+        table_rows[side] = batches * per
+    return layout, table_rows
+
+
+def factored_inverse_tables(
+    factors: Dict[str, Dict[str, jnp.ndarray]],
+    tables: Dict[str, jnp.ndarray],
+    damping: jnp.ndarray,
+    eps: float,
+    shared_a: Dict[str, str],
+    layout: Dict[str, Dict[str, Tuple[int, int, int]]],
+) -> Dict[str, jnp.ndarray]:
+    """The refresh of :func:`factored_inverse_all` into the inverse tables
+    ``{str(side): [K, side, side]}``, each written where it lies: a loop over
+    the table's batches (one batch's Cholesky program), each step damping its
+    batch's factors (``lax.switch`` over the batches: which factors those
+    are is static) and writing their inverses over the table's old rows, so
+    that no second copy of a side's factors exists. A bank is damped (its own
+    π per expert) and inverted per expert; a layer of ``shared_a`` reads its
+    owner's ``A`` and still gets an ``iA`` of its own, because π is the
+    layer's own. Padding rows stay at the identity."""
+    sqrt_l = jnp.sqrt(damping.astype(jnp.float32))
+    pis = _pis(factors, shared_a, eps)
+    rows_of: Dict[int, list] = {}  # side -> [(factor, shift, row of a bank or None)], in table order
+    for name in layout:
+        for key in ("iA", "iG"):
+            side, first, rows = layout[name][key]
+            if key == "iA":
+                m, shift = factors[shared_a.get(name, name)]["A"], pis[name] * sqrt_l
+            else:
+                m, shift = factors[name]["G"], sqrt_l / pis[name]
+            assert first == len(rows_of.setdefault(side, []))
+            rows_of[side] += [(m, shift, e if m.ndim == 3 else None) for e in range(rows)]
+    out = {}
+    for side, rows in rows_of.items():
+        table = tables[str(side)]
+        eye = jnp.eye(side, dtype=jnp.float32)
+        batches, per = _batches(table.shape[0], side)
+
+        def damped(lo, rows=rows, eye=eye, per=per):
+            """Rows ``lo .. lo + per`` of the table, damped, before inversion."""
+            parts, i = [], lo
+            while i < min(lo + per, len(rows)):
+                m, shift, e = rows[i]
+                if e is None:
+                    parts.append((m.astype(jnp.float32) + shift * eye)[None])
+                    i += 1
+                    continue
+                n = min(m.shape[0] - e, lo + per - i)  # a run of one bank's experts
+                parts.append(m[e:e + n].astype(jnp.float32) + shift[e:e + n, None, None] * eye)
+                i += n
+            pad = max(0, lo + per - len(rows))  # only the last batch has any
+            if pad:
+                parts.append(jnp.broadcast_to(eye, (pad, side, side)))
+            return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+        if batches == 1:
+            out[str(side)] = _spd_inverse_stack(damped(0))
+            continue
+        branches = [partial(damped, j * per) for j in range(batches)]
+
+        def body(j, table, branches=branches, per=per):
+            inverse = _spd_inverse_stack(lax.switch(j, branches))
+            return lax.dynamic_update_slice_in_dim(table, inverse, j * per, 0)
+
+        out[str(side)] = lax.fori_loop(0, batches, body, table)
+    return out
+
+
+def precondition_all_inv_tables(
+    grad_mats: Dict[str, jnp.ndarray],
+    tables: Dict[str, jnp.ndarray],
+    layout: Dict[str, Dict[str, Tuple[int, int, int]]],
+    precision: lax.Precision = _ROTATION_PRECISION,
+) -> Dict[str, jnp.ndarray]:
+    """``v = iG · grad · iA`` per layer, the inverses read from their tables;
+    a bank's ``[E, m, a]`` gradient against its E rows."""
+    out = {}
+    for name, g in grad_mats.items():
+        inv = {}
+        for key, (side, first, rows) in layout[name].items():
+            rows_of = tables[str(side)][first:first + rows]
+            inv[key] = rows_of if g.ndim == 3 else rows_of[0]
+        out[name] = precondition_mat_inv(g, inv["iA"], inv["iG"], precision)
+    return out
+
+
 def split_inv_state(
     inv: Dict[str, Dict[str, jnp.ndarray]],
 ) -> Tuple[Dict[str, Dict[str, jnp.ndarray]], Dict[str, Dict[str, jnp.ndarray]]]:

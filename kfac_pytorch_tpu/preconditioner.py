@@ -177,6 +177,7 @@ class KFAC:
         service_devices: int = 0,
         profile: Optional[Any] = None,
         profile_shapes: Optional[Any] = None,
+        shared_a: Optional[Dict[str, str]] = None,
     ):
         _validate("learning rate", 0.0 <= lr, lr)
         _validate("factor decay rate", 0.0 < factor_decay <= 1, factor_decay)
@@ -252,6 +253,20 @@ class KFAC:
         # tensor-sharded and MoE kernels. Only an explicit layers= list can
         # carry them (the params heuristic never synthesizes shard names),
         # so the named refusals below fire at construction, not mid-step.
+        # Layers that read the same input as another keep no A factor of
+        # their own: ``{layer: owner}``, both in ``layers``. The owner's A is
+        # captured and averaged once; each layer still has its own inverse
+        # (π is the layer's own). Inverse method, replicated state.
+        self.shared_a = dict(shared_a or {})
+        unknown = [
+            n for pair in self.shared_a.items() for n in pair
+            if n not in (self.layers or [])
+        ]
+        if unknown or set(self.shared_a) & set(self.shared_a.values()):
+            raise ValueError(
+                "shared_a maps layers of layers= to owners of layers= that "
+                f"share with no one themselves; got {self.shared_a}"
+            )
         self.shard_layers = shardwise.shard_entries(self.layers or [])
         self.has_shard_lens = shardwise.has_shard_lens(self.layers or [])
         self.has_moe = shardwise.has_moe(self.layers or [])
@@ -829,6 +844,27 @@ class KFAC:
         # folds, diagonal blocking, owner re-homing, the curvature service —
         # has nothing coherent to act on and refuses up front rather than
         # silently skipping the shard layers.
+        banks = [
+            n for n in self.layers or []
+            if capture.split_bank_name(n)[1] is not None
+        ]
+        # With expert banks (or shared inputs) the inverses live in one table
+        # per side, refreshed where they lie (ops/precondition.py, "Inverse
+        # tables"); every other model keeps the per-layer / stacked layout.
+        self.inverse_tables = bool(banks or self.shared_a)
+        if self.inverse_tables and (
+            self.precond_method != "inverse"
+            or self.requested_factor_sharding == "owner"
+            or self.distribute_precondition
+            or self.track_diagnostics
+            or self.factor_comm.comm_freq > 1
+        ):
+            raise ValueError(
+                "expert banks ('#b' layers) and shared_a run on the "
+                "replicated inverse path alone: precond_method='inverse', "
+                "factor_sharding='replicated', no distribute_precondition, "
+                "no track_diagnostics, factor_comm_freq=1"
+            )
         if self.has_shard_lens or self.has_moe:
             kind = "MoE expert banks" if not self.has_shard_lens else (
                 "shard-lens layers"
@@ -1354,6 +1390,23 @@ class KFAC:
                     form, count, tuple(node["kernel"].shape), "bias" in node
                 )
                 continue
+            bbase, bcount = capture.split_bank_name(name)
+            if bcount is not None:
+                # expert bank: identity stacks [E, ., .], the dense layers'
+                # init per expert
+                node = params
+                for k in bbase.split("/"):
+                    node = node[k]
+                _, a_in, m = node["kernel"].shape
+                facs[name] = {
+                    "A": jnp.broadcast_to(
+                        jnp.eye(a_in, dtype=jnp.float32), (bcount, a_in, a_in)
+                    ),
+                    "G": jnp.broadcast_to(
+                        jnp.eye(m, dtype=jnp.float32), (bcount, m, m)
+                    ),
+                }
+                continue
             base, group_idx = capture.split_group_name(name)
             base, split_idx = capture.split_lens_name(base)
             node = params
@@ -1395,7 +1448,23 @@ class KFAC:
                 "A": jnp.eye(a_side, dtype=jnp.float32),
                 "G": jnp.eye(g_side, dtype=jnp.float32),
             }
+        for name, owner in self.shared_a.items():
+            if facs[name]["A"].shape != facs[owner]["A"].shape:
+                raise ValueError(
+                    f"shared_a: {name!r} and its owner {owner!r} read inputs "
+                    f"of different shape ({facs[name]['A'].shape} against "
+                    f"{facs[owner]['A'].shape})"
+                )
         return facs
+
+    def _inverse_layout(self, facs):
+        """Where each layer's inverses lie in the inverse tables, from the
+        factors' shapes (``facs`` may lack the ``A`` of a ``shared_a``
+        layer): ops/precondition.py::inverse_table_layout."""
+        shapes = {
+            n: {k: tuple(v.shape) for k, v in f.items()} for n, f in facs.items()
+        }
+        return precond_ops.inverse_table_layout(shapes, self.shared_a)
 
     def factor_shapes(self, params: PyTree):
         """``({name: (g, a)}, diag_a_names)`` for ``params`` — the pure
@@ -1447,6 +1516,23 @@ class KFAC:
                     **self._eigen_side_init("A", a_side),
                     **self._eigen_side_init("G", g_side),
                 }
+        if self.inverse_tables:
+            _, rows = self._inverse_layout(facs)
+            # a layer that shares its input keeps no A of its own (shared_a)
+            facs = {
+                n: ({"G": f["G"]} if n in self.shared_a else f)
+                for n, f in facs.items()
+            }
+            return {
+                "step": jnp.zeros((), jnp.int32),
+                "factors": facs,
+                "eigen": {},
+                "eigen_stacked": {},
+                "inverse_tables": {
+                    str(side): jnp.zeros((k, side, side), jnp.float32)
+                    for side, k in rows.items()
+                },
+            }
         if self.owner_sharded:
             return self._owner_init(facs)
         # same-shape groups live ONLY pre-stacked (batched-rotation form);
@@ -1759,7 +1845,11 @@ class KFAC:
                 raise ValueError(
                     "update_factors=True requires a_contribs and g_factor_stats"
                 )
-            missing = [n for n in names if n not in a_contribs or n not in g_factor_stats]
+            missing = [
+                n for n in names
+                if (n not in a_contribs and n not in self.shared_a)
+                or n not in g_factor_stats
+            ]
             if missing:
                 raise ValueError(
                     f"no captured statistics for layers {missing}; the model "
@@ -1785,6 +1875,15 @@ class KFAC:
                             g_factor_stats[name],
                             self.factor_decay,
                         )
+                        continue
+                    if name in self.shared_a:  # the owner averages the A
+                        facs[name] = {
+                            "G": factor_ops.update_running_avg(
+                                g_factor_stats[name],
+                                old_facs[name]["G"],
+                                self.factor_decay,
+                            )
+                        }
                         continue
                     facs[name] = {
                         ("A_diag" if "A_diag" in old_facs[name] else "A"):
@@ -1818,6 +1917,7 @@ class KFAC:
 
         eigen = state["eigen"]
         stacked = state.get("eigen_stacked")
+        tables = state.get("inverse_tables")
         pending = state.get("eigen_pending")
         spectrum_mass = state.get("spectrum_mass")
         # Per-layer eigenvalue spectra captured (pre-split) on eigen-update
@@ -1849,9 +1949,17 @@ class KFAC:
             # exchange; the EVERY-STEP solve still shards via
             # distribute_precondition.
             with phase("kfac_refresh", "trace/kfac/eigh"):
-                inv = precond_ops.factored_inverse_all(
-                    facs, jnp.asarray(damping, jnp.float32), self.eps
-                )
+                if self.inverse_tables:
+                    tables = precond_ops.factored_inverse_tables(
+                        facs, tables, jnp.asarray(damping, jnp.float32),
+                        self.eps, self.shared_a,
+                        self._inverse_layout(facs)[0],
+                    )
+                    inv = {}
+                else:
+                    inv = precond_ops.factored_inverse_all(
+                        facs, jnp.asarray(damping, jnp.float32), self.eps
+                    )
                 if self.eigen_dtype != jnp.float32:
                     inv = {
                         # only the MATRIX inverses downcast; the embedding
@@ -2061,7 +2169,7 @@ class KFAC:
         if not precond_early:
             with phase("kfac_apply", "trace/kfac/precondition"):
                 new_grads, gmats, updates, nu = self._precondition_replicated(
-                    grads, names, facs, eigen, stacked, lr, damping
+                    grads, names, facs, eigen, stacked, lr, damping, tables
                 )
 
         new_state = {
@@ -2070,6 +2178,8 @@ class KFAC:
             "eigen": eigen,
             "eigen_stacked": stacked,
         }
+        if tables is not None:
+            new_state["inverse_tables"] = tables
         if pending is not None:
             new_state["eigen_pending"] = pending
         if spectrum_mass is not None:
@@ -2110,7 +2220,7 @@ class KFAC:
         return new_grads, new_state
 
     def _precondition_replicated(
-        self, grads, names, facs, eigen, stacked, lr, damping
+        self, grads, names, facs, eigen, stacked, lr, damping, tables=None
     ):
         """The every-step precondition + KL clip of the replicated flow,
         factored out so the overlap plane can emit it either before the
@@ -2148,6 +2258,10 @@ class KFAC:
                 norm_gmats, eigen, damping, *precision_args, stacked=stacked,
                 mesh=self.mesh, owners=owners,
                 comm_dtype=self.precond_comm_dtype,
+            )
+        elif tables is not None:
+            updates = precond_ops.precondition_all_inv_tables(
+                norm_gmats, tables, self._inverse_layout(facs)[0], *precision_args
             )
         elif inverse:
             updates = precond_ops.precondition_all_inv(

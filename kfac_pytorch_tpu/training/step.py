@@ -25,7 +25,7 @@ import optax
 from jax import lax
 
 from kfac_pytorch_tpu import capture, compat
-from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS
+from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS, STEP_SCALARS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
 from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels, factors
@@ -124,7 +124,7 @@ def _compressed_grads(compute, mesh, comm_dtype, accum_steps, factor_comm=None):
         check_vma=False,
     )
     def _inner(params, batch_stats, images, labels):
-        loss, acc, grads, new_bs, a_c, g_s = compute(
+        loss, acc, grads, new_bs, a_c, g_s, scalars = compute(
             params, batch_stats, images, labels
         )
         overlap = factor_comm is not None and factor_comm.overlap
@@ -148,7 +148,7 @@ def _compressed_grads(compute, mesh, comm_dtype, accum_steps, factor_comm=None):
                 # per-leaf f32 exchange
                 a_c = lax.pmean(a_c, axis)
                 g_s = lax.pmean(g_s, axis)
-        return loss, acc, grads, new_bs, a_c, g_s
+        return loss, acc, grads, new_bs, a_c, g_s, lax.pmean(scalars, axis)
 
     return _inner
 
@@ -353,7 +353,7 @@ def make_train_step(
         perts = capture.perturbation_zeros(model, images, **train_kwargs)
         factors.reset_capture_tally()  # the gauges count this program's products
         has_bn = bool(batch_stats)
-        mutable = (["batch_stats"] if has_bn else []) + [KFAC_ACTS]
+        mutable = (["batch_stats"] if has_bn else []) + [KFAC_ACTS, STEP_SCALARS]
 
         def loss_fn(params, perts):
             out = model.apply(
@@ -389,29 +389,27 @@ def make_train_step(
             acc = jnp.mean(
                 (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
             )
-        return loss, acc, grads, new_bs, a_c, g_s
+        return loss, acc, grads, new_bs, a_c, g_s, _step_scalars(mut)
+
+    def _step_scalars(mut):
+        # scalars the model reports beside the loss (models/layers.py::
+        # STEP_SCALARS: a sparse-expert model's routing load), for the metrics
+        return {
+            name: value[-1] if isinstance(value, tuple) else value
+            for name, value in mut.get(STEP_SCALARS, {}).items()
+        }
 
     def loss_and_grads_plain(params, batch_stats, images, labels):
         has_bn = bool(batch_stats)
-        mutable = ["batch_stats"] if has_bn else []
+        mutable = (["batch_stats"] if has_bn else []) + [STEP_SCALARS]
 
         def loss_fn(params):
-            # flax returns an (out, mut) tuple for ANY mutable list, even [] —
-            # only skip the unpack when we pass no mutable arg at all
-            if mutable:
-                logits, mut = model.apply(
-                    _variables(params, batch_stats),
-                    images,
-                    mutable=mutable,
-                    **train_kwargs,
-                )
-            else:
-                logits, mut = (
-                    model.apply(
-                        _variables(params, batch_stats), images, **train_kwargs
-                    ),
-                    {},
-                )
+            logits, mut = model.apply(
+                _variables(params, batch_stats),
+                images,
+                mutable=mutable,
+                **train_kwargs,
+            )
             loss = softmax_cross_entropy(logits, labels, label_smoothing)
             return loss, (mut, logits)
 
@@ -424,7 +422,7 @@ def make_train_step(
             acc = jnp.mean(
                 (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
             )
-        return loss, acc, grads, new_bs, None, None
+        return loss, acc, grads, new_bs, None, None, _step_scalars(mut)
 
     @phase("model")
     def accum_loss_and_grads(params, batch_stats, images, labels, capture_stats):
@@ -436,7 +434,7 @@ def make_train_step(
         def body(carry, xs):
             bs, gsum, lsum, asum = carry
             im, lb = xs
-            loss, acc, grads, new_bs, _, _ = loss_and_grads_plain(
+            loss, acc, grads, new_bs, *_ = loss_and_grads_plain(
                 params, bs, im, lb
             )
             gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
@@ -453,14 +451,14 @@ def make_train_step(
         )
         a_c = g_s = None
         if capture_stats:
-            loss, acc, grads, bs, a_c, g_s = loss_and_grads_captured(
+            loss, acc, grads, bs, a_c, g_s, _ = loss_and_grads_captured(
                 params, bs, images[-1], labels[-1]
             )
             gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
             lsum, asum = lsum + loss, asum + acc
         inv = 1.0 / accum_steps
         grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
-        return lsum * inv, asum * inv, grads, bs, a_c, g_s
+        return lsum * inv, asum * inv, grads, bs, a_c, g_s, {}
 
     @phase("model")
     def accum_loss_and_grads_all_stats(params, batch_stats, images, labels):
@@ -478,7 +476,7 @@ def make_train_step(
         def body(carry, xs):
             bs, gsum, lsum, asum, a_sum, g_sum = carry
             im, lb = xs
-            loss, acc, grads, new_bs, a_c, g_s = loss_and_grads_captured(
+            loss, acc, grads, new_bs, a_c, g_s, _ = loss_and_grads_captured(
                 params, bs, im, lb
             )
             gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
@@ -503,7 +501,7 @@ def make_train_step(
         with phase("kfac_capture"):
             a_c = jax.tree_util.tree_map(lambda a: a * inv, a_sum)
             g_s = jax.tree_util.tree_map(lambda g: g * inv, g_sum)
-        return lsum * inv, asum * inv, grads, bs, a_c, g_s
+        return lsum * inv, asum * inv, grads, bs, a_c, g_s, {}
 
     def train_step(
         state: TrainState,
@@ -542,7 +540,7 @@ def make_train_step(
             and mesh.devices.size > 1
         )
         if use_wrapper:
-            loss, acc, grads, new_bs, a_c, g_s = _compressed_grads(
+            loss, acc, grads, new_bs, a_c, g_s, scalars = _compressed_grads(
                 _compute,
                 mesh,
                 grad_comm_dtype if grad_comm_dtype is not None else jnp.float32,
@@ -550,7 +548,7 @@ def make_train_step(
                 factor_comm,
             )(state.params, state.batch_stats, images, labels)
         else:
-            loss, acc, grads, new_bs, a_c, g_s = _compute(
+            loss, acc, grads, new_bs, a_c, g_s, scalars = _compute(
                 state.params, state.batch_stats, images, labels
             )
 
@@ -609,6 +607,7 @@ def make_train_step(
                 params = optax.apply_updates(state.params, updates)
 
         metrics = {"loss": loss, "accuracy": acc}
+        metrics.update(scalars)
         if kfac is not None and kfac.track_diagnostics:
             metrics.update(diagnostic_metrics(kfac_state["diagnostics"]))
         if kfac_state is not None and "spectrum_mass" in kfac_state:
