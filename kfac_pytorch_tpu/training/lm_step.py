@@ -21,7 +21,7 @@ from kfac_pytorch_tpu import capture, compat
 from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
 from kfac_pytorch_tpu.observability.phases import phase
-from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels, factors
+from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels, factors, flash_attention
 from kfac_pytorch_tpu.preconditioner import KFAC
 from kfac_pytorch_tpu.training.step import (
     TrainState,
@@ -84,6 +84,7 @@ def make_lm_train_step(
             # A contribution through the configured kernel.
             with factor_kernels.factor_kernel_scope(kfac.factor_kernel):
                 return _compute_captured(params, tokens, targets, carry, rngs)
+        flash_attention.reset_flash_tally()  # the gauges count this program's kernels
 
         def loss_fn(params):
             logits, new_carry = model.apply(
@@ -103,6 +104,7 @@ def make_lm_train_step(
     def _compute_captured(params, tokens, targets, carry, rngs):
         perts = capture.perturbation_zeros(model, tokens, train=True)
         factors.reset_capture_tally()  # the gauges count this program's products
+        flash_attention.reset_flash_tally()  # and its attention kernels
 
         def loss_fn(params, perts):
             (logits, new_carry), mut = model.apply(

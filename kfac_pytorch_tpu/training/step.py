@@ -28,7 +28,7 @@ from kfac_pytorch_tpu import capture, compat
 from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS, STEP_SCALARS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
 from kfac_pytorch_tpu.observability.phases import phase
-from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels, factors
+from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels, factors, flash_attention
 from kfac_pytorch_tpu.preconditioner import KFAC
 
 PyTree = Any
@@ -352,6 +352,7 @@ def make_train_step(
     def _loss_and_grads_captured(params, batch_stats, images, labels):
         perts = capture.perturbation_zeros(model, images, **train_kwargs)
         factors.reset_capture_tally()  # the gauges count this program's products
+        flash_attention.reset_flash_tally()  # and its attention kernels
         has_bn = bool(batch_stats)
         mutable = (["batch_stats"] if has_bn else []) + [KFAC_ACTS, STEP_SCALARS]
 
@@ -400,6 +401,7 @@ def make_train_step(
         }
 
     def loss_and_grads_plain(params, batch_stats, images, labels):
+        flash_attention.reset_flash_tally()  # the gauges count this program's kernels
         has_bn = bool(batch_stats)
         mutable = (["batch_stats"] if has_bn else []) + [STEP_SCALARS]
 

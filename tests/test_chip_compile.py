@@ -176,3 +176,28 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, dtype):
 
     hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip, x, x, x)
     assert hlo.count("tpu_custom_call") >= 3  # fwd + two bwd kernels
+
+
+@pytest.mark.parametrize("shape,resident", [
+    ((8, 1024, 12, 64), True), ((1, 2048, 20, 256), True), ((1, 8192, 4, 128), False),
+], ids=["gpt2_124m", "glm47_flash_ep8", "beyond_residency"])
+def test_flash_attention_chosen_tiling_compiles_at_the_cells_shapes(one_chip, shape, resident):
+    """The benchmark's two attention shapes, float32, at the blocks
+    `_choose_blocks` gives them (nothing passed, as the cells' builders pass
+    nothing): a head's K and V resident, key sub-blocks swept inside the grid
+    step, a VMEM limit over the compiler's default for GLM's heads of 256; and
+    a length whose K and V exceed the budget, which takes k-major blocks with
+    a clamped index map (no cell runs it). That the chip's compiler takes the
+    chosen tiling is a fact of this file, not a chip call's surprise."""
+    from kfac_pytorch_tpu.ops.flash_attention import _choose_blocks
+
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    assert (_choose_blocks(shape[1], shape[3], 4).major == shape[1]) == resident
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).sum()
+
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip, x, x, x)
+    entry = hlo[hlo.index("\nENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 3  # fwd, dq, dk/dv
+    assert " dot(" not in hlo and "convolution" not in hlo  # no product outside them
