@@ -169,7 +169,7 @@ def run_trainer(argv):
     assert compiled == len(set(programs)), (
         f"{compiled} step programs compiled for {sorted(set(programs))}"
     )
-    assert (run.kfac.factor_kernel, run.kfac.apply_kernel) == ("dense", "dense")
+    assert run.kfac.factor_kernel == "dense"
     all_finite = jax.jit(lambda tree: jnp.all(jnp.stack(
         [jnp.all(jnp.isfinite(x)) for x in jax.tree_util.tree_leaves(tree)])))
     assert bool(all_finite(run.state)), "non-finite state"
@@ -180,7 +180,6 @@ def run_trainer(argv):
         "argv": argv,
         "precond_method": run.kfac.precond_method,
         "factor_kernel": run.kfac.factor_kernel,
-        "apply_kernel": run.kfac.apply_kernel,
         "step_programs": programs,
         "step_losses": run.step_losses,
         "step_programs_compiled": compiled,

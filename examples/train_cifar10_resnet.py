@@ -202,16 +202,6 @@ def parse_args(argv=None):
                         "= im2col oracle, auto = dense (the Pallas kernel is "
                         "opt-in: the v5e compiler refuses it at ResNet-50 "
                         "shapes, docs/PERF.md)")
-    p.add_argument("--apply-kernel", default="auto",
-                   choices=["auto", "pallas", "dense"],
-                   help="preconditioned-update apply path: pallas = one "
-                        "fused VMEM kernel per shape group (rotate + damped "
-                        "scale + back-rotate + KL-clip partial, plus the "
-                        "momentum/weight-decay update when the step declares "
-                        "sgd_hyper; docs/PERF.md 'Fused apply'), dense = "
-                        "einsum chain + optax oracle, auto = dense (the Pallas "
-                        "kernel is opt-in: the v5e compiler refuses it for "
-                        "multi-layer shape groups, docs/PERF.md)")
     p.add_argument("--bf16", action="store_true",
                    help="bfloat16 conv/matmul compute (params + K-FAC factor "
                         "math stay f32)")
@@ -380,7 +370,6 @@ def main(argv=None):
                 track_diagnostics=args.kfac_diagnostics,
                 eigh_chunks=args.eigh_chunks,
                 factor_kernel=args.factor_kernel,
-                apply_kernel=args.apply_kernel,
                 factor_comm_dtype=args.factor_comm_dtype,
                 factor_comm_freq=args.factor_comm_freq,
                 solver=args.solver,
@@ -433,7 +422,6 @@ def main(argv=None):
                     mesh=mesh if args.grad_comm_dtype else None,
                     grad_comm_dtype=(jnp.bfloat16
                                      if args.grad_comm_dtype == "bf16" else None),
-                    sgd_hyper=(args.momentum, args.wd),
                 )
 
             warm = put_global_batch(
@@ -504,9 +492,6 @@ def main(argv=None):
         stats_all_microbatches=args.stats_all_microbatches,
         mesh=mesh if args.grad_comm_dtype else None,
         grad_comm_dtype=jnp.bfloat16 if args.grad_comm_dtype == "bf16" else None,
-        # tx IS make_sgd(momentum, wd): the declaration lets a pallas
-        # apply_kernel fuse the optimizer pass; inert under dense
-        sgd_hyper=(args.momentum, args.wd) if kfac is not None else None,
     )
     eval_step = make_masked_eval_step(
         model, label_smoothing=args.label_smoothing, eval_kwargs={"train": False}

@@ -113,9 +113,6 @@ def build(model_name, sizes, *, global_batch, seq_len, attention_fn,
     def make_step(kfac):
         return make_train_step(
             model, tx, kfac, train_kwargs={"train": True}, grad_clip=grad_clip,
-            # tx IS make_sgd(momentum, wd): the declaration lets a pallas
-            # apply_kernel fuse the optimizer pass; inert under dense
-            sgd_hyper=(momentum, weight_decay) if kfac is not None else None,
             **(step_kwargs or {}),
         )
 
@@ -259,15 +256,6 @@ def parse_args(argv=None):
                         "(--seq-parallel 1; --tensor-parallel composes). "
                         "Diagonal-A embedding factors shard as [vocab] "
                         "vector slots, so --kfac-embedding composes too")
-    p.add_argument("--apply-kernel", default="auto",
-                   choices=["auto", "pallas", "dense"],
-                   help="preconditioned-update apply path: pallas = one "
-                        "fused VMEM kernel per shape group (rotate + damped "
-                        "scale + back-rotate + KL-clip partial, plus the "
-                        "momentum/weight-decay update; docs/PERF.md 'Fused "
-                        "apply'), dense = einsum chain + optax oracle, auto "
-                        "= dense (the Pallas kernels are opt-in: the v5e compiler "
-                        "refuses them at ResNet-50 shapes, docs/PERF.md)")
     p.add_argument("--solver", default="eigh",
                    choices=["eigh", "rsvd", "streaming"],
                    help="curvature eigensolver: eigh = full (dense) "
@@ -389,7 +377,6 @@ def main(argv=None):
 
     cli_plan = planner.Plan(
         eigh_chunks=args.eigh_chunks,
-        apply_kernel=args.apply_kernel,
         factor_comm_dtype=args.factor_comm_dtype,
         factor_comm_freq=args.factor_comm_freq,
         solver=args.solver,
@@ -554,7 +541,6 @@ def main(argv=None):
             mesh=mesh if devices.size > 1 else None,
             track_diagnostics=args.kfac_diagnostics,
             eigh_chunks=args.eigh_chunks,
-            apply_kernel=args.apply_kernel,
             factor_comm_dtype=args.factor_comm_dtype,
             factor_comm_freq=args.factor_comm_freq,
             solver=args.solver,

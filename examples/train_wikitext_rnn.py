@@ -121,14 +121,6 @@ def parse_args(argv=None):
                         "O(model/devices) factor memory; embedding diag-A "
                         "factors shard as [vocab] vector slots, so "
                         "--kfac-embedding composes (docs/PERF.md)")
-    p.add_argument("--apply-kernel", default="auto",
-                   choices=["auto", "pallas", "dense"],
-                   help="preconditioned-update apply path: pallas = one "
-                        "fused VMEM kernel per shape group, incl. the "
-                        "momentum/weight-decay update (docs/PERF.md 'Fused "
-                        "apply'); dense = einsum chain + optax oracle; auto "
-                        "= dense (the Pallas kernels are opt-in: the v5e compiler "
-                        "refuses them at ResNet-50 shapes, docs/PERF.md)")
     p.add_argument("--solver", default="eigh",
                    choices=["eigh", "rsvd", "streaming"],
                    help="curvature eigensolver (rsvd: randomized truncated "
@@ -216,7 +208,6 @@ def main(argv=None):
             # matrix's reasons instead of ad-hoc SystemExits
             cli_plan = planner.Plan(
                 eigh_chunks=args.eigh_chunks,
-                apply_kernel=args.apply_kernel,
                 factor_comm_dtype=args.factor_comm_dtype,
                 factor_comm_freq=args.factor_comm_freq,
                 solver=args.solver,
@@ -266,7 +257,6 @@ def main(argv=None):
                 kfac_update_freq=args.kfac_update_freq,
                 mesh=mesh,
                 eigh_chunks=args.eigh_chunks,
-                apply_kernel=args.apply_kernel,
                 factor_comm_dtype=args.factor_comm_dtype,
                 factor_comm_freq=args.factor_comm_freq,
                 solver=args.solver,
@@ -341,9 +331,6 @@ def main(argv=None):
         model, tx, kfac, grad_clip=args.clip,
         mesh=mesh if args.grad_comm_dtype else None,
         grad_comm_dtype=jnp.bfloat16 if args.grad_comm_dtype == "bf16" else None,
-        # tx IS make_sgd(momentum, wd): the declaration lets a pallas
-        # apply_kernel fuse the optimizer pass; inert under dense
-        sgd_hyper=(args.momentum, args.wd) if kfac is not None else None,
     )
     eval_step = make_lm_eval_step(model)
 

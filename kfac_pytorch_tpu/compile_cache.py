@@ -79,11 +79,9 @@ def expected_step_variants(kfac, plan=None, autotune_candidates: int = 0) -> int
     the rank policy is a pure function of static factor shapes, so it
     swaps WHICH programs compile (truncated vs dense refresh, Woodbury
     vs dense apply), never how many the schedule produces.
-    The same holds for ``apply_kernel`` and the int8 wire: the fused
-    Pallas apply swaps the eigenbasis-apply (and, with ``sgd_hyper``, the
-    optimizer-pass) program bodies, and ``factor_comm_dtype="int8"``
-    swaps the flush program's merge body — neither adds a static flag, so
-    neither widens the budget (tests/test_fused_apply.py pins this).
+    The same holds for the int8 wire: ``factor_comm_dtype="int8"`` swaps
+    the flush program's merge body and adds no static flag, so it does not
+    widen the budget (tests/test_wire_quant.py pins this).
     ``solver="streaming"`` CAN change it: the replay drives the cadence
     with no drift signal (re-orth at every boundary), and a run with a
     wired signal may additionally skip boundary re-orths — so every

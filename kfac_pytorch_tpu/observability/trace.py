@@ -25,9 +25,9 @@ Record schema (one JSON object per line)::
 ``scripts/check_trace_events.py`` lint keeps the docs event registry and
 the emitted set in sync, same contract as the metric-name lint.
 
-Host identity deliberately never touches jax: ``bench.py`` configures
-tracing *before* the backend probe (so the probe itself is traceable),
-at which point ``jax.process_index()`` would initialize the backend.
+Host identity deliberately never touches jax: a caller may configure
+tracing before its first device use, at which point
+``jax.process_index()`` would initialize the backend.
 Callers that know their rank pass ``host=``; otherwise the env fallback
 (``KFAC_TRACE_HOST``/``JAX_PROCESS_ID``/``PROCESS_ID``) applies.
 """

@@ -18,7 +18,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kfac_pytorch_tpu import compat
-from kfac_pytorch_tpu.ops import apply_kernels
 
 _HIGHEST = lax.Precision.HIGHEST
 # Eigenbasis rotations default to HIGH (3-pass bf16 error compensation,
@@ -340,39 +339,6 @@ def solve_eigen_entry(
     )
 
 
-def solve_eigen_entry_maybe_fused(
-    g: jnp.ndarray,
-    e: Dict[str, jnp.ndarray],
-    damping: jnp.ndarray,
-    precision: lax.Precision = _ROTATION_PRECISION,
-) -> jnp.ndarray:
-    """Per-entry fused-kernel routing for the distributed/owner solves.
-
-    The owner-sharded (:func:`precondition_all_owner`) and
-    distributed-precondition (:func:`precondition_all_distributed`) paths
-    solve ONE layer at a time inside ``lax.cond`` owner branches — there is
-    no stack to batch, but a ``k=1`` fused pass still collapses the layer's
-    five-matmul chain into one VMEM residency, shortening the owner-side
-    critical path BEFORE the single pinned payload collective (the packed
-    allgather then overlaps whatever replicated re-solves follow it in the
-    latency-hiding scheduler). Under a dense scope, or for any form the
-    fused kernel does not cover (diagonal-A, low-rank), this is exactly
-    :func:`solve_eigen_entry`. The KL-clip by-product is discarded here:
-    these paths reduce ν from the gathered updates as before.
-    """
-    if (
-        apply_kernels.active_apply_kernel() == "pallas"
-        and "QA" in e
-        and not entry_is_lowrank(e)
-    ):
-        v, _ = apply_kernels.dispatch_precondition_stack(
-            g[None], e["QA"][None], e["dA"][None], e["QG"][None],
-            e["dG"][None], damping,
-        )
-        return v[0]
-    return solve_eigen_entry(g, e, damping, precision)
-
-
 def precondition_all(
     grad_mats: Dict[str, jnp.ndarray],
     eigen: Dict[str, Dict[str, jnp.ndarray]],
@@ -437,110 +403,6 @@ def precondition_all(
         for row, name in enumerate(names):
             out[name] = v[row]
     return out
-
-
-def precondition_all_with_vg(
-    grad_mats: Dict[str, jnp.ndarray],
-    eigen: Dict[str, Dict[str, jnp.ndarray]],
-    damping: jnp.ndarray,
-    precision: lax.Precision = _ROTATION_PRECISION,
-    stacked: Optional[Dict[str, Dict[str, jnp.ndarray]]] = None,
-) -> Tuple[Dict[str, jnp.ndarray], Optional[list]]:
-    """:func:`precondition_all` + per-layer KL-clip partials, kernel-routed.
-
-    Under a dense :func:`~kfac_pytorch_tpu.ops.apply_kernels.apply_kernel_scope`
-    (the default — shape-only tracing never opens a scope) this delegates to
-    the verbatim :func:`precondition_all` and returns ``vg_terms=None``; the
-    caller then recomputes the KL-clip sum from HBM via
-    :func:`kl_clip_coefficient` exactly as before, keeping the default
-    program bit-identical. Under a "pallas" scope, full-eigen dense entries
-    — stacked groups AND singletons (a ``k=1`` stack) — run through the
-    fused VMEM kernel, which also emits each layer's ``Σ v·g`` partial;
-    diagonal-A (embedding) and low-rank (Woodbury/streaming-truncated)
-    entries stay on the dense solve with their partial reduced densely. The
-    returned ``vg_terms`` list is in EMISSION order — identical to the
-    ``updates`` dict insertion order that fixes the
-    :func:`kl_clip_coefficient` summation order — so
-    :func:`kl_clip_from_vg` reproduces the same left-to-right f32 sum.
-    """
-    if apply_kernels.active_apply_kernel() != "pallas":
-        return (
-            precondition_all(grad_mats, eigen, damping, precision, stacked),
-            None,
-        )
-    diag_a = diag_a_names(eigen)
-    out: Dict[str, jnp.ndarray] = {}
-    vg_terms: list = []
-
-    def _dense_entry(name: str, e: Dict[str, jnp.ndarray]) -> None:
-        v = solve_eigen_entry(grad_mats[name], e, damping, precision)
-        out[name] = v
-        vg_terms.append(
-            jnp.sum(
-                v.astype(jnp.float32) * grad_mats[name].astype(jnp.float32)
-            )
-        )
-
-    # sorted: same fixed emission order as precondition_all (the KL-clip
-    # summation order must not vary per process)
-    for name in sorted(diag_a):
-        _dense_entry(name, eigen[name])
-    shapes = {
-        name: g.shape for name, g in grad_mats.items() if name not in diag_a
-    }
-    for (go, ai), names in shape_groups(shapes).items():
-        key = f"{go}x{ai}"
-        if len(names) == 1:
-            e = eigen[names[0]]
-            if entry_is_lowrank(e):
-                _dense_entry(names[0], e)
-                continue
-            s = {k: e[k][None] for k in ("QA", "QG", "dA", "dG")}
-        elif stacked is not None and key in stacked:
-            s = stacked[key]
-        else:
-            keys = eigen[names[0]].keys()
-            s = {k: jnp.stack([eigen[n][k] for n in names]) for k in keys}
-        gm = jnp.stack([grad_mats[n] for n in names])  # [k, out, in]
-        if entry_is_lowrank(s):
-            v = jax.vmap(
-                lambda g, e: solve_eigen_entry(g, e, damping, precision)
-            )(gm, s)
-            for row, name in enumerate(names):
-                out[name] = v[row]
-                vg_terms.append(
-                    jnp.sum(
-                        v[row].astype(jnp.float32)
-                        * gm[row].astype(jnp.float32)
-                    )
-                )
-            continue
-        v, vg = apply_kernels.dispatch_precondition_stack(
-            gm, s["QA"], s["dA"], s["QG"], s["dG"], damping
-        )
-        for row, name in enumerate(names):
-            out[name] = v[row]
-            vg_terms.append(vg[row])
-    return out, vg_terms
-
-
-def kl_clip_from_vg(
-    vg_terms: list,
-    lr: jnp.ndarray,
-    kl_clip: float,
-) -> jnp.ndarray:
-    """:func:`kl_clip_coefficient` from pre-reduced per-layer partials.
-
-    Consumes the ``vg_terms`` the fused apply emitted as kernel by-products
-    — the dense path's separate ``Σ v·g`` pass over every update/gradient
-    pair in HBM is exactly what the fusion deletes. Same left-to-right f32
-    accumulation, same per-term ``lr²`` scaling, same 1e-30 floor.
-    """
-    vg_sum = jnp.asarray(0.0, dtype=jnp.float32)
-    for t in vg_terms:
-        vg_sum = vg_sum + t.astype(jnp.float32) * (lr**2)
-    denom = jnp.maximum(jnp.abs(vg_sum), 1e-30)
-    return jnp.minimum(1.0, jnp.sqrt(kl_clip / denom))
 
 
 def _stack_layout(
@@ -683,7 +545,7 @@ def precondition_all_distributed(
     """
 
     def _solve(g, e, damp):
-        return solve_eigen_entry_maybe_fused(g, e, damp, precision)
+        return solve_eigen_entry(g, e, damp, precision)
 
     return _apply_distributed(
         grad_mats, eigen, stacked, damping, mesh, owners, _solve, comm_dtype
@@ -868,7 +730,7 @@ def precondition_all_owner(
             def _payload(name=name, seg=seg):
                 entry = _entry(eshard, name)
                 if seg["mode"] == "update":
-                    v = solve_eigen_entry_maybe_fused(
+                    v = solve_eigen_entry(
                         gmats[name], entry, damp, precision
                     )
                     return v.astype(jnp.float32).reshape(-1)

@@ -12,9 +12,10 @@ One production entry point over the levers PRs 2–6 landed individually:
 * :mod:`drift` — post-run plan-vs-measured comparison publishing the
   ``kfac/plan_drift_*`` ratio gauges.
 
-Consumed by ``KFAC(profile=...)`` (preconditioner.py), both example CLIs
-(``--profile``/``--autotune-steps``), bench.py's ``-prod`` arm, and the
-golden-plan lint ``scripts/check_plan_snapshot.py``. See docs/PLANNER.md.
+Consumed by ``KFAC(...)`` itself (preconditioner.py: the validity matrix
+on every construction, the rest under ``profile=``), both example CLIs
+(``--profile``/``--autotune-steps``) and the golden-plan lint
+``scripts/check_plan_snapshot.py``. See docs/PLANNER.md.
 """
 
 from kfac_pytorch_tpu.planner.autotune import (
@@ -95,10 +96,6 @@ def log_plan(plan: Plan, dropped=(), telemetry=None) -> None:
     tel.set_gauge(
         "kfac/plan_factor_comm_int8",
         1.0 if plan.factor_comm_dtype == "int8" else 0.0,
-    )
-    tel.set_gauge(
-        "kfac/plan_apply_kernel_pallas",
-        1.0 if plan.apply_kernel == "pallas" else 0.0,
     )
     tel.set_gauge("kfac/plan_factor_comm_freq", float(plan.factor_comm_freq))
     tel.set_gauge(

@@ -480,10 +480,10 @@ def _resolve_production(facts: ModelFacts, env: PlanEnv) -> Plan:
         ):
             plan = dataclasses.replace(plan, staleness_budget=1)
 
-    # kernels: factor_kernel / apply_kernel stay "auto" (= dense) on every
-    # backend. The Pallas capture and apply kernels are refused by the v5e
-    # compiler at ResNet-50 shapes (docs/PERF.md, "Refused by the v5e
-    # compiler"), so no profile pins them; they are an explicit opt-in.
+    # kernels: factor_kernel stays "auto" (= dense) on every backend. The
+    # Pallas capture kernel is refused by the v5e compiler at ResNet-50
+    # shapes (docs/PERF.md, "Refused by the v5e compiler"), so no profile
+    # pins it; it is an explicit opt-in.
     return plan
 
 

@@ -48,7 +48,6 @@ LEVER_FIELDS = (
     "staleness_budget",
     "stream_drift_threshold",
     "service_devices",
-    "apply_kernel",
 )
 
 
@@ -80,11 +79,6 @@ class Plan:
     # dedicated refresh workers (kfac_pytorch_tpu/service/). 0 = refresh
     # stays in-step (bitwise-inert default).
     service_devices: int = 0
-    # Fused Pallas apply (ops/apply_kernels.py): the whole per-layer
-    # eigenbasis apply — rotate, damped scale, back-rotate, KL-clip term —
-    # in one VMEM-resident kernel. "auto" resolves like factor_kernel
-    # (dense on every backend); mirrors the constructor default.
-    apply_kernel: str = "auto"
 
     def kfac_kwargs(self) -> Dict[str, object]:
         """The KFAC constructor kwargs this plan pins."""
@@ -95,15 +89,14 @@ class Plan:
 
         ``solver_rank``/``solver_auto_threshold``/``stream_drift_threshold``
         count only when a truncating solver is actually on, and
-        ``factor_kernel``/``apply_kernel`` count only when pinned away from
-        ``auto`` — matching what changes the compiled program.
+        ``factor_kernel`` counts only when pinned away from ``auto`` —
+        matching what changes the compiled program.
         """
         default = Plan()
         out = []
         for f in ("eigh_chunks", "factor_kernel", "factor_comm_dtype",
                   "factor_comm_freq", "solver", "factor_sharding",
-                  "comm_overlap", "staleness_budget", "service_devices",
-                  "apply_kernel"):
+                  "comm_overlap", "staleness_budget", "service_devices"):
             if getattr(self, f) != getattr(default, f):
                 out.append(f)
         return tuple(out)
@@ -166,12 +159,13 @@ class Plan:
                 round(self.stream_drift_threshold * self._DRIFT_SCALE)
             ),
             "service_devices": self.service_devices,
-            "apply_kernel": self._KERNELS.index(self.apply_kernel),
         }
         return {k: np.asarray(v, np.int32) for k, v in enc.items()}
 
     @classmethod
     def from_state(cls, state: Dict[str, np.ndarray]) -> "Plan":
+        # keys this class no longer has (``apply_kernel``, in checkpoints
+        # written before its path went) are read past, not refused
         g = {k: int(np.asarray(v)) for k, v in state.items()}
         return cls(
             eigh_chunks=g["eigh_chunks"],
@@ -195,9 +189,6 @@ class Plan:
             ),
             # absent in pre-service checkpoints: refresh stays in-step
             service_devices=g.get("service_devices", 0),
-            # absent in pre-fused-apply checkpoints: index 0 = "auto",
-            # the field default
-            apply_kernel=cls._KERNELS[g.get("apply_kernel", 0)],
         )
 
     def describe(self) -> str:
@@ -234,8 +225,6 @@ class Plan:
             bits.append(f"staleness_budget={self.staleness_budget}")
         if "service_devices" in on:
             bits.append(f"service_devices={self.service_devices}")
-        if "apply_kernel" in on:
-            bits.append(f"apply_kernel={self.apply_kernel}")
         return "plan: " + " ".join(bits)
 
 
@@ -243,8 +232,9 @@ class Plan:
 class PlanEnv:
     """Everything a plan's validity and cost depend on besides the levers.
 
-    ``mesh_axes`` is the KFAC mesh's axis-name tuple (empty when no mesh);
-    ``world`` its total device count (1 without a mesh); ``data_world`` the
+    ``mesh_axes`` names the KFAC mesh's batch axis and every other axis of
+    size > 1 (empty when no mesh; another axis of size 1 splits nothing,
+    and no enforcement point counts it); ``world`` its total device count (1 without a mesh); ``data_world`` the
     device count along the factor (data) axes only — 0 means "same as
     world", which holds on every 1-D mesh; a 2-D data×tensor mesh passes
     the data-axis size, since owner shard stacks split over the data axis
@@ -268,6 +258,10 @@ class PlanEnv:
     # unchanged.
     has_shard_lens_layers: bool = False
     has_moe_layers: bool = False
+    # Expert banks ("#b" layer names) or shared_a: the inverses then live in
+    # one table per side (ops/precondition.py, "Inverse tables"), a layout
+    # only the replicated inverse path has.
+    has_inverse_tables: bool = False
     fac_update_freq: int = 10
     kfac_update_freq: int = 100
     # The curvature-service carve the OPERATOR has offered (devices already
@@ -333,7 +327,9 @@ RULES: Tuple[Rule, ...] = (
         drop=("eigh_chunks",),
         enforced_by="constructor",
         message="eigh_chunks > 1 pipelines the eigendecomposition refresh; "
-                "precond_method='inverse' has no eigh spike to spread",
+                "precond_method='inverse' refreshes via one batched Cholesky "
+                "~30x cheaper than the eigh it replaces — there is no spike to "
+                "spread, so refusing a config that implies one",
     ),
     Rule(
         name="rsvd_vs_inverse",
@@ -341,9 +337,10 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.precond_method == "inverse",
         drop=("solver",),
         enforced_by="constructor",
-        message="a truncating solver (rsvd/streaming) feeds the eigenbasis "
-                "(Woodbury) apply path; precond_method='inverse' would "
-                "silently ignore it",
+        message="a truncating solver ('rsvd'/'streaming') produces a truncated "
+                "eigenbasis consumed by the eigenbasis (Woodbury) apply path; "
+                "precond_method='inverse' preconditions with explicit Cholesky "
+                "inverses and would silently ignore the configured solver",
     ),
     Rule(
         name="rsvd_vs_diag_blocks",
@@ -351,8 +348,10 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.diag_blocks > 1,
         drop=("solver",),
         enforced_by="constructor",
-        message="a truncating solver (rsvd/streaming) stores one basis per "
-                "whole factor; diag_blocks > 1 carves factors into blocks",
+        message="a truncating solver ('rsvd'/'streaming') stores one (Q_r, d_r, "
+                "rho) triple per whole factor; diag_blocks > 1 carves factors "
+                "into diagonal blocks whose truncated bases cannot share that "
+                "layout — pick one approximation",
     ),
     Rule(
         name="owner_vs_inverse",
@@ -360,9 +359,10 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.precond_method != "eigen",
         drop=("factor_sharding",),
         enforced_by="constructor",
-        message="factor_sharding='owner' shards eigenbasis state; "
-                "precond_method='inverse' keeps Cholesky inverses it does "
-                "not lay out",
+        message="factor_sharding='owner' shards the eigenbasis state; "
+                "precond_method='inverse' keeps explicit Cholesky inverses that "
+                "this mode does not lay out — use the eigen method or "
+                "replicated sharding",
     ),
     Rule(
         name="owner_vs_diag_blocks",
@@ -371,7 +371,8 @@ RULES: Tuple[Rule, ...] = (
         drop=("factor_sharding",),
         enforced_by="constructor",
         message="factor_sharding='owner' stores one whole-factor slot per "
-                "(layer, side); diag_blocks > 1 has its own owner table",
+                "(layer, side); diag_blocks > 1 carves factors into blocks with "
+                "their own owner table — pick one distribution scheme",
     ),
     Rule(
         name="owner_vs_distribute_precondition",
@@ -379,9 +380,10 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.distribute_precondition,
         drop=("factor_sharding",),
         enforced_by="constructor",
-        message="factor_sharding='owner' already preconditions each layer "
-                "on its owner; distribute_precondition would layer a second "
-                "owner table on top",
+        message="factor_sharding='owner' already preconditions each layer on "
+                "its owner (that is where its eigenbasis lives); "
+                "distribute_precondition=True would layer a second, different "
+                "owner table on top — drop it",
     ),
     Rule(
         name="owner_vs_diagnostics",
@@ -389,8 +391,9 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.track_diagnostics,
         drop=("factor_sharding",),
         enforced_by="constructor",
-        message="factor_sharding='owner' keeps no replicated per-layer "
-                "spectra for the diagnostics pytree to read",
+        message="factor_sharding='owner' keeps no replicated per-layer spectra "
+                "for the diagnostics pytree to read — run track_diagnostics "
+                "with replicated sharding",
     ),
     Rule(
         name="owner_vs_multi_axis_mesh",
@@ -398,9 +401,11 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.multi_device and not e.pure_dp,
         drop=("factor_sharding",),
         enforced_by="constructor",
-        message="factor_sharding='owner' requires a single data axis to "
-                "shard across (extra axes are allowed only under the "
-                "replicated-compute tensor* convention)",
+        message="factor_sharding='owner' requires a data-plane mesh (one batch "
+                "axis plus optional 'tensor*'/'fsdp*' axes): the shard stacks "
+                "ride the factor plane only, and any other axis of size > 1 "
+                "would split examples or factor rows in ways the plan cannot "
+                "see",
     ),
     # PR-6's owner_vs_diag_a_layers refusal used to live here; owner
     # sharding now lays diagonal-A (embedding) factors out as [vocab]
@@ -468,8 +473,9 @@ RULES: Tuple[Rule, ...] = (
         drop=("eigh_chunks",),
         enforced_by="constructor",
         message="solver='streaming' replaces the periodic refresh with a "
-                "per-step fold — no recurring eigh spike remains for "
-                "eigh_chunks > 1 to spread",
+                "per-step fold — there is no recurring eigh spike left for "
+                "eigh_chunks > 1 to spread, and the chunk plan's double buffer "
+                "would shadow the streamed tables",
     ),
     Rule(
         name="streaming_vs_swap_slip",
@@ -478,8 +484,9 @@ RULES: Tuple[Rule, ...] = (
         drop=("staleness_budget",),
         enforced_by="constructor",
         message="solver='streaming' has no pending eigen swap to slip — "
-                "re-orthonormalizations land in place on drift boundaries, "
-                "so a staleness_budget would silently mean nothing",
+                "re-orthonormalizations land in place on drift boundaries — so "
+                "a staleness_budget would silently mean nothing on the eigen "
+                "side; leave staleness_budget=0",
     ),
     # Curvature-service exclusions (service/ — refresh runs on carved
     # workers, out of the training step). Environment conflicts shed the
@@ -494,9 +501,9 @@ RULES: Tuple[Rule, ...] = (
         drop=("service_devices",),
         enforced_by="constructor",
         message="service_devices > 0 publishes factor snapshots to workers "
-                "that refresh an eigenbasis; precond_method='inverse' "
-                "refreshes ~30x-cheaper Cholesky inverses in-step — no "
-                "refresh spike worth a carve",
+                "that refresh an EIGENBASIS; precond_method='inverse' refreshes "
+                "~30x-cheaper Cholesky inverses in-step — there is no refresh "
+                "spike worth a carve",
     ),
     Rule(
         name="service_vs_streaming",
@@ -515,9 +522,9 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: p.eigh_chunks > 1,
         drop=("eigh_chunks",),
         enforced_by="constructor",
-        message="service_devices > 0 removes the refresh from the training "
-                "step entirely; eigh_chunks > 1 spreads an in-step refresh "
-                "spike that no longer exists",
+        message="service_devices > 0 removes the refresh from the training step "
+                "entirely; eigh_chunks > 1 spreads an in-step refresh spike "
+                "that no longer exists — leave eigh_chunks=1",
     ),
     Rule(
         name="service_vs_diag_blocks",
@@ -525,9 +532,9 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.diag_blocks > 1,
         drop=("service_devices",),
         enforced_by="constructor",
-        message="service_devices > 0 runs the worker refresh on whole "
-                "factors; diag_blocks > 1 needs the trainer-side conv "
-                "layout the published snapshot does not carry",
+        message="service_devices > 0 runs the worker refresh on whole factors; "
+                "diag_blocks > 1 needs the trainer-side conv layout the "
+                "published snapshot does not carry — leave diag_blocks=1",
     ),
     Rule(
         name="service_vs_owner_sharding",
@@ -538,10 +545,11 @@ RULES: Tuple[Rule, ...] = (
         and e.factor_world > 1,
         drop=("service_devices",),
         enforced_by="constructor",
-        message="service_devices > 0 publishes full replicated factor "
-                "snapshots and installs full replicated bases; "
-                "factor_sharding='owner' keeps per-owner shards that would "
-                "have to gather through the mailbox every boundary",
+        message="service_devices > 0 publishes full replicated factor snapshots "
+                "and installs full replicated bases; factor_sharding='owner' "
+                "keeps per-owner shards that would have to gather through the "
+                "mailbox every boundary — run the service with replicated "
+                "sharding",
     ),
     # Shard-lens / MoE exclusions (kfac_pytorch_tpu/shardwise/). The model
     # facts are ENV, not levers, so two of these rows guard env-vs-env
@@ -559,10 +567,10 @@ RULES: Tuple[Rule, ...] = (
         ),
         drop=(),
         enforced_by="constructor",
-        message="shard-lens/MoE layers precondition through per-shard "
-                "eigenbases (shardwise.precondition); precond_method="
-                "'inverse' keeps whole-factor Cholesky inverses that have "
-                "no per-shard block layout",
+        message="shard-lens layers and MoE expert banks precondition per shard "
+                "block in the eigenbasis (shardwise.precondition); "
+                "precond_method='inverse' keeps whole-factor Cholesky inverses "
+                "with no per-block layout — use the eigen method",
     ),
     Rule(
         name="shard_lens_vs_diag_blocks",
@@ -573,9 +581,10 @@ RULES: Tuple[Rule, ...] = (
         ),
         drop=(),
         enforced_by="constructor",
-        message="shard-lens/MoE factors already carry a stack (block) "
-                "dimension per shard; diag_blocks > 1 would carve a second "
-                "block structure into the same factors",
+        message="shard-lens layers and MoE expert banks already block their "
+                "factors along shard/expert boundaries; diag_blocks > 1 would "
+                "carve a second, conflicting block structure into the same "
+                "factors",
     ),
     Rule(
         name="shard_lens_vs_owner_sharding",
@@ -583,10 +592,11 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.has_shard_lens_layers,
         drop=("factor_sharding",),
         enforced_by="constructor",
-        message="shard-lens factors are already device-sharded along the "
-                "tensor axis (shardwise.factor_leaf_spec); factor_sharding="
-                "'owner' would re-shard them over the batch axes and force "
-                "a gather on every solve",
+        message="shard-lens layers pin each factor block to the device holding "
+                "the matching kernel shard (shardwise.factor_leaf_spec); "
+                "factor_sharding='owner' would re-home those blocks onto LPT "
+                "owners and gather them back every step — pick one placement "
+                "scheme",
     ),
     Rule(
         name="moe_vs_owner_sharding",
@@ -595,9 +605,10 @@ RULES: Tuple[Rule, ...] = (
         drop=("factor_sharding",),
         enforced_by="constructor",
         message="MoE expert banks keep per-expert [E, n, n] factor stacks "
-                "whose token-count-weighted EMA runs where the dispatch "
-                "statistics live; factor_sharding='owner' has no slot "
-                "layout for expert stacks",
+                "pinned where the dispatch statistics live "
+                "(shardwise.factor_leaf_spec); factor_sharding='owner' would "
+                "re-home those blocks onto LPT owners and gather them back "
+                "every step — pick one placement scheme",
     ),
     Rule(
         name="shard_lens_vs_chunks",
@@ -605,9 +616,10 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.has_shard_lens_layers or e.has_moe_layers,
         drop=("eigh_chunks",),
         enforced_by="constructor",
-        message="eigh_chunks > 1 pipelines the refresh through the "
-                "whole-factor slot planner; shard-lens/MoE stacks refresh "
-                "as batched per-block eigh outside that plan",
+        message="shard-lens layers and MoE expert banks refresh densely per "
+                "block — there is no whole-factor eigh spike for eigh_chunks > "
+                "1 to spread, and the chunk planner's slot tables do not "
+                "describe stacked factors",
     ),
     Rule(
         name="shard_lens_vs_streaming",
@@ -615,9 +627,10 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.has_shard_lens_layers or e.has_moe_layers,
         drop=("solver",),
         enforced_by="constructor",
-        message="solver='streaming' folds factors through retained "
-                "whole-factor bases; shard-lens/MoE stacks have no "
-                "streaming fold",
+        message="shard-lens layers and MoE expert banks keep dense per-block "
+                "bases; solver='streaming' folds factors through retained "
+                "truncated bases that the stacked layout does not carry — "
+                "non-shard layers may ride solver='rsvd' instead",
     ),
     Rule(
         name="moe_vs_deferred_comm",
@@ -625,9 +638,11 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.has_moe_layers,
         drop=("factor_comm_freq",),
         enforced_by="constructor",
-        message="factor_comm_freq > 1 merges deferred factor EMAs by "
-                "linearity; the MoE token-count-weighted per-expert decay "
-                "(alpha**(f_e*E)) is not linear in the deferred statistics",
+        message="MoE expert banks use the token-count-weighted EMA "
+                "(shardwise.moe_ema), whose per-expert decay alpha**w_e is not "
+                "linear in the contributions — deferred factor communication "
+                "(factor_comm_freq > 1) merges per-replica EMAs by linearity "
+                "and would silently corrupt expert statistics",
     ),
     Rule(
         name="service_vs_shard_lens",
@@ -635,9 +650,30 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: e.has_shard_lens_layers or e.has_moe_layers,
         drop=("service_devices",),
         enforced_by="constructor",
-        message="service_devices > 0 publishes replicated whole-factor "
-                "snapshots to refresh workers; shard-lens/MoE factor "
-                "stacks live device-sharded and never leave the mesh",
+        message="shard-lens layers and MoE expert banks refresh in-step (cheap "
+                "dense per-block eigh); service_devices > 0 publishes "
+                "whole-factor snapshots the worker protocol does not lay out "
+                "as stacks — run the service on unsharded models",
+    ),
+    # BEFORE the int8 rows for the same reason as moe_vs_deferred_comm: it
+    # strips factor_comm_freq. The method and the two env flags are not
+    # levers, so fit_plan can only shed the two levers that are.
+    Rule(
+        name="inverse_tables_vs_other_paths",
+        applies=lambda p: True,
+        conflicts=lambda p, e: e.has_inverse_tables and (
+            e.precond_method != "inverse"
+            or p.factor_sharding == "owner"
+            or e.distribute_precondition
+            or e.track_diagnostics
+            or p.factor_comm_freq > 1
+        ),
+        drop=("factor_sharding", "factor_comm_freq"),
+        enforced_by="constructor",
+        message="expert banks ('#b' layers) and shared_a run on the "
+                "replicated inverse path alone: precond_method='inverse', "
+                "factor_sharding='replicated', no distribute_precondition, "
+                "no track_diagnostics, factor_comm_freq=1",
     ),
     # Int8 wire exclusions (parallel/comm.py block-scaled quantization).
     # AFTER moe_vs_deferred_comm and the comm single-device/multi-axis
@@ -651,12 +687,12 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: p.factor_comm_freq <= 1,
         drop=("factor_comm_dtype",),
         enforced_by="constructor",
-        message="factor_comm_dtype='int8' quantizes the deferred factor "
-                "flush with error-feedback residuals carried in "
+        message="factor_comm_dtype='int8' quantizes the deferred factor flush "
+                "with error-feedback accumulators carried in "
                 "state['wire_error']; factor_comm_freq=1 exchanges "
-                "contributions every capture step with no residual slot — "
-                "the rounding bias would accumulate unrecoverably in the "
-                "EMA",
+                "contributions every capture step with no residual slot to "
+                "carry, so each exchange would bias the EMA with unrecoverable "
+                "rounding — set factor_comm_freq > 1 or widen the wire to bf16",
     ),
     Rule(
         name="int8_wire_vs_owner_sharding",
@@ -664,25 +700,10 @@ RULES: Tuple[Rule, ...] = (
         conflicts=lambda p, e: p.factor_sharding == "owner",
         drop=("factor_comm_dtype",),
         enforced_by="constructor",
-        message="factor_comm_dtype='int8' exchanges codes + block scales "
-                "over all_gather on the replicated deferred flush; "
-                "factor_sharding='owner' merges through psum_scatter, "
-                "which would widen the int8 codes on-wire — use the bf16 "
-                "wire with owner sharding",
-    ),
-    # Degrade, not refusal: the constructor warns and resolves the apply
-    # kernel to dense (ops/apply_kernels.py routes only the eigenbasis
-    # apply; the inverse method never builds one).
-    Rule(
-        name="apply_pallas_vs_inverse",
-        applies=lambda p: p.apply_kernel == "pallas",
-        conflicts=lambda p, e: e.precond_method == "inverse",
-        drop=("apply_kernel",),
-        enforced_by="degrade",
-        message="apply_kernel='pallas' fuses the eigenbasis rotate/scale/"
-                "back-rotate apply; precond_method='inverse' preconditions "
-                "through Cholesky inverse matmuls with no eigenbasis to "
-                "fuse",
+        message="factor_comm_dtype='int8' rides the replicated deferred flush "
+                "(codes + block scales over all_gather); factor_sharding='owner' "
+                "exchanges through psum_scatter, which would have to widen the "
+                "codes on-wire — use the bf16 wire with owner sharding",
     ),
     # Last on purpose: its conflict is plan-internal, so it must see the
     # plan AFTER every rule above has cleared levers — a fitted plan that
@@ -697,12 +718,12 @@ RULES: Tuple[Rule, ...] = (
         ),
         drop=("staleness_budget",),
         enforced_by="constructor",
-        message="staleness_budget > 0 bounds how far a deferred factor "
-                "flush, a pending eigen swap, or a service basis install "
-                "may slip, and this configuration has none of them: enable "
-                "factor_comm_freq > 1 (deferred flushes), eigh_chunks > 1 "
-                "(pending swaps), or service_devices > 0 (curvature "
-                "service)",
+        message="staleness_budget > 0 bounds how far a deferred factor flush, a "
+                "pending eigen swap, or a service basis install may slip, and "
+                "this configuration has none of them: enable factor_comm_freq > "
+                "1 (deferred reduction), eigh_chunks > 1 (pipelined refresh), "
+                "or service_devices > 0 (curvature service), or leave "
+                "staleness_budget=0",
     ),
 )
 
@@ -718,9 +739,16 @@ def violations(plan: Plan, env: PlanEnv,
     return [r for r in rules if r.applies(plan) and r.conflicts(plan, env)]
 
 
-def check_plan(plan: Plan, env: PlanEnv) -> None:
-    """Raise ``ValueError`` listing every refusal this plan would hit."""
-    bad = violations(plan, env)
+def check_plan(
+    plan: Plan, env: PlanEnv, enforced_by: Optional[str] = None
+) -> None:
+    """Raise ``ValueError`` listing every refusal this plan would hit, or,
+    for the enforcement point that names itself (``KFAC.__init__`` passes
+    ``"constructor"``), only the rows that point raises."""
+    bad = [
+        r for r in violations(plan, env)
+        if enforced_by is None or r.enforced_by == enforced_by
+    ]
     if bad:
         lines = "; ".join(f"[{r.name}] {r.message}" for r in bad)
         raise ValueError(f"invalid lever composition: {lines}")

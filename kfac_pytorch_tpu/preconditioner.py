@@ -40,10 +40,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from kfac_pytorch_tpu import capture, shardwise
+from kfac_pytorch_tpu import capture, planner, shardwise
 from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.observability.telemetry import get_telemetry
-from kfac_pytorch_tpu.ops import apply_kernels as apply_kernel_ops
 from kfac_pytorch_tpu.ops import factor_kernels as factor_kernel_ops
 from kfac_pytorch_tpu.ops import factors as factor_ops
 from kfac_pytorch_tpu.ops import precondition as precond_ops
@@ -128,6 +127,35 @@ def _non_tensor_world(mesh: Optional[Mesh], axis_name: str) -> int:
     return world
 
 
+# factor_comm_dtype spellings, and the names planner.Plan knows them by
+_FACTOR_COMM_DTYPES = {
+    "f32": jnp.float32,
+    "float32": jnp.float32,
+    "bf16": jnp.bfloat16,
+    "bfloat16": jnp.bfloat16,
+    "int8": jnp.int8,
+}
+_PLAN_COMM_NAMES = {"float32": "f32", "bfloat16": "bf16"}
+
+
+def _profile_facts(profile_shapes, layers):
+    """``profile_shapes`` as the planner's ``ModelFacts``: already one, a
+    plain ``{layer: (g_side, a_side)}`` dict, or a live params pytree whose
+    sides are derived the way ``init`` will, honoring the layer list."""
+    if profile_shapes is None or isinstance(profile_shapes, planner.ModelFacts):
+        return profile_shapes
+    d = dict(profile_shapes)
+    if d and all(
+        isinstance(v, (tuple, list)) and len(v) == 2
+        and all(isinstance(s, (int, np.integer)) for s in v)
+        for v in d.values()
+    ):
+        return planner.ModelFacts(
+            shapes={k: (int(g), int(a)) for k, (g, a) in d.items()}
+        )
+    return planner.model_facts(profile_shapes, layers=layers)
+
+
 class KFAC:
     """Distributed K-FAC gradient preconditioner.
 
@@ -164,7 +192,6 @@ class KFAC:
         track_diagnostics: bool = False,
         eigh_chunks: int = 1,
         factor_kernel: str = "auto",
-        apply_kernel: str = "auto",
         factor_comm_dtype: Any = "f32",
         factor_comm_freq: int = 1,
         solver: str = "eigh",
@@ -306,140 +333,156 @@ class KFAC:
                 "block-diagonal approximation"
             )
         self.precond_method = precond_method
-        # planner/ entry point: profile=None is the bitwise-inert default —
-        # the planner package is not even imported, and every lever below
-        # keeps exactly the value (explicit or default) the caller passed.
-        # A profile name ("production"/"memory"/"safe") or a planner.Plan
-        # resolves/validates against this constructor's environment and
-        # fills in ONLY the lever arguments the caller left at their
-        # defaults — an explicit lever always wins over the plan, so a
-        # profile is a starting point, not a straitjacket (docs/PLANNER.md).
+        # With expert banks ('#b' layers) or shared inputs the inverses live
+        # in one table per side, refreshed where they lie
+        # (ops/precondition.py, "Inverse tables"); every other model keeps
+        # the per-layer / stacked layout.
+        self.inverse_tables = bool(
+            self.shared_a
+            or any(
+                capture.split_bank_name(n)[1] is not None
+                for n in self.layers or []
+            )
+        )
+        # Decoupled curvature service (kfac_pytorch_tpu/service/):
+        # service_devices=N declares that N dedicated curvature workers were
+        # carved OUT of the device set (split_service_mesh) and run the
+        # eigen refresh out-of-band — this KFAC's mesh is the TRAINING
+        # submesh and never sees them. In-step consequences: update()
+        # structurally refuses every refresh flag (update_eigen /
+        # eigen_chunk / swap_eigen), which is what pins the training-step
+        # HLO to zero eigendecompositions; refreshed bases arrive via
+        # service.ServiceClient.install between steps.
+        _validate(
+            "service_devices",
+            isinstance(service_devices, int) and service_devices >= 0,
+            service_devices,
+        )
+        # What the lever rules (planner/profiles.py::RULES) are judged
+        # against besides the levers, read off this constructor's own
+        # arguments. Other axes of size 1 split nothing and are left out;
+        # owner shards split over the batch axes only (tensor* replicas hold
+        # identical rows, parallel/mesh.py).
+        facts = (
+            _profile_facts(profile_shapes, self.layers)
+            if profile is not None
+            else None
+        )
+        env = planner.PlanEnv(
+            world=1 if mesh is None else int(mesh.devices.size),
+            data_world=_non_tensor_world(mesh, axis_name),
+            mesh_axes=()
+            if mesh is None
+            else tuple(
+                str(a)
+                for a in mesh.axis_names
+                if a == axis_name or int(mesh.shape[a]) > 1
+            ),
+            precond_method=precond_method,
+            diag_blocks=diag_blocks,
+            distribute_precondition=distribute_precondition,
+            track_diagnostics=track_diagnostics,
+            has_diag_a_layers=facts.has_diag_a if facts is not None else False,
+            has_conv_layers=facts.has_conv if facts is not None else True,
+            has_shard_lens_layers=self.has_shard_lens,
+            has_moe_layers=self.has_moe,
+            has_inverse_tables=self.inverse_tables,
+            fac_update_freq=fac_update_freq,
+            kfac_update_freq=kfac_update_freq,
+            # the curvature-service carve the operator has OFFERED (the
+            # devices already removed from this mesh by split_service_mesh);
+            # the cost model decides engagement
+            service_devices=service_devices,
+        )
+        levers = dict(
+            eigh_chunks=eigh_chunks,
+            factor_kernel=factor_kernel,
+            factor_comm_dtype=factor_comm_dtype,
+            factor_comm_freq=factor_comm_freq,
+            solver=solver,
+            solver_rank=solver_rank,
+            solver_auto_threshold=solver_auto_threshold,
+            factor_sharding=factor_sharding,
+            comm_overlap=comm_overlap,
+            staleness_budget=staleness_budget,
+            stream_drift_threshold=stream_drift_threshold,
+            service_devices=service_devices,
+        )
+        # profile=None is the bitwise-inert default: every lever keeps
+        # exactly the value (explicit or default) the caller passed. A
+        # profile name ("production"/"memory"/"safe") or a planner.Plan
+        # resolves against the environment above and fills in ONLY the lever
+        # arguments the caller left at their defaults — an explicit lever
+        # always wins over the plan, so a profile is a starting point, not a
+        # straitjacket (docs/PLANNER.md).
         self.plan = None
         self.plan_dropped: Tuple[str, ...] = ()
         self.plan_report = None
         self.plan_env = None
         if profile is not None:
-            from kfac_pytorch_tpu import planner as _planner
-
-            facts = profile_shapes
-            if facts is not None and not isinstance(facts, _planner.ModelFacts):
-                d = dict(facts)
-                if d and all(
-                    isinstance(v, (tuple, list)) and len(v) == 2
-                    and all(isinstance(s, (int, np.integer)) for s in v)
-                    for v in d.values()
-                ):
-                    # plain {layer: (g_side, a_side)} shape dict
-                    facts = _planner.ModelFacts(
-                        shapes={k: (int(g), int(a)) for k, (g, a) in d.items()}
-                    )
-                else:
-                    # live params pytree — derive sides the same way init
-                    # will, honoring the captured layer list
-                    facts = _planner.model_facts(
-                        profile_shapes, layers=self.layers
-                    )
-            env = _planner.PlanEnv(
-                world=1 if mesh is None else int(mesh.devices.size),
-                # owner shards split over the data axes only; tensor*
-                # replicas hold identical rows (parallel/mesh.py)
-                data_world=1
-                if mesh is None
-                else int(
-                    np.prod(
-                        [
-                            int(mesh.shape[a])
-                            for a in mesh.axis_names
-                            if not str(a).startswith("tensor")
-                        ]
-                    )
-                ),
-                has_shard_lens_layers=self.has_shard_lens,
-                has_moe_layers=self.has_moe,
-                mesh_axes=()
-                if mesh is None
-                else tuple(str(a) for a in mesh.axis_names),
-                precond_method=precond_method,
-                diag_blocks=diag_blocks,
-                distribute_precondition=distribute_precondition,
-                track_diagnostics=track_diagnostics,
-                has_diag_a_layers=(
-                    facts.has_diag_a if facts is not None else False
-                ),
-                has_conv_layers=(
-                    facts.has_conv if facts is not None else True
-                ),
-                fac_update_freq=fac_update_freq,
-                kfac_update_freq=kfac_update_freq,
-                # the curvature-service carve the operator has OFFERED (the
-                # devices already removed from this mesh by
-                # split_service_mesh); the cost model decides engagement
-                service_devices=int(service_devices),
-            )
-            if isinstance(profile, _planner.Plan):
-                # An explicit plan must be valid as given (refusals raise
-                # here with the matrix's reasons); the degrade rules then
-                # normalize it — e.g. owner sharding on a 1-device dev run
-                # resolves to replicated, same as the constructor warning
-                # path would.
-                _planner.check_plan(profile, env)
-                plan, dropped = _planner.fit_plan(profile, env)
+            if isinstance(profile, planner.Plan):
+                # An explicit plan must be valid as given; the degrade rules
+                # then normalize it — e.g. owner sharding on a 1-device dev
+                # run resolves to replicated, as the warning path below
+                # would.
+                planner.check_plan(profile, env)
+                plan, dropped = planner.fit_plan(profile, env)
                 report = None
             else:
-                plan, report, dropped = _planner.resolve_profile(
+                plan, report, dropped = planner.resolve_profile(
                     profile, facts, env
                 )
-            plan_defaults = _planner.Plan()
-            levers = {
-                "eigh_chunks": eigh_chunks,
-                "factor_kernel": factor_kernel,
-                "apply_kernel": apply_kernel,
-                "factor_comm_dtype": factor_comm_dtype,
-                "factor_comm_freq": factor_comm_freq,
-                "solver": solver,
-                "solver_rank": solver_rank,
-                "solver_auto_threshold": solver_auto_threshold,
-                "factor_sharding": factor_sharding,
-                "comm_overlap": comm_overlap,
-                "staleness_budget": staleness_budget,
-                "stream_drift_threshold": stream_drift_threshold,
-                "service_devices": service_devices,
-            }
+            plan_defaults = planner.Plan()
             for field, value in plan.kfac_kwargs().items():
                 if levers[field] == getattr(plan_defaults, field):
                     levers[field] = value
-            eigh_chunks = levers["eigh_chunks"]
-            factor_kernel = levers["factor_kernel"]
-            apply_kernel = levers["apply_kernel"]
-            factor_comm_dtype = levers["factor_comm_dtype"]
-            factor_comm_freq = levers["factor_comm_freq"]
-            solver = levers["solver"]
-            solver_rank = levers["solver_rank"]
-            solver_auto_threshold = levers["solver_auto_threshold"]
-            factor_sharding = levers["factor_sharding"]
-            comm_overlap = levers["comm_overlap"]
-            staleness_budget = levers["staleness_budget"]
-            stream_drift_threshold = levers["stream_drift_threshold"]
-            service_devices = levers["service_devices"]
             self.plan = plan
             self.plan_dropped = tuple(dropped)
             self.plan_report = report
             self.plan_env = env
-            _planner.log_plan(plan, dropped)
+            planner.log_plan(plan, dropped)
+        # Range checks of single lever values, then ONE check of how they
+        # compose: every refusal that names two options is a row of
+        # planner.RULES and raises from check_plan with the row's name.
+        positive_int = lambda v: isinstance(v, int) and 0 < v  # noqa: E731
+        for name, ok in (
+            ("eigh_chunks", lambda v: 0 < v),
+            ("solver", lambda v: v in ("eigh", "rsvd", "streaming")),
+            ("solver_rank", positive_int),
+            ("solver_auto_threshold", positive_int),
+            ("factor_comm_freq", positive_int),
+            (
+                "stream_drift_threshold",
+                lambda v: isinstance(v, (int, float)) and 0.0 <= float(v),
+            ),
+            ("factor_sharding", lambda v: v in ("replicated", "owner")),
+            ("factor_kernel", lambda v: v in factor_kernel_ops.FACTOR_KERNELS),
+            ("comm_overlap", lambda v: isinstance(v, bool)),
+            ("staleness_budget", lambda v: isinstance(v, int) and v >= 0),
+        ):
+            _validate(name, ok(levers[name]), levers[name])
+        comm_dtype = levers["factor_comm_dtype"]
+        if isinstance(comm_dtype, str):
+            _validate(
+                "factor_comm_dtype",
+                comm_dtype.lower() in _FACTOR_COMM_DTYPES,
+                comm_dtype,
+            )
+            comm_dtype = _FACTOR_COMM_DTYPES[comm_dtype.lower()]
+        comm_dtype = jnp.dtype(comm_dtype)
+        # the levers as the caller ASKED for them: the rules fire on this,
+        # even where a 1-device mesh degrades a lever below
+        levers["factor_comm_dtype"] = _PLAN_COMM_NAMES.get(
+            comm_dtype.name, comm_dtype.name
+        )
+        asked = planner.Plan(**levers)
+        planner.check_plan(asked, env, enforced_by="constructor")
         # Pipelined curvature refresh: split the eigen refresh into this many
         # static chunks spread over the steps after each kfac_update_freq
         # boundary, double-buffered in state["eigen_pending"] and swapped in
         # atomically once every chunk lands (scheduler.EigenRefreshCadence
         # drives the cadence). 1 = today's monolithic refresh, bit-exact.
-        _validate("eigh chunk count", 0 < eigh_chunks, eigh_chunks)
-        if eigh_chunks > 1 and precond_method == "inverse":
-            raise ValueError(
-                "eigh_chunks > 1 pipelines the eigendecomposition refresh; "
-                "precond_method='inverse' refreshes via one batched Cholesky "
-                "~30x cheaper than the eigh it replaces — there is no spike "
-                "to spread, so refusing a config that implies one"
-            )
-        self.eigh_chunks = int(eigh_chunks)
+        self.eigh_chunks = int(asked.eigh_chunks)
         # Curvature solver for the refresh: "eigh" (full QDWH/syevd
         # eigendecomposition, reference parity, bitwise-inert default),
         # "rsvd" (randomized truncated eigensolve, ops/rsvd.py): factors with
@@ -454,63 +497,16 @@ class KFAC:
         # residual-mass drift gauge crosses stream_drift_threshold).
         # Factors below the threshold — or with solver_rank ≥ n, where
         # truncation buys nothing — stay on the dense path unchanged.
-        _validate("solver", solver in ("eigh", "rsvd", "streaming"), solver)
-        _validate(
-            "solver_rank",
-            isinstance(solver_rank, int) and 0 < solver_rank,
-            solver_rank,
-        )
-        _validate(
-            "solver_auto_threshold",
-            isinstance(solver_auto_threshold, int) and 0 < solver_auto_threshold,
-            solver_auto_threshold,
-        )
-        if solver != "eigh" and precond_method == "inverse":
-            raise ValueError(
-                f"solver={solver!r} produces a truncated eigenbasis consumed "
-                "by the eigenbasis (Woodbury) apply path; precond_method="
-                "'inverse' preconditions with explicit Cholesky inverses and "
-                "would silently ignore the configured solver"
-            )
-        if solver != "eigh" and diag_blocks != 1:
-            raise ValueError(
-                f"solver={solver!r} stores one (Q_r, d_r, rho) triple per "
-                "whole factor; diag_blocks > 1 carves factors into diagonal "
-                "blocks whose truncated bases cannot share that layout — "
-                "pick one approximation"
-            )
-        if solver == "streaming" and eigh_chunks > 1:
-            raise ValueError(
-                "solver='streaming' replaces the periodic refresh with a "
-                "per-step fold — there is no recurring eigh spike left for "
-                "eigh_chunks > 1 to spread, and the chunk plan's double "
-                "buffer would shadow the streamed tables (planner rule "
-                "streaming_vs_chunks)"
-            )
-        if solver == "streaming" and staleness_budget > 0:
-            raise ValueError(
-                "solver='streaming' has no pending eigen swap to slip — "
-                "re-orthonormalizations land in place on drift boundaries — "
-                "so a staleness_budget would silently mean nothing on the "
-                "eigen side (planner rule streaming_vs_swap_slip); leave "
-                "staleness_budget=0"
-            )
-        _validate(
-            "stream_drift_threshold",
-            isinstance(stream_drift_threshold, (int, float))
-            and 0.0 <= float(stream_drift_threshold),
-            stream_drift_threshold,
-        )
-        self.solver = solver
-        self.stream_drift_threshold = float(stream_drift_threshold)
+        self.solver = asked.solver
+        self.stream_drift_threshold = float(asked.stream_drift_threshold)
         # Host-side drift source for the streaming re-orth decision: a
         # zero-arg callable returning the latest device residual-mass gauge
         # (trainers wire it to state["stream_residual"]). None → the cadence
         # re-orthonormalizes at every kfac_update_freq boundary, the safe
         # (and deterministic) degenerate schedule.
         self.stream_drift_signal = None
-        self.solver_rank = int(solver_rank)
-        self.solver_auto_threshold = int(solver_auto_threshold)
+        self.solver_rank = int(asked.solver_rank)
+        self.solver_auto_threshold = int(asked.solver_auto_threshold)
         # Where the factor running averages / eigenbases LIVE on the mesh:
         # "replicated" (default, bitwise-inert — every device holds every
         # layer's curvature state, reference parity) or "owner" (DP-KFAC,
@@ -520,69 +516,17 @@ class KFAC:
         # moves just the preconditioned gradients — per-replica state and
         # factor wire both become O(model/devices)). The shard layout is
         # parallel.assignment.plan_factor_shards.
-        _validate(
-            "factor_sharding",
-            factor_sharding in ("replicated", "owner"),
-            factor_sharding,
-        )
-        # pre-degrade value: the shard-lens validity refusals below fire on
-        # what the caller ASKED for, even where a 1-device mesh would have
-        # degraded owner mode to replicated anyway
-        self.requested_factor_sharding = factor_sharding
+        factor_sharding = asked.factor_sharding
         if factor_sharding == "owner":
-            if precond_method != "eigen":
+            if env.multi_device and axis_name not in mesh.axis_names:
+                # no row of RULES: the rules know the mesh's axes, not which
+                # of them this KFAC was told carries the batch
                 raise ValueError(
-                    "factor_sharding='owner' shards the eigenbasis state; "
-                    "precond_method='inverse' keeps explicit Cholesky "
-                    "inverses that this mode does not lay out — use the "
-                    "eigen method or replicated sharding"
+                    "factor_sharding='owner' requires a data-plane mesh: its "
+                    f"shard stacks ride axis {axis_name!r}, and the mesh has "
+                    f"axes {tuple(mesh.axis_names)}"
                 )
-            if diag_blocks != 1:
-                raise ValueError(
-                    "factor_sharding='owner' stores one whole-factor slot "
-                    "per (layer, side); diag_blocks > 1 carves factors into "
-                    "blocks with their own owner table — pick one "
-                    "distribution scheme"
-                )
-            if distribute_precondition:
-                raise ValueError(
-                    "factor_sharding='owner' already preconditions each "
-                    "layer on its owner (that is where its eigenbasis "
-                    "lives); distribute_precondition=True would layer a "
-                    "second, different owner table on top — drop it"
-                )
-            if track_diagnostics:
-                raise ValueError(
-                    "factor_sharding='owner' keeps no replicated per-layer "
-                    "spectra for the diagnostics pytree to read — run "
-                    "track_diagnostics with replicated sharding"
-                )
-            if mesh is not None and mesh.devices.size > 1:
-                # The shard stacks ride the factor plane only; extra axes
-                # are fine iff they are replicated-compute tensor axes or
-                # batch-carrying fsdp axes (the data_fsdp_tensor_mesh
-                # convention — fsdp replicas see whole examples and JOIN the
-                # factor plane, so owner shards size to data×fsdp) —
-                # anything else would split examples or factor rows in ways
-                # the plan cannot see.
-                bad = [
-                    a
-                    for a in mesh.axis_names
-                    if a != axis_name
-                    and int(mesh.shape[a]) > 1
-                    and not (
-                        str(a).startswith("tensor")
-                        or str(a).startswith("fsdp")
-                    )
-                ]
-                if axis_name not in mesh.axis_names or bad:
-                    raise ValueError(
-                        "factor_sharding='owner' requires a data-plane mesh "
-                        f"(axis {axis_name!r} plus optional 'tensor*'/"
-                        f"'fsdp*' axes); got axes {tuple(mesh.axis_names)}"
-                    )
-            _data_size = _non_tensor_world(mesh, axis_name)
-            if mesh is None or _data_size <= 1:
+            if env.data_world <= 1:
                 # Mirrors the distribute_precondition warning: trainers pass
                 # the same flags to 1-device dev runs. There is nothing to
                 # shard across, so degrade to the (identical-numerics)
@@ -594,62 +538,7 @@ class KFAC:
                 factor_sharding = "replicated"
         self.factor_sharding = factor_sharding
         self._shard_plans: Dict[Any, Any] = {}
-        # Decoupled curvature service (kfac_pytorch_tpu/service/):
-        # service_devices=N declares that N dedicated curvature workers were
-        # carved OUT of the device set (split_service_mesh) and run the
-        # eigen refresh out-of-band — this KFAC's mesh is the TRAINING
-        # submesh and never sees them. In-step consequences: update()
-        # structurally refuses every refresh flag (update_eigen /
-        # eigen_chunk / swap_eigen), which is what pins the training-step
-        # HLO to zero eigendecompositions; refreshed bases arrive via
-        # service.ServiceClient.install between steps. The exclusions below
-        # mirror the planner validity rules of the same names.
-        _validate(
-            "service_devices",
-            isinstance(service_devices, int) and service_devices >= 0,
-            service_devices,
-        )
-        if service_devices > 0:
-            if precond_method == "inverse":
-                raise ValueError(
-                    "service_devices > 0 publishes factor snapshots to "
-                    "workers that refresh an EIGENBASIS; precond_method="
-                    "'inverse' refreshes ~30x-cheaper Cholesky inverses "
-                    "in-step — there is no refresh spike worth a carve "
-                    "(planner rule service_vs_inverse)"
-                )
-            if solver == "streaming":
-                raise ValueError(
-                    "service_devices > 0 moves the periodic refresh to "
-                    "dedicated workers; solver='streaming' already replaced "
-                    "it with a per-step in-graph fold that cannot leave the "
-                    "training program — pick one refresh-elimination scheme "
-                    "(planner rule service_vs_streaming)"
-                )
-            if eigh_chunks > 1:
-                raise ValueError(
-                    "service_devices > 0 removes the refresh from the "
-                    "training step entirely; eigh_chunks > 1 spreads an "
-                    "in-step refresh spike that no longer exists — leave "
-                    "eigh_chunks=1 (planner rule service_vs_chunks)"
-                )
-            if diag_blocks != 1:
-                raise ValueError(
-                    "service_devices > 0 runs the worker refresh on whole "
-                    "factors; diag_blocks > 1 needs the trainer-side conv "
-                    "layout the published snapshot does not carry — leave "
-                    "diag_blocks=1 (planner rule service_vs_diag_blocks)"
-                )
-            if factor_sharding == "owner":
-                raise ValueError(
-                    "service_devices > 0 publishes full replicated factor "
-                    "snapshots and installs full replicated bases; "
-                    "factor_sharding='owner' keeps per-owner shards that "
-                    "would have to gather through the mailbox every "
-                    "boundary — run the service with replicated sharding "
-                    "(planner rule service_vs_owner_sharding)"
-                )
-        self.service_devices = int(service_devices)
+        self.service_devices = int(asked.service_devices)
         # Stability telemetry (costs two scalars of state + O(layers) mins):
         # ν — the KL trust-region coefficient actually applied each step
         # (kfac_preconditioner.py:320-326) — and the minimum damped
@@ -668,88 +557,9 @@ class KFAC:
         # ResNet-50 shapes (docs/PERF.md, "Refused by the v5e compiler"), so
         # it is an explicit opt-in that compiles or raises. Train steps open
         # a factor_kernel_scope with this value around their capture forward.
-        _validate(
-            "factor_kernel",
-            factor_kernel in factor_kernel_ops.FACTOR_KERNELS,
-            factor_kernel,
+        self.factor_kernel = factor_kernel_ops.resolve_factor_kernel(
+            asked.factor_kernel
         )
-        self.factor_kernel = factor_kernel_ops.resolve_factor_kernel(factor_kernel)
-        # Per-layer apply kernel: "dense" is the verbatim einsum-chain oracle
-        # (ops/precondition.py::precondition_all + the separate optax step),
-        # "pallas" the fused VMEM-resident rotate→divide→back-rotate kernel
-        # that also emits the KL-clip partials and fuses the SGD update
-        # (ops/apply_kernels.py). "auto" resolves like factor_kernel: dense
-        # on every backend. Train steps open an apply_kernel_scope
-        # with this value around KFAC.update + the optimizer step; anything
-        # traced outside a scope (eval_shape, state templates) pins dense.
-        _validate(
-            "apply_kernel",
-            apply_kernel in apply_kernel_ops.APPLY_KERNELS,
-            apply_kernel,
-        )
-        apply_kernel = apply_kernel_ops.resolve_apply_kernel(apply_kernel)
-        if apply_kernel == "pallas" and precond_method == "inverse":
-            # Degrade, not refuse (planner rule apply_pallas_vs_inverse):
-            # the inverse path's 2-matmul chain has no eigenbasis stage for
-            # the fused kernel to cover.
-            print(
-                "WARNING: apply_kernel='pallas' fuses the eigenbasis apply; "
-                "precond_method='inverse' preconditions with explicit "
-                "Cholesky inverses — falling back to the dense apply path"
-            )
-            apply_kernel = "dense"
-        self.apply_kernel = apply_kernel
-        # Factor-communication plane (parallel/comm.py): bucketed fusion of
-        # the per-layer A/G stat exchange, optional bf16 wire compression,
-        # optional deferred reduction every `factor_comm_freq` capture steps
-        # (flushed before every eigen refresh). Defaults are the parity
-        # escape hatch: f32 + freq 1 leaves the step's numerics bitwise
-        # unchanged, and without a multi-device mesh the plane is inert.
-        if isinstance(factor_comm_dtype, str):
-            _FACTOR_COMM_DTYPES = {
-                "f32": jnp.float32,
-                "float32": jnp.float32,
-                "bf16": jnp.bfloat16,
-                "bfloat16": jnp.bfloat16,
-                "int8": jnp.int8,
-            }
-            _validate(
-                "factor_comm_dtype",
-                factor_comm_dtype.lower() in _FACTOR_COMM_DTYPES,
-                factor_comm_dtype,
-            )
-            factor_comm_dtype = _FACTOR_COMM_DTYPES[factor_comm_dtype.lower()]
-        _validate(
-            "factor_comm_freq",
-            isinstance(factor_comm_freq, int) and 0 < factor_comm_freq,
-            factor_comm_freq,
-        )
-        if jnp.dtype(factor_comm_dtype) == jnp.dtype(jnp.int8):
-            # The int8 wire is only sound WITH error feedback, and the
-            # residual accumulators live in KFAC state on the deferred path
-            # (state["wire_error"], carried across flushes). The per-step
-            # contribution exchange has no state slot — each exchange would
-            # bias the EMA with unrecoverable rounding — so refuse instead
-            # of silently running feedback-free (planner rule
-            # int8_wire_requires_deferral).
-            if factor_comm_freq <= 1:
-                raise ValueError(
-                    "factor_comm_dtype='int8' quantizes the deferred factor "
-                    "flush with error-feedback accumulators carried in "
-                    "state; factor_comm_freq=1 exchanges contributions every "
-                    "capture step with no residual slot to carry — set "
-                    "factor_comm_freq > 1 or widen the wire to bf16 "
-                    "(planner rule int8_wire_requires_deferral)"
-                )
-            if self.requested_factor_sharding == "owner":
-                raise ValueError(
-                    "factor_comm_dtype='int8' rides the replicated deferred "
-                    "flush (codes + block scales over all_gather); "
-                    "factor_sharding='owner' exchanges through psum_scatter, "
-                    "which would have to widen the codes on-wire — use the "
-                    "bf16 wire with owner sharding (planner rule "
-                    "int8_wire_vs_owner_sharding)"
-                )
         # Overlap plane (the scheduling lever): comm_overlap=True issues the
         # factor-statistics bucket reductions interleaved with the gradient
         # pmean in the explicit shard_map wrapper (training/step.py), in
@@ -758,8 +568,8 @@ class KFAC:
         # independent of issue position and bucket order, so the fused
         # stream is bitwise-identical to the serial one — it only changes
         # what the XLA scheduler may run concurrently.
-        _validate("comm_overlap", isinstance(comm_overlap, bool), comm_overlap)
-        if comm_overlap and (mesh is None or mesh.devices.size <= 1):
+        comm_overlap = asked.comm_overlap
+        if comm_overlap and not env.multi_device:
             # Degrade, not refuse (planner rule overlap_vs_single_device):
             # trainers pass the same flags to 1-device dev runs, and there
             # is no cross-replica stream to fuse into.
@@ -768,7 +578,7 @@ class KFAC:
                 "multi-device mesh — there is no factor exchange to overlap"
             )
             comm_overlap = False
-        self.comm_overlap = bool(comm_overlap)
+        self.comm_overlap = comm_overlap
         # Batch-carrying reduction axes of the factor plane: the data axis
         # plus any size>1 fsdp* axes (parallel/mesh.py::data_fsdp_tensor_mesh
         # — fsdp replicas see whole examples, so their statistics reduce
@@ -784,16 +594,24 @@ class KFAC:
             )
             if _fsdp_axes:
                 self.batch_axes = (axis_name,) + _fsdp_axes
+        # Factor-communication plane (parallel/comm.py): bucketed fusion of
+        # the per-layer A/G stat exchange, optional bf16 wire compression,
+        # optional deferred reduction every `factor_comm_freq` capture steps
+        # (flushed before every eigen refresh), optional int8 wire with
+        # error-feedback residuals carried in state["wire_error"] on the
+        # deferred path. Defaults are the parity escape hatch: f32 + freq 1
+        # leaves the step's numerics bitwise unchanged, and without a
+        # multi-device mesh the plane is inert.
         self.factor_comm = FactorComm(
             mesh=mesh,
             axis_name=self.batch_axes,
-            comm_dtype=factor_comm_dtype,
-            comm_freq=factor_comm_freq,
+            comm_dtype=comm_dtype,
+            comm_freq=asked.factor_comm_freq,
             sharded=self.owner_sharded,
             overlap=self.comm_overlap,
         )
         if (
-            factor_comm_freq > 1 or self.factor_comm.comm_dtype != jnp.dtype("float32")
+            asked.factor_comm_freq > 1 or comm_dtype != jnp.dtype("float32")
         ) and not self.factor_comm.multi_device:
             # Mirrors the distribute_precondition warning above: not an
             # error — trainers pass the same flags to 1-device dev runs —
@@ -808,127 +626,14 @@ class KFAC:
         # (scheduler.EigenRefreshCadence) slip a deferred factor flush or a
         # pending eigen swap by up to S steps when the measured
         # comm/compute pressure says the wire is saturated. S=0 (default)
-        # never slips — bitwise-inert. S>0 needs something that CAN slip:
-        # a deferred flush (factor_comm_freq>1) or a pipelined swap
-        # (eigh_chunks>1); refusing the slack-free combination keeps the
-        # lever from silently meaning nothing (planner rule
-        # staleness_requires_slack).
-        _validate(
-            "staleness_budget",
-            isinstance(staleness_budget, int) and staleness_budget >= 0,
-            staleness_budget,
-        )
-        if staleness_budget > 0 and not (
-            factor_comm_freq > 1 or eigh_chunks > 1 or service_devices > 0
-        ):
-            raise ValueError(
-                "staleness_budget > 0 bounds how far a deferred factor "
-                "flush, a pending eigen swap, or a service basis install "
-                "may slip, and this configuration has none of them: enable "
-                "factor_comm_freq > 1 (deferred reduction), eigh_chunks > 1 "
-                "(pipelined refresh), or service_devices > 0 (curvature "
-                "service), or leave staleness_budget=0"
-            )
-        self.staleness_budget = int(staleness_budget)
+        # never slips — bitwise-inert.
+        self.staleness_budget = int(asked.staleness_budget)
         # Host-side comm/compute pressure source for the slip decision:
         # a zero-arg callable returning the measured comm/compute ratio
-        # (bench/trainers wire one up from their timers). None → ratio 0 →
+        # (trainers wire one up from their timers). None → ratio 0 →
         # the cadence never slips, keeping replays (expected_step_variants)
         # and tests deterministic by default.
         self.staleness_signal = None
-        # Shard-lens validity (named after the planner rules of the same
-        # names, planner/profiles.py). Shardwise factor stacks always
-        # refresh DENSELY per block (the blocks are 1/T- or per-expert-
-        # sized; there is no whole-factor eigh spike left), so every lever
-        # that reshapes the refresh — inverses, chunk pipelining, streaming
-        # folds, diagonal blocking, owner re-homing, the curvature service —
-        # has nothing coherent to act on and refuses up front rather than
-        # silently skipping the shard layers.
-        banks = [
-            n for n in self.layers or []
-            if capture.split_bank_name(n)[1] is not None
-        ]
-        # With expert banks (or shared inputs) the inverses live in one table
-        # per side, refreshed where they lie (ops/precondition.py, "Inverse
-        # tables"); every other model keeps the per-layer / stacked layout.
-        self.inverse_tables = bool(banks or self.shared_a)
-        if self.inverse_tables and (
-            self.precond_method != "inverse"
-            or self.requested_factor_sharding == "owner"
-            or self.distribute_precondition
-            or self.track_diagnostics
-            or self.factor_comm.comm_freq > 1
-        ):
-            raise ValueError(
-                "expert banks ('#b' layers) and shared_a run on the "
-                "replicated inverse path alone: precond_method='inverse', "
-                "factor_sharding='replicated', no distribute_precondition, "
-                "no track_diagnostics, factor_comm_freq=1"
-            )
-        if self.has_shard_lens or self.has_moe:
-            kind = "MoE expert banks" if not self.has_shard_lens else (
-                "shard-lens layers"
-            )
-            if self.precond_method == "inverse":
-                raise ValueError(
-                    f"{kind} precondition per shard block in the eigenbasis "
-                    "(shardwise.precondition); precond_method='inverse' "
-                    "keeps whole-factor Cholesky inverses with no per-block "
-                    "layout — use the eigen method (planner rule "
-                    "shard_lens_vs_inverse)"
-                )
-            if self.requested_factor_sharding == "owner":
-                raise ValueError(
-                    f"{kind} pin each factor block to the device holding "
-                    "the matching kernel shard (shardwise.factor_leaf_spec); "
-                    "factor_sharding='owner' would re-home those blocks "
-                    "onto LPT owners and gather them back every step — "
-                    "pick one placement scheme (planner rule "
-                    + (
-                        "moe_vs_owner_sharding)"
-                        if self.has_moe and not self.has_shard_lens
-                        else "shard_lens_vs_owner_sharding)"
-                    )
-                )
-            if self.eigh_chunks > 1:
-                raise ValueError(
-                    f"{kind} refresh densely per block — there is no "
-                    "whole-factor eigh spike for eigh_chunks > 1 to spread, "
-                    "and the chunk planner's slot tables do not describe "
-                    "stacked factors (planner rule shard_lens_vs_chunks)"
-                )
-            if self.solver == "streaming":
-                raise ValueError(
-                    f"{kind} keep dense per-block bases; solver='streaming' "
-                    "folds factors through retained truncated bases that "
-                    "the stacked layout does not carry — non-shard layers "
-                    "may ride solver='rsvd' instead (planner rule "
-                    "shard_lens_vs_streaming)"
-                )
-            if self.diag_blocks != 1:
-                raise ValueError(
-                    f"{kind} already block their factors along shard/expert "
-                    "boundaries; diag_blocks > 1 would carve a second, "
-                    "conflicting block structure into the same factors "
-                    "(planner rule shard_lens_vs_diag_blocks)"
-                )
-            if self.service_devices > 0:
-                raise ValueError(
-                    f"{kind} refresh in-step (cheap dense per-block eigh); "
-                    "service_devices > 0 publishes whole-factor snapshots "
-                    "the worker protocol does not lay out as stacks — run "
-                    "the service on unsharded models (planner rule "
-                    "service_vs_shard_lens)"
-                )
-        if self.has_moe and self.factor_comm.comm_freq > 1:
-            raise ValueError(
-                "MoE expert banks use the token-count-weighted EMA "
-                "(shardwise.moe_ema), whose per-expert decay alpha**w_e is "
-                "not linear in the contributions — deferred factor "
-                "communication (factor_comm_freq > 1) merges per-replica "
-                "EMAs by linearity and would silently corrupt expert "
-                "statistics (planner rule moe_vs_deferred_comm)"
-            )
         self.hparams = KFACHParams(
             damping=damping,
             kl_clip=kl_clip,
@@ -2268,37 +1973,13 @@ class KFAC:
                 norm_gmats, eigen, *precision_args, stacked=stacked
             )
         else:
-            # vg_terms is None under a dense apply_kernel scope (the
-            # delegate is the verbatim precondition_all — bit-identical
-            # default); under a pallas scope the fused kernel emitted the
-            # per-layer KL-clip partials as by-products.
-            updates, vg_terms = precond_ops.precondition_all_with_vg(
+            updates = precond_ops.precondition_all(
                 norm_gmats, eigen, damping, *precision_args, stacked=stacked
             )
-            for n, (_, form, count) in shard_items.items():
-                updates[n] = shardwise.precondition(
-                    form, count, gmats[n], eigen[n], damping
-                )
-            if vg_terms is not None:
-                # shard-lens layers append their partials in the same
-                # (emission) order kl_clip_coefficient would visit them
-                for n in shard_items:
-                    vg_terms.append(
-                        jnp.sum(
-                            updates[n].astype(jnp.float32)
-                            * gmats[n].astype(jnp.float32)
-                        )
-                    )
-                nu = precond_ops.kl_clip_from_vg(
-                    vg_terms, lr, self.hparams.kl_clip
-                )
-                new_grads = capture.write_back(grads, updates, nu)
-                return new_grads, gmats, updates, nu
         for n, (_, form, count) in shard_items.items():
-            if n not in updates:
-                updates[n] = shardwise.precondition(
-                    form, count, gmats[n], eigen[n], damping
-                )
+            updates[n] = shardwise.precondition(
+                form, count, gmats[n], eigen[n], damping
+            )
 
         # Global KL trust-region rescale (kfac_preconditioner.py:311-334).
         nu = precond_ops.kl_clip_coefficient(

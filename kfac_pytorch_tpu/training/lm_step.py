@@ -21,11 +21,10 @@ from kfac_pytorch_tpu import capture, compat
 from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
 from kfac_pytorch_tpu.observability.phases import phase
-from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels, factors, flash_attention
+from kfac_pytorch_tpu.ops import factor_kernels, factors, flash_attention
 from kfac_pytorch_tpu.preconditioner import KFAC
 from kfac_pytorch_tpu.training.step import (
     TrainState,
-    _momentum_state_index,
     clip_by_global_norm as _clip_by_global_norm,
     softmax_cross_entropy,
 )
@@ -40,7 +39,6 @@ def make_lm_train_step(
     grad_clip: float = 0.25,
     mesh=None,
     grad_comm_dtype=None,
-    sgd_hyper: Optional[Tuple[float, float]] = None,
 ):
     """Build the jitted LM train step.
 
@@ -55,12 +53,6 @@ def make_lm_train_step(
     recurrent carry shards over the batch axis (every cell carry leaf is
     batch-leading) and stays per-device; dropout keys fold in the device
     index so masks are iid across the mesh.
-
-    ``sgd_hyper=(momentum, weight_decay)`` declares that ``tx`` is exactly
-    ``optimizers.make_sgd(momentum, weight_decay)`` so the optimizer pass can
-    fuse into the Pallas apply kernel when the preconditioner resolved
-    ``apply_kernel="pallas"`` — same contract as ``training.step``'s
-    parameter of the same name. Defaults to ``None`` (verbatim optax pass).
     """
     if grad_comm_dtype is not None and mesh is None:
         raise ValueError(
@@ -229,50 +221,27 @@ def make_lm_train_step(
 
         kfac_state = state.kfac_state
         if kfac is not None:
-            # Trace-time apply-kernel scope, same as training/step.py: the
-            # fused Pallas apply (ops/apply_kernels.py) engages only inside
-            # this block; tracing outside it pins dense.
-            with apply_kernels.apply_kernel_scope(kfac.apply_kernel):
-                grads, kfac_state = kfac.update(
-                    grads,
-                    kfac_state,
-                    a_contribs=a_c,
-                    g_factor_stats=g_s,
-                    lr=lr,
-                    damping=damping,
-                    update_factors=update_factors,
-                    update_eigen=update_eigen,
-                    diag_warmup_done=diag_warmup_done,
-                    eigen_chunk=eigen_chunk,
-                    swap_eigen=swap_eigen,
-                    flush_factors=flush_factors,
-                )
-
-        fused = None
-        if sgd_hyper is not None and kfac is not None:
-            ti = _momentum_state_index(state.opt_state)
-            with apply_kernels.apply_kernel_scope(kfac.apply_kernel):
-                fused = apply_kernels.dispatch_sgd_apply(
-                    state.params,
-                    grads,
-                    state.opt_state[ti].trace,
-                    lr,
-                    sgd_hyper[0],
-                    sgd_hyper[1],
-                )
-        if fused is not None:
-            params, new_trace = fused
-            opt_state = tuple(
-                s._replace(trace=new_trace) if i == ti else s
-                for i, s in enumerate(state.opt_state)
+            grads, kfac_state = kfac.update(
+                grads,
+                kfac_state,
+                a_contribs=a_c,
+                g_factor_stats=g_s,
+                lr=lr,
+                damping=damping,
+                update_factors=update_factors,
+                update_eigen=update_eigen,
+                diag_warmup_done=diag_warmup_done,
+                eigen_chunk=eigen_chunk,
+                swap_eigen=swap_eigen,
+                flush_factors=flush_factors,
             )
-        else:
-            with phase("optimizer"):
-                updates, opt_state = tx.update(
-                    grads, state.opt_state, state.params
-                )
-                updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
-                params = optax.apply_updates(state.params, updates)
+
+        with phase("optimizer"):
+            updates, opt_state = tx.update(
+                grads, state.opt_state, state.params
+            )
+            updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
+            params = optax.apply_updates(state.params, updates)
 
         metrics = {"loss": loss, "ppl": jnp.exp(loss)}
         if kfac is not None and kfac.track_diagnostics:

@@ -28,7 +28,7 @@ from kfac_pytorch_tpu import capture, compat
 from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS, STEP_SCALARS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
 from kfac_pytorch_tpu.observability.phases import phase
-from kfac_pytorch_tpu.ops import apply_kernels, factor_kernels, factors, flash_attention
+from kfac_pytorch_tpu.ops import factor_kernels, factors, flash_attention
 from kfac_pytorch_tpu.preconditioner import KFAC
 
 PyTree = Any
@@ -207,21 +207,6 @@ def make_sgd(momentum: float = 0.9, weight_decay: float = 0.0):
     return optax.chain(*chain)
 
 
-def _momentum_state_index(opt_state) -> int:
-    """Locate the ``optax.trace`` momentum state inside a ``make_sgd`` chain
-    (the only stateful link — ``add_decayed_weights`` carries EmptyState).
-    Raises if the transformation is not make_sgd-shaped, which is how the
-    fused-SGD path refuses optimizers it cannot reproduce."""
-    for i, s in enumerate(opt_state):
-        if hasattr(s, "trace"):
-            return i
-    raise ValueError(
-        "sgd_hyper requires a make_sgd-style optax chain (one optax.trace "
-        "momentum state); the fused apply kernel replicates exactly that "
-        "update rule"
-    )
-
-
 def per_sample_cross_entropy(
     logits: jnp.ndarray, labels: jnp.ndarray, label_smoothing: float = 0.0
 ) -> jnp.ndarray:
@@ -277,16 +262,10 @@ def make_train_step(
     :func:`_compressed_grads`. ``None`` (default) leaves the reduction to
     GSPMD at f32.
 
-    ``sgd_hyper=(momentum, weight_decay)`` declares that ``tx`` is exactly
-    ``make_sgd(momentum, weight_decay)`` — the declaration the fused apply
-    kernel needs to replace the separate optax pass: when the
-    preconditioner resolved ``KFAC(apply_kernel="pallas")``, the optimizer
-    step runs as ONE flattened Pallas stream
-    (``ops.apply_kernels.fused_sgd_apply``) updating params and the
-    momentum trace together, and ``tx.update`` never enters the program
-    (scripts/check_apply_hlo.py pins the eliminated pass). ``None``
-    (default), a dense apply kernel, or ``kfac=None`` keep the optax block
-    verbatim — bitwise-inert.
+    ``sgd_hyper`` is accepted and unused: the optimizer pass is always
+    ``tx.update``. It stays in the signature only because
+    ``benchmarks/configs/transformer_lm.py`` passes it and a PR that is not
+    a ``benchmark`` PR may not edit that file (ROADMAP.md, Queue C).
 
     ``KFAC(factor_sharding="owner")`` needs NO step-level wiring: it makes
     ``kfac.factor_comm.active`` true, which routes the step through the
@@ -562,51 +541,27 @@ def make_train_step(
 
         kfac_state = state.kfac_state
         if kfac is not None:
-            # Trace-time scope, mirroring factor_kernel_scope above: the
-            # preconditioner's apply path routes through the fused Pallas
-            # kernel (ops/apply_kernels.py) only inside this block — any
-            # eval_shape/template tracing outside it pins dense.
-            with apply_kernels.apply_kernel_scope(kfac.apply_kernel):
-                grads, kfac_state = kfac.update(
-                    grads,
-                    kfac_state,
-                    a_contribs=a_c,
-                    g_factor_stats=g_s,
-                    lr=lr,
-                    damping=damping,
-                    update_factors=update_factors,
-                    update_eigen=update_eigen,
-                    diag_warmup_done=diag_warmup_done,
-                    eigen_chunk=eigen_chunk,
-                    swap_eigen=swap_eigen,
-                    flush_factors=flush_factors,
-                )
-
-        fused = None
-        if sgd_hyper is not None and kfac is not None:
-            ti = _momentum_state_index(state.opt_state)
-            with apply_kernels.apply_kernel_scope(kfac.apply_kernel):
-                fused = apply_kernels.dispatch_sgd_apply(
-                    state.params,
-                    grads,
-                    state.opt_state[ti].trace,
-                    lr,
-                    sgd_hyper[0],
-                    sgd_hyper[1],
-                )
-        if fused is not None:
-            params, new_trace = fused
-            opt_state = tuple(
-                s._replace(trace=new_trace) if i == ti else s
-                for i, s in enumerate(state.opt_state)
+            grads, kfac_state = kfac.update(
+                grads,
+                kfac_state,
+                a_contribs=a_c,
+                g_factor_stats=g_s,
+                lr=lr,
+                damping=damping,
+                update_factors=update_factors,
+                update_eigen=update_eigen,
+                diag_warmup_done=diag_warmup_done,
+                eigen_chunk=eigen_chunk,
+                swap_eigen=swap_eigen,
+                flush_factors=flush_factors,
             )
-        else:
-            with phase("optimizer"):
-                updates, opt_state = tx.update(
-                    grads, state.opt_state, state.params
-                )
-                updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
-                params = optax.apply_updates(state.params, updates)
+
+        with phase("optimizer"):
+            updates, opt_state = tx.update(
+                grads, state.opt_state, state.params
+            )
+            updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
+            params = optax.apply_updates(state.params, updates)
 
         metrics = {"loss": loss, "accuracy": acc}
         metrics.update(scalars)

@@ -31,8 +31,8 @@ all-gather — "allgather count unchanged"), and (b) every factor collective's
 mesh (8).
 
 Fourth section: compile-only memory regression for the embedding capture.
-The token-gather kernel's compiled temp bytes (XLA ``memory_analysis``, via
-bench.py's ``_compiled_memory``) must stay under a tenth of the dense
+The token-gather kernel's compiled temp bytes (XLA ``memory_analysis``)
+must stay under a tenth of the dense
 one-hot oracle's — the [B·T, V] one-hot and dense [V, V] A factor must
 never materialize.
 
@@ -428,9 +428,16 @@ def _check_3d_mesh() -> int:
 def _check_embed_memory() -> int:
     """Compile-only memory pin: the token-gather embedding capture must not
     materialize the one-hot program — temp bytes < dense oracle / 10."""
-    from bench import _compiled_memory
-
     from kfac_pytorch_tpu.ops import factor_kernels, factors
+
+    def _compiled_memory(lowered):
+        # memory_analysis() is best-effort per backend: a failure is an
+        # error note, read below as a skip
+        try:
+            stats = lowered.compile().memory_analysis()
+            return {"temp_bytes": int(stats.temp_size_in_bytes)}
+        except Exception as e:  # noqa: BLE001 — backend-dependent reporting
+            return {"error": f"{type(e).__name__}: {e}"[:200]}
 
     vocab, toks = 4096, (16, 512)  # one-hot temp: 16·512·4096·4 B = 128 MiB
     ids = jnp.zeros(toks, jnp.int32)

@@ -55,9 +55,8 @@ _CIFAR_RESNET32 = {
     "KFACDense_0": (10, 65),
 }
 
-# --- fixture 2: ImageNet ResNet-50 on a v5e-32 (bench.py's headline
-# model, shapes = planner.model_facts over resnet50 init). Big sides
-# (4608, 2304, 2049...) → rsvd and the full lever stack should engage;
+# --- fixture 2: ImageNet ResNet-50 on a v5e-32 (shapes =
+# planner.model_facts over resnet50 init). Big sides (4608, 2304, 2049...) → rsvd and the full lever stack should engage;
 # the acceptance criterion (≥3 non-default levers) is pinned here.
 _RESNET50 = {
     "Bottleneck_0/KFACConv_0": (64, 64), "Bottleneck_0/KFACConv_1": (64, 576),

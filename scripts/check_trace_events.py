@@ -2,9 +2,8 @@
 """Lint: emitted flight-recorder event kinds ↔ docs registry, both ways.
 
 Every event kind passed to ``event(`` (the flight recorder,
-``observability/trace.py``) anywhere in ``kfac_pytorch_tpu/``,
-``examples/``, or ``bench.py`` must be a string LITERAL (policy — keeps
-this lint sound) and must appear in the registry table between the
+``observability/trace.py``) anywhere in ``kfac_pytorch_tpu/`` or
+``examples/`` must be a string LITERAL (policy — keeps this lint sound) and must appear in the registry table between the
 ``trace-event-registry:start``/``end`` markers of docs/OBSERVABILITY.md;
 conversely every registry row must be emitted somewhere. ``scripts/`` and
 ``tests/`` are deliberately out of scan scope: merge_timeline.py and the
@@ -22,7 +21,7 @@ import sys
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 DOC = ROOT / "docs" / "OBSERVABILITY.md"
-SCAN = ["kfac_pytorch_tpu", "examples", "bench.py"]
+SCAN = ["kfac_pytorch_tpu", "examples"]
 
 # Lowercase `event(` only — matches `tr.event("kind", ...)` /
 # `get_trace().event("kind", ...)`, not `threading.Event(`.

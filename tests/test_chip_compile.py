@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from kfac_pytorch_tpu.ops import apply_kernels, eigh, factors
+from kfac_pytorch_tpu.ops import eigh, factors
 from kfac_pytorch_tpu.ops import precondition as precond_ops
 from kfac_pytorch_tpu.ops.flash_attention import flash_attention
 
@@ -132,36 +132,6 @@ def test_eigh_smallest_bucket_compiles(one_chip):
     """ops/eigh.py::batched_eigh at the 128 bucket. Larger buckets take
     minutes each to compile (docs/PERF.md): scripts/compile_for_chip.py."""
     _compile(eigh.batched_eigh, one_chip, _f32(1, 128, 128))
-
-
-def test_fused_sgd_apply_compiles(one_chip):
-    """ops/apply_kernels.py::fused_sgd_apply — the one Pallas kernel of the
-    apply path the compiler accepts at ResNet-50's largest leaves; an
-    explicit opt-in (interpret=False: compile through Mosaic)."""
-    tree = {"conv": _f32(3, 3, 512, 512), "fc": _f32(2048, 1000), "bias": _f32(1000)}
-    hlo = _compile(
-        lambda p, g, m, lr: apply_kernels.fused_sgd_apply(
-            p, g, m, lr, 0.9, 5e-5, interpret=False
-        ),
-        one_chip, tree, tree, tree, _f32(),
-    )
-    assert "tpu_custom_call" in hlo
-
-
-def test_explicit_pallas_apply_raises_the_compilers_error(one_chip):
-    """An explicit Pallas request compiles or raises the compiler's own
-    error; nothing catches it and nothing gives way to dense. k > 1 stacks
-    are refused today (block (1, a) of the [k, a] eigenvalue array)."""
-    k, g, a = 3, 64, 576
-    with pytest.raises(Exception) as err:
-        _compile(
-            lambda gm, qa, da, qg, dg, d: apply_kernels.fused_precondition_stack(
-                gm, qa, da, qg, dg, d, interpret=False
-            ),
-            one_chip, _f32(k, g, a), _f32(k, a, a), _f32(k, a),
-            _f32(k, g, g), _f32(k, g), _f32(),
-        )
-    assert "block shape" in str(err.value).lower()
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -87,8 +87,7 @@ def program(cfg, remat=True, fac_freq=1, kfac_freq=10, **kfac_kwargs):
     copy = jax.tree_util.tree_map(jnp.array, p0)  # the step donates its state
     state = TrainState(step=jnp.zeros((), jnp.int32), params=copy, batch_stats={},
                        opt_state=tx.init(copy), kfac_state=kfac.init(copy))
-    step = make_train_step(model, tx, kfac, train_kwargs={"train": True}, grad_clip=cfg["grad_clip"],
-                           sgd_hyper=(cfg["momentum"], cfg["weight_decay"]))
+    step = make_train_step(model, tx, kfac, train_kwargs={"train": True}, grad_clip=cfg["grad_clip"])
     return model, kfac, step, state, p0
 
 

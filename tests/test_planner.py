@@ -203,8 +203,6 @@ _LEVERS = {
         factor_comm_dtype="int8", factor_comm_freq=2,
         factor_sharding="owner",
     ),
-    # fused apply: degrades (never refuses) under precond_method='inverse'
-    "apply_pallas": Plan(apply_kernel="pallas"),
 }
 
 # environment features, each mapping to (PlanEnv kwargs, KFAC kwargs)
@@ -242,6 +240,12 @@ _ENVS = {
     "shard_lens_diag_blocks": (
         dict(has_shard_lens_layers=True, diag_blocks=2),
         dict(layers=["block_0/ff1#c2"], diag_blocks=2),
+    ),
+    # expert banks ("#b" names) hold their inverses in tables, a layout of
+    # the replicated inverse path alone (inverse_tables_vs_other_paths)
+    "banks": (
+        dict(has_inverse_tables=True, precond_method="inverse"),
+        dict(layers=["block_0/experts#b4"], precond_method="inverse"),
     ),
 }
 
@@ -304,6 +308,69 @@ def test_matrix_grid_exercises_every_refusal_rule():
             tripped |= {r.name for r in violations(plan, env)}
     expected = {r.name for r in REFUSAL_RULES}
     assert expected <= tripped, expected - tripped
+
+
+# One smallest KFAC(...) call per constructor-enforced row, tripping that
+# row alone: the constructor holds no refusal text of its own, so WHICH rule
+# an error names is the row's. "mesh" is "dp" (8 x data), "seq" (4 x data,
+# 2 x seq) or absent (no mesh).
+_LENS, _MOE, _BANK = ["blk/ff1#c2"], ["blk/moe#e4"], ["blk/experts#b4"]
+_ROW_CASES = {
+    "chunks_vs_inverse": dict(eigh_chunks=2, precond_method="inverse"),
+    "rsvd_vs_inverse": dict(solver="rsvd", precond_method="inverse"),
+    "rsvd_vs_diag_blocks": dict(solver="rsvd", diag_blocks=2),
+    "owner_vs_inverse": dict(
+        factor_sharding="owner", precond_method="inverse", mesh="dp"),
+    "owner_vs_diag_blocks": dict(
+        factor_sharding="owner", diag_blocks=2, mesh="dp"),
+    "owner_vs_distribute_precondition": dict(
+        factor_sharding="owner", distribute_precondition=True, mesh="dp"),
+    "owner_vs_diagnostics": dict(
+        factor_sharding="owner", track_diagnostics=True, mesh="dp"),
+    "owner_vs_multi_axis_mesh": dict(factor_sharding="owner", mesh="seq"),
+    "streaming_vs_chunks": dict(solver="streaming", eigh_chunks=2),
+    "streaming_vs_swap_slip": dict(
+        solver="streaming", staleness_budget=1, factor_comm_freq=2),
+    "service_vs_inverse": dict(service_devices=1, precond_method="inverse"),
+    "service_vs_streaming": dict(service_devices=1, solver="streaming"),
+    "service_vs_chunks": dict(service_devices=1, eigh_chunks=2),
+    "service_vs_diag_blocks": dict(service_devices=1, diag_blocks=2),
+    "service_vs_owner_sharding": dict(
+        service_devices=1, factor_sharding="owner", mesh="dp"),
+    "shard_lens_vs_inverse": dict(layers=_LENS, precond_method="inverse"),
+    "shard_lens_vs_diag_blocks": dict(layers=_LENS, diag_blocks=2),
+    "shard_lens_vs_owner_sharding": dict(
+        layers=_LENS, factor_sharding="owner", mesh="dp"),
+    "moe_vs_owner_sharding": dict(
+        layers=_MOE, factor_sharding="owner", mesh="dp"),
+    "shard_lens_vs_chunks": dict(layers=_LENS, eigh_chunks=2),
+    "shard_lens_vs_streaming": dict(layers=_MOE, solver="streaming"),
+    "moe_vs_deferred_comm": dict(layers=_MOE, factor_comm_freq=2, mesh="dp"),
+    "service_vs_shard_lens": dict(layers=_LENS, service_devices=1),
+    "inverse_tables_vs_other_paths": dict(
+        layers=_BANK, precond_method="inverse", distribute_precondition=True,
+        mesh="dp"),
+    "int8_wire_requires_deferral": dict(factor_comm_dtype="int8", mesh="dp"),
+    "int8_wire_vs_owner_sharding": dict(
+        factor_comm_dtype="int8", factor_comm_freq=2,
+        factor_sharding="owner", mesh="dp"),
+    "staleness_requires_slack": dict(staleness_budget=1),
+}
+
+
+@pytest.mark.parametrize(
+    "rule_name",
+    [r.name for r in REFUSAL_RULES if r.enforced_by == "constructor"],
+)
+def test_constructor_names_the_row_it_refuses_by(rule_name):
+    kw = dict(_ROW_CASES[rule_name])
+    mesh = kw.pop("mesh", None)
+    if mesh is not None:
+        mesh = _mesh_for("multi_axis" if mesh == "seq" else "default_dp8")
+    with pytest.raises(ValueError) as err:
+        KFAC(damping=0.01, mesh=mesh, **kw)
+    named = [r.name for r in REFUSAL_RULES if f"[{r.name}]" in str(err.value)]
+    assert named == [rule_name], str(err.value)
 
 
 def test_owner_accepts_diag_a_layers():
@@ -403,7 +470,7 @@ class _MLP(nn.Module):
         return KFACDense(10, name="fc2")(x)
 
 
-def _lowered_text(kfac):
+def _lowered_text(kfac, **step_kwargs):
     model = _MLP()
     r = np.random.RandomState(0)
     x = jnp.asarray(r.randn(8, 4, 3).astype(np.float32))
@@ -417,7 +484,9 @@ def _lowered_text(kfac):
         opt_state=tx.init(params),
         kfac_state=kfac.init(params),
     )
-    fn = make_train_step(model, tx, kfac, train_kwargs={"train": True})
+    fn = make_train_step(
+        model, tx, kfac, train_kwargs={"train": True}, **step_kwargs
+    )
     return fn.lower(
         state, (x, y), jnp.float32(0.1), jnp.float32(0.01),
         update_factors=True, update_eigen=True,
@@ -431,6 +500,14 @@ def test_profile_none_and_safe_are_inert():
     base = _lowered_text(KFAC(damping=0.01))
     assert _lowered_text(KFAC(damping=0.01, profile=None)) == base
     assert _lowered_text(KFAC(damping=0.01, profile="safe")) == base
+
+
+def test_sgd_hyper_keyword_builds_the_same_step():
+    """make_train_step still takes ``sgd_hyper`` (benchmarks/configs/
+    transformer_lm.py passes it) and builds the program it builds without."""
+    assert _lowered_text(
+        KFAC(damping=0.01), sgd_hyper=(0.9, 5e-4)
+    ) == _lowered_text(KFAC(damping=0.01))
 
 
 def test_profile_fills_only_default_levers():
@@ -550,6 +627,14 @@ def test_plan_dict_round_trip_and_unknown_fields():
     legacy = dict(svc.to_state())
     legacy.pop("service_devices")
     assert Plan.from_state(legacy).service_devices == 0
+
+
+def test_plan_from_state_reads_past_a_stored_apply_kernel():
+    """A checkpoint written while Plan still had ``apply_kernel`` (an int32
+    index like the other categorical levers) restores to the same plan."""
+    plan = Plan(eigh_chunks=2, factor_comm_dtype="bf16", factor_comm_freq=2)
+    old = dict(plan.to_state(), apply_kernel=np.asarray(1, np.int32))
+    assert Plan.from_state(old) == plan
 
 
 # ---------------------------------------------------------------------------

@@ -2,6 +2,7 @@
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from kfac_pytorch_tpu.ops import eigh as eigh_ops
 from kfac_pytorch_tpu.ops import precondition as pc
@@ -67,6 +68,46 @@ def test_precondition_all_matches_per_layer():
         np.testing.assert_allclose(
             np.asarray(got[name]), np.asarray(want), rtol=1e-5, atol=1e-6
         )
+
+
+@pytest.mark.parametrize(
+    "k,g,a",
+    [
+        (1, 8, 9),        # singleton: the per-layer route
+        (2, 16, 17),      # bias-augmented odd A side
+        (3, 24, 25),
+        (4, 10, 130),     # A side wider than one 128 lane
+    ],
+)
+def test_precondition_all_matches_float64_formula(k, g, a):
+    """A group of k same-shape layers (stacked given, stacked on the fly, or
+    alone) against QG ((QGᵀ grad QA) / (dG dAᵀ + λ)) QAᵀ in float64, and the
+    KL clip over the result against its own formula."""
+    r = np.random.RandomState(k * 1000 + g)
+    orth = lambda n: np.linalg.qr(r.randn(n, n))[0].astype(np.float32)
+    gmats, eigen, want = {}, {}, {}
+    for i in range(k):
+        e = {"QA": orth(a), "dA": r.rand(a).astype(np.float32) + 0.1,
+             "QG": orth(g), "dG": r.rand(g).astype(np.float32) + 0.1}
+        grad = r.randn(g, a).astype(np.float32)
+        qa, da, qg, dg, gr = (np.float64(x) for x in (*e.values(), grad))
+        want[f"l{i}"] = qg @ ((qg.T @ gr @ qa) / (np.outer(dg, da) + 0.03)) @ qa.T
+        gmats[f"l{i}"] = jnp.asarray(grad)
+        eigen[f"l{i}"] = {n: jnp.asarray(x) for n, x in e.items()}
+    _, stacked = pc.split_eigen_state(eigen)
+    assert bool(stacked) == (k > 1)
+    for stacks in (None, stacked):
+        got = pc.precondition_all(gmats, eigen, jnp.float32(0.03), stacked=stacks)
+        for name in gmats:
+            np.testing.assert_allclose(
+                np.asarray(got[name]), want[name], rtol=1e-4, atol=1e-5
+            )
+    lr, clip = 0.5, 0.001
+    vg = sum(float((want[n] * np.float64(gmats[n])).sum()) for n in gmats) * lr**2
+    np.testing.assert_allclose(
+        float(pc.kl_clip_coefficient(got, gmats, lr, clip)),
+        min(1.0, np.sqrt(clip / abs(vg))), rtol=1e-4,
+    )
 
 
 def test_kl_clip_no_clipping_when_small():

@@ -132,21 +132,6 @@ def test_solver_hlo_check():
     assert "OK" in res.stdout
 
 
-def test_apply_hlo_check():
-    """The apply_kernel='pallas' program must hold exactly one pallas_call
-    per (g, a) shape group with the standalone eigenbasis dot chain GONE
-    (not duplicated beside the kernels), the dense default must stay
-    kernel-free, and the fused 8-device train step (apply + sgd_hyper)
-    must lower to the identical collective multiset as dense + optax
-    (scripts/check_apply_hlo.py)."""
-    res = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "check_apply_hlo.py")],
-        capture_output=True, text=True, cwd=REPO,
-    )
-    assert res.returncode == 0, f"\n{res.stdout}{res.stderr}"
-    assert "OK" in res.stdout
-
-
 def test_service_hlo_check():
     """Under ``service_devices > 0`` the compiled training step must contain
     zero eigendecomposition custom-calls and no refresh collectives, and the
@@ -213,46 +198,6 @@ def test_no_scratch_files_tracked():
         pytest.skip("not a git checkout")
     bad = res.stdout.splitlines()
     assert not bad, f"scratch files tracked by git: {bad}"
-
-
-def _run_bench(env_update, env_drop=()):
-    env = dict(os.environ)
-    for k in env_drop:
-        env.pop(k, None)
-    env.update(
-        JAX_PLATFORMS="cpu",
-        KFAC_BENCH_ARMS="none",  # no arm keys match: skip all measurements
-        KFAC_BENCH_SKIP_TRANSFORMER="1",
-        KFAC_BENCH_WALL_S="120",
-        **env_update,
-    )
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=110, env=env, cwd=REPO,
-    )
-
-
-def test_bench_refuses_to_run_without_a_chip():
-    """No chip and no forced platform: bench.py exits non-zero, names the
-    platform it found, and prints no result line — there is no probe child,
-    no retry loop and no CPU fallback to hide the device."""
-    res = _run_bench({}, env_drop=("KFAC_FORCE_PLATFORM",))
-    assert res.returncode != 0, res.stdout[-2000:]
-    assert "platform='cpu'" in res.stderr, res.stderr[-2000:]
-    assert not res.stdout.strip(), res.stdout[-2000:]
-
-
-def test_bench_forced_cpu_is_an_explicit_request():
-    """KFAC_FORCE_PLATFORM is the one way onto the CPU backend, set by a
-    test on purpose; the run then says which device it saw."""
-    import json
-
-    res = _run_bench({"KFAC_FORCE_PLATFORM": "cpu:1"})
-    assert res.returncode == 0, f"rc={res.returncode}\n{res.stderr[-2000:]}"
-    rec = json.loads(res.stdout.strip().splitlines()[-1])
-    assert rec["metric"] and "value" in rec and "vs_baseline" in rec
-    assert "cpu" in rec["detail"]["device"].lower()
-    assert "backend_fallback" not in rec["detail"]
 
 
 def test_summarize_curves_compare_fallback(tmp_path):

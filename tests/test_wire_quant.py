@@ -27,6 +27,7 @@ import numpy as np
 import pytest
 
 from kfac_pytorch_tpu import KFAC, EigenRefreshCadence
+from kfac_pytorch_tpu.compile_cache import expected_step_variants
 from kfac_pytorch_tpu.elastic import Supervisor, state_io
 from kfac_pytorch_tpu.parallel import comm
 from kfac_pytorch_tpu.parallel.mesh import data_parallel_mesh
@@ -240,8 +241,18 @@ def test_int8_with_owner_sharding_refuses():
              factor_comm_freq=2, factor_sharding="owner")
 
 
-def test_pallas_with_inverse_degrades_to_dense(capsys):
-    kfac = KFAC(damping=0.01, apply_kernel="pallas",
-                precond_method="inverse")
-    assert kfac.apply_kernel == "dense"
-    assert "falling back to the dense apply" in capsys.readouterr().out
+def test_int8_wire_does_not_widen_variant_budget():
+    """The int8 wire swaps the flush program's merge BODY — the flag
+    schedule (and so the recompile-monitor budget) must not move. This is
+    the pin compile_cache.expected_step_variants' docstring names."""
+    mesh = data_parallel_mesh()
+    kw = dict(damping=0.01, fac_update_freq=1, kfac_update_freq=3, mesh=mesh,
+              factor_comm_freq=2)
+    base = expected_step_variants(KFAC(**kw))
+    assert expected_step_variants(KFAC(**kw, factor_comm_dtype="int8")) == base
+    kfac = KFAC(**kw)
+    assert expected_step_variants(
+        kfac, plan=Plan(factor_comm_freq=2)
+    ) == expected_step_variants(
+        kfac, plan=Plan(factor_comm_freq=2, factor_comm_dtype="int8")
+    )
