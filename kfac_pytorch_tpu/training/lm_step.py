@@ -26,6 +26,7 @@ from kfac_pytorch_tpu.preconditioner import KFAC
 from kfac_pytorch_tpu.training.step import (
     TrainState,
     clip_by_global_norm as _clip_by_global_norm,
+    reset_loss_tally,
     softmax_cross_entropy,
 )
 
@@ -77,14 +78,13 @@ def make_lm_train_step(
             with factor_kernels.factor_kernel_scope(kfac.factor_kernel):
                 return _compute_captured(params, tokens, targets, carry, rngs)
         flash_attention.reset_flash_tally()  # the gauges count this program's kernels
+        reset_loss_tally()  # and its losses
 
         def loss_fn(params):
             logits, new_carry = model.apply(
                 {"params": params}, tokens, carry=carry, train=True, rngs=rngs
             )
-            loss = softmax_cross_entropy(
-                logits.reshape(-1, logits.shape[-1]), targets.reshape(-1)
-            )
+            loss = softmax_cross_entropy(logits, targets)
             return loss, new_carry
 
         with phase("model"):
@@ -97,6 +97,7 @@ def make_lm_train_step(
         perts = capture.perturbation_zeros(model, tokens, train=True)
         factors.reset_capture_tally()  # the gauges count this program's products
         flash_attention.reset_flash_tally()  # and its attention kernels
+        reset_loss_tally()  # and its losses
 
         def loss_fn(params, perts):
             (logits, new_carry), mut = model.apply(
@@ -107,9 +108,7 @@ def make_lm_train_step(
                 mutable=[KFAC_ACTS],
                 rngs=rngs,
             )
-            loss = softmax_cross_entropy(
-                logits.reshape(-1, logits.shape[-1]), targets.reshape(-1)
-            )
+            loss = softmax_cross_entropy(logits, targets)
             return loss, (mut, new_carry)
 
         with phase("model"):
@@ -283,9 +282,7 @@ def make_lm_eval_step(model):
         logits, new_carry = model.apply(
             {"params": state.params}, tokens, carry=carry, train=False
         )
-        loss = softmax_cross_entropy(
-            logits.reshape(-1, logits.shape[-1]), targets.reshape(-1)
-        )
+        loss = softmax_cross_entropy(logits, targets)
         return {"loss": loss, "ppl": jnp.exp(loss)}, new_carry
 
     return jax.jit(eval_step)

@@ -16,6 +16,7 @@ Each (update_factors, update_eigen) combination compiles once and is cached.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional, Tuple
 
 import flax.struct
@@ -28,6 +29,7 @@ from kfac_pytorch_tpu import capture, compat
 from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS, STEP_SCALARS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
 from kfac_pytorch_tpu.observability.phases import phase
+from kfac_pytorch_tpu.observability.telemetry import get_telemetry
 from kfac_pytorch_tpu.ops import factor_kernels, factors, flash_attention
 from kfac_pytorch_tpu.preconditioner import KFAC
 
@@ -207,23 +209,110 @@ def make_sgd(momentum: float = 0.9, weight_decay: float = 0.0):
     return optax.chain(*chain)
 
 
+# Trace-time count of the closed-form losses built since the last
+# :func:`reset_loss_tally` (the precedent is ops/factors.py::_TALLY).
+_TALLY = {"calls": 0}
+
+
+def reset_loss_tally() -> None:
+    """Start the count behind ``loss/closed_form_calls`` anew. The step
+    builders call it where a step program's forward/backward starts to trace,
+    so the gauge describes that program."""
+    _TALLY["calls"] = 0
+
+
+def _class_iota(x):
+    return lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+
+
+def _cross_entropy_fwd(logits, labels, label_smoothing):
+    x = logits.astype(jnp.float32)
+    v = x.shape[-1]
+    m = jnp.max(x, axis=-1, keepdims=True)
+    # ONE pass over the classes for the sum of exponentials, the first index
+    # of the maximum (jnp.argmax's answer; V where the row holds a NaN), the
+    # label's logit and, under smoothing, the sum of the logits. The label's
+    # logit is a member of the reduce and not a gather: on the v5e a gather
+    # out of the logits moved the whole step program's activations out of the
+    # chip's fast memory and cost what the closed form saves (PERF.md, PR 31).
+    # Every term is taken relative to the maximum, so nothing is rounded at
+    # the logits' magnitude.
+    iota = _class_iota(x)
+    operands = [
+        jnp.exp(x - m),
+        jnp.where(x == m, iota, v),
+        jnp.where(iota == labels[..., None], x - m, 0.0),
+    ]
+    inits = [jnp.float32(0.0), jnp.int32(v), jnp.float32(0.0)]
+    combiners = [jnp.add, jnp.minimum, jnp.add]
+    if label_smoothing > 0.0:
+        operands.append(x - m)
+        inits.append(jnp.float32(0.0))
+        combiners.append(jnp.add)
+    l, idx, picked, *total = lax.reduce(
+        operands,
+        inits,
+        lambda a, b: tuple(f(p, q) for f, p, q in zip(combiners, a, b)),
+        (x.ndim - 1,),
+    )
+    log_l = jnp.log(l)
+    loss = log_l - (1.0 - label_smoothing) * picked
+    if label_smoothing > 0.0:
+        loss = loss - label_smoothing * total[0] / v
+    correct = (idx == labels).astype(jnp.float32)
+    return (loss, correct), (logits, m, log_l, labels)
+
+
+def _cross_entropy_bwd(label_smoothing, residuals, cotangents):
+    logits, m, log_l, labels = residuals
+    g, _ = cotangents  # nothing is differentiated through the accuracy
+    x = logits.astype(jnp.float32)
+    target = (_class_iota(x) == labels[..., None]).astype(jnp.float32)
+    if label_smoothing > 0.0:
+        target = (1.0 - label_smoothing) * target + label_smoothing / x.shape[-1]
+    dx = (jnp.exp(x - m - log_l[..., None]) - target) * g[..., None]
+    return dx.astype(logits.dtype), None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _cross_entropy(logits, labels, label_smoothing):
+    return _cross_entropy_fwd(logits, labels, label_smoothing)[0]
+
+
+_cross_entropy.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
+
+
 def per_sample_cross_entropy(
     logits: jnp.ndarray, labels: jnp.ndarray, label_smoothing: float = 0.0
-) -> jnp.ndarray:
-    """Per-sample CE with optional label smoothing → shape ``[batch]``."""
-    num_classes = logits.shape[-1]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    onehot = jax.nn.one_hot(labels, num_classes, dtype=jnp.float32)
-    if label_smoothing > 0.0:
-        onehot = (1.0 - label_smoothing) * onehot + label_smoothing / num_classes
-    return -jnp.sum(onehot * logp, axis=-1)
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-sample CE with optional label smoothing, and whether the first
+    maximal logit is the label's (float32 0/1) → two arrays of the labels'
+    shape, from one reduction over the classes.
+
+    Closed form, forward and backward (``lse - logits[label]``; ``softmax -
+    target``), float32 throughout, over the LAST axis of the logits as they
+    come: an LM head writes ``[B, T, V]`` logits classes-second-minor on the
+    TPU, and flattening them first costs a copy of the whole array. An
+    out-of-range label picks no logit (its loss is the row's ``lse - max``).
+    """
+    _TALLY["calls"] += 1
+    get_telemetry().set_gauge("loss/closed_form_calls", _TALLY["calls"])
+    return _cross_entropy(logits, labels, float(label_smoothing))
+
+
+def cross_entropy_and_accuracy(
+    logits: jnp.ndarray, labels: jnp.ndarray, label_smoothing: float = 0.0
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Mean CE and top-1 accuracy over every row, reading the logits once."""
+    loss, correct = per_sample_cross_entropy(logits, labels, label_smoothing)
+    return jnp.mean(loss), jnp.mean(correct)
 
 
 def softmax_cross_entropy(
     logits: jnp.ndarray, labels: jnp.ndarray, label_smoothing: float = 0.0
 ) -> jnp.ndarray:
     """Mean CE with optional label smoothing (examples/utils.py:19-31)."""
-    return jnp.mean(per_sample_cross_entropy(logits, labels, label_smoothing))
+    return cross_entropy_and_accuracy(logits, labels, label_smoothing)[0]
 
 
 def _variables(params, batch_stats, extra=None):
@@ -332,6 +421,7 @@ def make_train_step(
         perts = capture.perturbation_zeros(model, images, **train_kwargs)
         factors.reset_capture_tally()  # the gauges count this program's products
         flash_attention.reset_flash_tally()  # and its attention kernels
+        reset_loss_tally()  # and its losses
         has_bn = bool(batch_stats)
         mutable = (["batch_stats"] if has_bn else []) + [KFAC_ACTS, STEP_SCALARS]
 
@@ -343,11 +433,11 @@ def make_train_step(
                 **train_kwargs,
             )
             logits, mut = out
-            loss = softmax_cross_entropy(logits, labels, label_smoothing)
-            return loss, (mut, logits)
+            loss, acc = cross_entropy_and_accuracy(logits, labels, label_smoothing)
+            return loss, (mut, acc)
 
         with phase("model"):
-            (loss, (mut, logits)), (grads, gperts) = jax.value_and_grad(
+            (loss, (mut, acc)), (grads, gperts) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(params, perts)
         if kfac is not None and kfac.layers is not None:
@@ -365,10 +455,6 @@ def make_train_step(
             gperts, names, batch_averaged=ba, captured=mut[KFAC_ACTS]
         )
         new_bs = mut.get("batch_stats", batch_stats)
-        with phase("model"):  # the accuracy reads the forward pass's logits
-            acc = jnp.mean(
-                (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
-            )
         return loss, acc, grads, new_bs, a_c, g_s, _step_scalars(mut)
 
     def _step_scalars(mut):
@@ -381,6 +467,7 @@ def make_train_step(
 
     def loss_and_grads_plain(params, batch_stats, images, labels):
         flash_attention.reset_flash_tally()  # the gauges count this program's kernels
+        reset_loss_tally()  # and its losses
         has_bn = bool(batch_stats)
         mutable = (["batch_stats"] if has_bn else []) + [STEP_SCALARS]
 
@@ -391,18 +478,14 @@ def make_train_step(
                 mutable=mutable,
                 **train_kwargs,
             )
-            loss = softmax_cross_entropy(logits, labels, label_smoothing)
-            return loss, (mut, logits)
+            loss, acc = cross_entropy_and_accuracy(logits, labels, label_smoothing)
+            return loss, (mut, acc)
 
         with phase("model"):
-            (loss, (mut, logits)), grads = jax.value_and_grad(
+            (loss, (mut, acc)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params)
         new_bs = mut.get("batch_stats", batch_stats)
-        with phase("model"):  # the accuracy reads the forward pass's logits
-            acc = jnp.mean(
-                (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
-            )
         return loss, acc, grads, new_bs, None, None, _step_scalars(mut)
 
     @phase("model")
@@ -609,12 +692,8 @@ def make_eval_step(model, label_smoothing: float = 0.0, eval_kwargs: Optional[di
         logits = model.apply(
             _variables(state.params, state.batch_stats), images, **eval_kwargs
         )
-        return {
-            "loss": softmax_cross_entropy(logits, labels, label_smoothing),
-            "accuracy": jnp.mean(
-                (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
-            ),
-        }
+        loss, acc = cross_entropy_and_accuracy(logits, labels, label_smoothing)
+        return {"loss": loss, "accuracy": acc}
 
     return jax.jit(eval_step)
 
@@ -638,8 +717,7 @@ def make_masked_eval_step(
         logits = model.apply(
             _variables(state.params, state.batch_stats), images, **eval_kwargs
         )
-        ce = per_sample_cross_entropy(logits, labels, label_smoothing)
-        correct = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
+        ce, correct = per_sample_cross_entropy(logits, labels, label_smoothing)
         return {
             "loss_sum": jnp.sum(ce * mask),
             "correct": jnp.sum(correct * mask),

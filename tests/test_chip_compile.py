@@ -171,3 +171,59 @@ def test_flash_attention_chosen_tiling_compiles_at_the_cells_shapes(one_chip, sh
     entry = hlo[hlo.index("\nENTRY"):]
     assert entry.count('custom_call_target="tpu_custom_call"') == 3  # fwd, dq, dk/dv
     assert " dot(" not in hlo and "convolution" not in hlo  # no product outside them
+
+
+def test_head_and_closed_form_loss_read_the_logits_once(one_chip):
+    """GPT-2 small's head, training/step.py's cross-entropy and its gradient at
+    the cells' shapes (8 x 1024 rows, 50,257 classes): of the fusions that
+    touch the `[8, 1024, 50257]` logits three are products (the head's, which
+    also gives the row maximum, and its two transposes, with `softmax -
+    onehot` formed in their operands) and ONE is a reduction (sum of
+    exponentials, first maximal index and the label's logit together).
+    Nothing copies the array (the head writes it classes-second-minor: a
+    flattened `[rows, classes]` view would), nothing else as large is written,
+    and nothing gathers from it: on the chip a gather of the labels' logits
+    moved the whole step program's activations out of fast memory (PERF.md,
+    section 6, PR 31)."""
+    from kfac_pytorch_tpu.training.step import cross_entropy_and_accuracy
+
+    b, t, d, v = 8, 1024, 768, 50257
+
+    def step(h, w, labels):
+        def loss_fn(h, w):
+            return cross_entropy_and_accuracy(jnp.einsum("btd,vd->btv", h, w), labels)
+
+        return jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)(h, w)
+
+    args = [
+        jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+        for s, dt in (((b, t, d), jnp.float32), ((v, d), jnp.float32), ((b, t), jnp.int32))
+    ]
+    compiled = jax.jit(step).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.8e9  # the logits, 1.65e9, and little else
+    hlo = compiled.as_text()
+    bodies = dict(re.findall(r"\n%?(fused_computation[\w.]*) [^\n]*\{\n(.*?)\n\}", hlo, re.S))
+    entry = hlo[hlo.index("\nENTRY"):]
+    logits = f"f32[{b},{t},{v}]"
+    kinds = []
+    producers = set()
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*?)\)(?:, |$)", line)
+        if not m:
+            continue
+        name, result, op, operands = m.groups()
+        if logits in result:
+            producers.add(name)
+        if not (logits in result or producers & set(re.findall(r"%([\w.-]+)", operands))):
+            continue
+        if op == "get-tuple-element":
+            continue
+        assert op == "fusion", line  # no copy, transpose or reshape of the array on its own
+        body = bodies[re.search(r"calls=%?([\w.]+)", line).group(1)]
+        kinds.append(
+            "product" if " convolution(" in body or " dot(" in body
+            else "gather" if " gather(" in body
+            else "reduction" if " reduce(" in body
+            else "other"
+        )
+    assert sorted(kinds) == ["product", "product", "product", "reduction"], kinds
