@@ -23,6 +23,7 @@ from kfac_pytorch_tpu.models.layers import (
     A_ROW,
     A_SHARED,
     A_SPLIT,
+    BANK_INPUT,
     BANK_ROWS,
     BANK_TOKENS,
     G_TIED,
@@ -559,6 +560,51 @@ def g_factors(
     return out
 
 
+def bank_tape(
+    tape: PyTree,
+    perturb_grads: PyTree,
+    names: List[str],
+    shared_a: Dict[str, str],
+) -> Dict[str, Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]]:
+    """``{bank name: (rows [M, a], ∂L/∂(bank output) [M, m], group sizes
+    [E])}`` for every expert bank of ``names``, from the ``KFAC_TAPE``
+    collection a step sowed and the gradient of its perturbations: the routed
+    rows the bank multiplied (a ``shared_a`` bank reads its owner's), their
+    cotangents and how many rows each expert has, each in its own dtype. The
+    kernel's gradient is ``rowsᵀ · cotangents`` per expert, so the apply can
+    work from these (ops/precondition.py::precondition_bank_rows)."""
+    out = {}
+    for name in names:
+        bbase, bcount = split_bank_name(name)
+        if bcount is None:
+            continue
+        owner = split_bank_name(shared_a.get(name, name))[0]
+        out[name] = (
+            _unwrap_sown(_get_path(tape, owner)[BANK_INPUT]),
+            _get_path(perturb_grads, bbase)[OUT_PERTURB],
+            _unwrap_sown(_get_path(tape, bbase)[BANK_ROWS]),
+        )
+    return out
+
+
+def bank_perturbation_zeros(model, names: List[str], *args, **kwargs) -> PyTree:
+    """:func:`perturbation_zeros` of the expert banks of ``names`` alone: what
+    a step that captures nothing perturbs to read its banks' cotangents for
+    :func:`bank_tape` (the other layers' outputs stay unperturbed)."""
+    perts = perturbation_zeros(model, *args, **kwargs)
+    out = {}
+    for name in names:
+        bbase, bcount = split_bank_name(name)
+        if bcount is None:
+            continue
+        *parents, leaf = bbase.split("/")
+        node = out
+        for k in parents:
+            node = node.setdefault(k, {})
+        node[leaf] = _get_path(perts, bbase)
+    return out
+
+
 def factor_stat_tree(
     a_contribs: Dict[str, jnp.ndarray], g_stats: Dict[str, jnp.ndarray]
 ) -> Dict[str, Dict[str, jnp.ndarray]]:
@@ -583,16 +629,20 @@ def split_factor_stat_tree(
 
 
 def grad_mats(
-    lgrads: Dict[str, Dict[str, jnp.ndarray]]
+    lgrads: Dict[str, Dict[str, jnp.ndarray]], kernel_layout=frozenset()
 ) -> Dict[str, jnp.ndarray]:
     """Per-layer factor-space gradient matrices ``[out, in(+1)]``.
 
     MoE expert banks (``#eE`` names, rank-3 ``[E, a, m]`` kernels) become
-    stacked ``[E, m, a]`` matrices — one factor-space mat per expert.
+    stacked ``[E, m, a]`` matrices — one factor-space mat per expert — but
+    for the banks named in ``kernel_layout``, which keep their kernel's
+    ``[E, a, m]`` (those preconditioned from their rows).
     """
     out = {}
     for name, g in lgrads.items():
-        if split_shard_name(name)[1] == "e" or split_bank_name(name)[1] is not None:
+        if name in kernel_layout:
+            out[name] = g["kernel"]
+        elif split_shard_name(name)[1] == "e" or split_bank_name(name)[1] is not None:
             out[name] = jnp.transpose(g["kernel"], (0, 2, 1))
         else:
             out[name] = factors.grads_to_mat(g)
@@ -600,13 +650,15 @@ def grad_mats(
 
 
 def write_back(
-    grads: PyTree, updates: Dict[str, jnp.ndarray], nu: jnp.ndarray
+    grads: PyTree, updates: Dict[str, jnp.ndarray], nu: jnp.ndarray,
+    kernel_layout=frozenset(),
 ) -> PyTree:
     """Scatter ν-scaled preconditioned matrices back into the full grad pytree.
 
     Non-K-FAC leaves (BN, embeddings, ...) pass through untouched — parity
     with the reference, which only rewrites Linear/Conv2d grads
-    (kfac_preconditioner.py:328-334).
+    (kfac_preconditioner.py:328-334). The banks named in ``kernel_layout``
+    come in their kernel's ``[E, a, m]`` and are written as they come.
     """
     def _deep_copy(node):
         if isinstance(node, dict):
@@ -621,9 +673,10 @@ def write_back(
         if bcount is not None:
             # stacked [E, m, a] expert updates back to the [E, a, m] bank
             node = _get_path(grads, bbase)
-            node["kernel"] = jnp.transpose(mat * nu, (0, 2, 1)).astype(
-                node["kernel"].dtype
-            )
+            mat = mat * nu
+            if name not in kernel_layout:
+                mat = jnp.transpose(mat, (0, 2, 1))
+            node["kernel"] = mat.astype(node["kernel"].dtype)
             continue
         shbase, form, _ = split_shard_name(name)
         if form is not None:

@@ -43,6 +43,10 @@ Padding = Union[str, int, Sequence[Tuple[int, int]]]
 # Collection names (public constants — capture.py and train steps use them).
 KFAC_ACTS = "kfac_acts"
 PERTURBATIONS = "perturbations"
+# An expert bank's tape (KFACBankDense): what the apply needs to precondition
+# the bank from its rows, sown wherever a step program asks for it, captured
+# or not.
+KFAC_TAPE = "kfac_tape"
 # Variable names inside a layer's path.
 A_CONTRIB = "a"
 OUT_PERTURB = "out"
@@ -73,11 +77,15 @@ OUT_MOE = "out_moe"
 # A_BANK is the [E, a, a] stack (plain 1/T scaling: an expert is a layer of
 # its own whose unrouted rows are zero); BANK_ROWS the [E] row counts and
 # BANK_TOKENS the token count T, which the G side needs beside the [M, m]
-# cotangent. A_SHARED marks a layer that reads the same input as a sibling
-# and keeps no A statistic of its own (KFAC(shared_a=...) names the owner).
+# cotangent. BANK_INPUT is the [M, a] rows themselves, as they come: with
+# BANK_ROWS, the half of a bank's tape (KFAC_TAPE) that the apply reads
+# beside that cotangent (ops/precondition.py::precondition_bank_rows).
+# A_SHARED marks a layer that reads the same input as a sibling and keeps no
+# A statistic of its own (KFAC(shared_a=...) names the owner).
 A_BANK = "a_bank"
 BANK_ROWS = "bank_rows"
 BANK_TOKENS = "bank_tokens"
+BANK_INPUT = "bank_input"
 A_SHARED = "a_shared"
 # Scalars a model reports beside the loss (routing load, ...): sown here,
 # ``make_train_step`` puts them into the step's metrics.
@@ -233,7 +241,9 @@ class KFACBankDense(_KFACLayer):
     ``A_e = (1/T) sum_{t in e} x_t x_t^T`` and, from the perturbation's
     cotangent, ``G_e = T sum_{t in e} g_t g_t^T`` with ``T = n_tokens``,
     running averages with the plain decay. Captured as ONE ``name#bE`` layer
-    whose factors stay stacked ``[E, ., .]``.
+    whose factors stay stacked ``[E, ., .]``. Where ``KFAC_TAPE`` is mutable
+    the group sizes and the rows it owns are sown there too, so that the apply
+    can precondition the bank from them.
     """
 
     features: int
@@ -270,6 +280,10 @@ class KFACBankDense(_KFACLayer):
                     ),
                     reduce_fn=_overwrite,
                 )
+        if self.kfac and not self.is_initializing() and self.is_mutable_collection(KFAC_TAPE):
+            self.sow(KFAC_TAPE, BANK_ROWS, group_sizes, reduce_fn=_overwrite)
+            if not self.a_shared:
+                self.sow(KFAC_TAPE, BANK_INPUT, rows, reduce_fn=_overwrite)
         rows, kernel = nn.dtypes.promote_dtype(rows, kernel, dtype=self.dtype)
         y = grouped.grouped_matmul(rows, kernel, group_sizes)
         return self._maybe_perturb(y) if self.kfac else y

@@ -18,6 +18,8 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kfac_pytorch_tpu import compat
+from kfac_pytorch_tpu.observability.telemetry import get_telemetry
+from kfac_pytorch_tpu.ops import grouped
 
 _HIGHEST = lax.Precision.HIGHEST
 # Eigenbasis rotations default to HIGH (3-pass bf16 error compensation,
@@ -1010,6 +1012,61 @@ def precondition_all_inv_tables(
             inv[key] = rows_of if g.ndim == 3 else rows_of[0]
         out[name] = precondition_mat_inv(g, inv["iA"], inv["iG"], precision)
     return out
+
+
+# Trace-time count of the banks preconditioned from their routed rows since
+# the last :func:`reset_apply_tally` (the precedent is ops/factors.py::_TALLY).
+_TALLY = {"routed": 0}
+
+
+def reset_apply_tally() -> None:
+    """Start the count behind ``kfac/apply_bank_routed`` anew, at 0. The step
+    builders call it where a step program starts to trace, so the gauge
+    describes that program (0 in one that preconditions no bank by rows)."""
+    _TALLY["routed"] = 0
+    get_telemetry().set_gauge("kfac/apply_bank_routed", 0)
+
+
+def bank_rows_pay(rows: int, experts: int, a: int, m: int) -> bool:
+    """Whether the update of an ``[E, a, m]`` bank from ``rows`` routed rows
+    (:func:`precondition_bank_rows`: ``2·M·(a² + m² + a·m)`` multiply-adds)
+    takes no more multiply-adds than the dense ``iG · g · iA``
+    (``2·E·(m²·a + m·a²)``) even when every row is real. Static: from shapes
+    alone. It counts multiply-adds, not passes: on the Mosaic kernels (one
+    device) the routed products run at float32, about twice the passes of the
+    dense form's ``high`` (ops/grouped.py), so there the routed form costs no
+    more only while about half the bound's rows or fewer are real. For
+    GLM-4.7-Flash's gate and up banks (8,192 rows against a bound of 9,299)
+    that is about 4,650 rows in the held groups, 4.5 times the mean load of
+    about 1,024; with every row in a held group the routed form takes about
+    1.76 times the dense form's passes."""
+    return rows * (a * a + m * m + a * m) <= experts * (m * m * a + m * a * a)
+
+
+def precondition_bank_rows(
+    rows: jnp.ndarray,
+    cotangents: jnp.ndarray,
+    group_sizes: jnp.ndarray,
+    tables: Dict[str, jnp.ndarray],
+    layout: Dict[str, Tuple[int, int, int]],
+    precision: lax.Precision = _ROTATION_PRECISION,
+) -> jnp.ndarray:
+    """A bank's ``v`` from the rows each expert saw, in the kernel's
+    ``[E, a, m]`` layout (the transpose of :func:`precondition_all_inv_tables`'
+    ``[E, m, a]``): the bank's gradient is ``g_e = ΔY_eᵀ X_e`` over its routed
+    rows, so ``v_eᵀ = iA_e · g_eᵀ · iG_e = (X_e · iA_e)ᵀ (ΔY_e · iG_e)`` (the
+    inverses are symmetric), three grouped products over the ``M`` rows
+    (``rows`` ``[M, a]``, ``cotangents`` ``[M, m]``, sorted by expert) in
+    place of two dense ones over every expert's ``[m, a]``. The same
+    arithmetic, not an approximation; the inverses are read where they lie in
+    their tables (``layout``: the layer's entry of
+    :func:`inverse_table_layout`)."""
+    (side_a, first_a, _), (side_g, first_g, _) = layout["iA"], layout["iG"]
+    x_ia = grouped.grouped_project(rows, tables[str(side_a)], first_a, group_sizes, precision)
+    dy_ig = grouped.grouped_project(cotangents, tables[str(side_g)], first_g, group_sizes, precision)
+    _TALLY["routed"] += 1
+    get_telemetry().set_gauge("kfac/apply_bank_routed", _TALLY["routed"])
+    return grouped.grouped_outer(x_ia, dy_ig, group_sizes, precision)
 
 
 def split_inv_state(

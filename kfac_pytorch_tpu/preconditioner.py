@@ -1393,6 +1393,8 @@ class KFAC:
         *,
         a_contribs: Optional[Dict[str, jnp.ndarray]] = None,
         g_factor_stats: Optional[Dict[str, jnp.ndarray]] = None,
+        bank_tape: Optional[Dict[str, Tuple[jnp.ndarray, ...]]] = None,
+        grad_scale: Optional[jnp.ndarray] = None,
         lr: Optional[jnp.ndarray] = None,
         damping: Optional[jnp.ndarray] = None,
         update_factors: bool,
@@ -1409,7 +1411,14 @@ class KFAC:
         (see ``training.step.kfac_flags_for_step``); each combination is its
         own compiled program, so non-update steps pay zero capture/eigh cost.
         ``a_contribs``/``g_factor_stats`` come from capture.py and are
-        required iff ``update_factors``. ``lr`` is REQUIRED (it scales the KL
+        required iff ``update_factors``. ``bank_tape`` (capture.py::bank_tape:
+        ``{bank: (rows, cotangents, group sizes)}``, from a step whose
+        gradient they make) lets the apply precondition an expert bank from
+        the rows each expert saw (ops/precondition.py::precondition_bank_rows)
+        wherever that costs no more than the dense form, which is a rule of
+        shapes alone; ``grad_scale`` is the one factor by which the step
+        scaled the gradients since (the global-norm clip), None for 1.
+        ``lr`` is REQUIRED (it scales the KL
         trust-region clip, kfac_preconditioner.py:320-326, and must track the
         trainer's schedule — a silently-stale fallback here once meant the
         clip used the construction-time lr). ``damping`` defaults to the
@@ -1874,7 +1883,8 @@ class KFAC:
         if not precond_early:
             with phase("kfac_apply", "trace/kfac/precondition"):
                 new_grads, gmats, updates, nu = self._precondition_replicated(
-                    grads, names, facs, eigen, stacked, lr, damping, tables
+                    grads, names, facs, eigen, stacked, lr, damping, tables,
+                    bank_tape, grad_scale,
                 )
 
         new_state = {
@@ -1925,16 +1935,24 @@ class KFAC:
         return new_grads, new_state
 
     def _precondition_replicated(
-        self, grads, names, facs, eigen, stacked, lr, damping, tables=None
+        self, grads, names, facs, eigen, stacked, lr, damping, tables=None,
+        bank_tape=None, grad_scale=None,
     ):
         """The every-step precondition + KL clip of the replicated flow,
         factored out so the overlap plane can emit it either before the
         chunk-eigh (comm_overlap chunk-only steps) or after the refresh
         branches (everywhere else) without duplicating the dispatch."""
         lgrads = capture.layer_grads(grads, names)
+        # the banks preconditioned from their rows: their gradient and update
+        # stay in the kernel's [E, a, m] (no transpose either way)
+        routed = {
+            n: t for n, t in (bank_tape or {}).items()
+            if tables is not None
+            and precond_ops.bank_rows_pay(t[0].shape[0], *lgrads[n]["kernel"].shape)
+        }
         gmats = {
             name: mat.astype(jnp.float32)
-            for name, mat in capture.grad_mats(lgrads).items()
+            for name, mat in capture.grad_mats(lgrads, frozenset(routed)).items()
         }
         # Shard-lens gmats (stacked 3-D, or block-structured 2-D) solve
         # shard-locally (shardwise.precondition) — they never enter the
@@ -1965,9 +1983,18 @@ class KFAC:
                 comm_dtype=self.precond_comm_dtype,
             )
         elif tables is not None:
+            layout = self._inverse_layout(facs)[0]
             updates = precond_ops.precondition_all_inv_tables(
-                norm_gmats, tables, self._inverse_layout(facs)[0], *precision_args
+                {n: g for n, g in norm_gmats.items() if n not in routed},
+                tables, layout, *precision_args,
             )
+            # v is linear in g: the step's clip factor carries over
+            scale = 1.0 if grad_scale is None else grad_scale
+            for n, tape in routed.items():
+                updates[n] = scale * precond_ops.precondition_bank_rows(
+                    *tape, tables, layout[n], *precision_args
+                )
+            updates = {n: updates[n] for n in norm_gmats}  # the KL clip's order
         elif inverse:
             updates = precond_ops.precondition_all_inv(
                 norm_gmats, eigen, *precision_args, stacked=stacked
@@ -1985,7 +2012,7 @@ class KFAC:
         nu = precond_ops.kl_clip_coefficient(
             updates, gmats, lr, self.hparams.kl_clip
         )
-        new_grads = capture.write_back(grads, updates, nu)
+        new_grads = capture.write_back(grads, updates, nu, frozenset(routed))
         return new_grads, gmats, updates, nu
 
     def _update_owner(

@@ -26,11 +26,12 @@ import optax
 from jax import lax
 
 from kfac_pytorch_tpu import capture, compat
-from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS, STEP_SCALARS
+from kfac_pytorch_tpu.models.layers import KFAC_ACTS, KFAC_TAPE, PERTURBATIONS, STEP_SCALARS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
 from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.observability.telemetry import get_telemetry
 from kfac_pytorch_tpu.ops import factor_kernels, factors, flash_attention
+from kfac_pytorch_tpu.ops import precondition as precond_ops
 from kfac_pytorch_tpu.preconditioner import KFAC
 
 PyTree = Any
@@ -126,7 +127,7 @@ def _compressed_grads(compute, mesh, comm_dtype, accum_steps, factor_comm=None):
         check_vma=False,
     )
     def _inner(params, batch_stats, images, labels):
-        loss, acc, grads, new_bs, a_c, g_s, scalars = compute(
+        loss, acc, grads, new_bs, a_c, g_s, scalars, _ = compute(
             params, batch_stats, images, labels
         )
         overlap = factor_comm is not None and factor_comm.overlap
@@ -150,7 +151,8 @@ def _compressed_grads(compute, mesh, comm_dtype, accum_steps, factor_comm=None):
                 # per-leaf f32 exchange
                 a_c = lax.pmean(a_c, axis)
                 g_s = lax.pmean(g_s, axis)
-        return loss, acc, grads, new_bs, a_c, g_s, lax.pmean(scalars, axis)
+        # no bank tape: a device's rows make its own gradient, not the mean
+        return loss, acc, grads, new_bs, a_c, g_s, lax.pmean(scalars, axis), None
 
     return _inner
 
@@ -324,10 +326,15 @@ def _variables(params, batch_stats, extra=None):
     return v
 
 
+def clip_scale(grads: PyTree, max_norm: float) -> jnp.ndarray:
+    """The one factor by which :func:`clip_by_global_norm` scales every leaf."""
+    gnorm = optax.global_norm(grads)
+    return jnp.minimum(1.0, max_norm / jnp.maximum(gnorm, 1e-12))
+
+
 def clip_by_global_norm(grads: PyTree, max_norm: float) -> PyTree:
     """``torch.nn.utils.clip_grad_norm_`` semantics (scale if above max)."""
-    gnorm = optax.global_norm(grads)
-    scale = jnp.minimum(1.0, max_norm / jnp.maximum(gnorm, 1e-12))
+    scale = clip_scale(grads, max_norm)
     return jax.tree_util.tree_map(lambda g: g * scale, grads)
 
 
@@ -407,6 +414,13 @@ def make_train_step(
     comm_active = factor_comm is not None and factor_comm.active
     if comm_active and mesh is None:
         mesh = kfac.mesh
+    # the expert banks whose tape a step that captures nothing keeps too, so
+    # that every step may precondition them from their rows (none in a model
+    # without banks, whose plain program is what it was)
+    tape_banks = [
+        n for n in (kfac.layers or [] if kfac is not None else [])
+        if capture.split_bank_name(n)[1] is not None
+    ]
 
     def loss_and_grads_captured(params, batch_stats, images, labels):
         # Trace-time scope: the KFACConv layers inside model.apply route
@@ -422,8 +436,9 @@ def make_train_step(
         factors.reset_capture_tally()  # the gauges count this program's products
         flash_attention.reset_flash_tally()  # and its attention kernels
         reset_loss_tally()  # and its losses
+        precond_ops.reset_apply_tally()  # and its banks preconditioned by rows
         has_bn = bool(batch_stats)
-        mutable = (["batch_stats"] if has_bn else []) + [KFAC_ACTS, STEP_SCALARS]
+        mutable = (["batch_stats"] if has_bn else []) + [KFAC_ACTS, KFAC_TAPE, STEP_SCALARS]
 
         def loss_fn(params, perts):
             out = model.apply(
@@ -454,8 +469,13 @@ def make_train_step(
         g_s = capture.g_factors(
             gperts, names, batch_averaged=ba, captured=mut[KFAC_ACTS]
         )
+        # an expert bank's routed rows and their cotangents: its gradient
+        # is their product, so the apply can work from them
+        tape = capture.bank_tape(
+            mut.get(KFAC_TAPE, {}), gperts, names, kfac.shared_a if kfac else {}
+        )
         new_bs = mut.get("batch_stats", batch_stats)
-        return loss, acc, grads, new_bs, a_c, g_s, _step_scalars(mut)
+        return loss, acc, grads, new_bs, a_c, g_s, _step_scalars(mut), tape
 
     def _step_scalars(mut):
         # scalars the model reports beside the loss (models/layers.py::
@@ -465,15 +485,24 @@ def make_train_step(
             for name, value in mut.get(STEP_SCALARS, {}).items()
         }
 
-    def loss_and_grads_plain(params, batch_stats, images, labels):
+    def loss_and_grads_plain(params, batch_stats, images, labels, taped=False):
         flash_attention.reset_flash_tally()  # the gauges count this program's kernels
         reset_loss_tally()  # and its losses
+        precond_ops.reset_apply_tally()  # and its banks preconditioned by rows
         has_bn = bool(batch_stats)
         mutable = (["batch_stats"] if has_bn else []) + [STEP_SCALARS]
+        # ``taped``: the banks' tape (capture.bank_tape), read through
+        # perturbations of their outputs alone; nothing else is captured
+        perts = (
+            capture.bank_perturbation_zeros(model, tape_banks, images, **train_kwargs)
+            if taped and tape_banks else {}
+        )
+        if perts:
+            mutable.append(KFAC_TAPE)
 
-        def loss_fn(params):
+        def loss_fn(params, perts):
             logits, mut = model.apply(
-                _variables(params, batch_stats),
+                _variables(params, batch_stats, {PERTURBATIONS: perts} if perts else None),
                 images,
                 mutable=mutable,
                 **train_kwargs,
@@ -481,12 +510,19 @@ def make_train_step(
             loss, acc = cross_entropy_and_accuracy(logits, labels, label_smoothing)
             return loss, (mut, acc)
 
+        tape = None
         with phase("model"):
-            (loss, (mut, acc)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params)
+            if perts:
+                (loss, (mut, acc)), (grads, gperts) = jax.value_and_grad(
+                    loss_fn, argnums=(0, 1), has_aux=True
+                )(params, perts)
+                tape = capture.bank_tape(mut[KFAC_TAPE], gperts, tape_banks, kfac.shared_a)
+            else:
+                (loss, (mut, acc)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params, perts)
         new_bs = mut.get("batch_stats", batch_stats)
-        return loss, acc, grads, new_bs, None, None, _step_scalars(mut)
+        return loss, acc, grads, new_bs, None, None, _step_scalars(mut), tape
 
     @phase("model")
     def accum_loss_and_grads(params, batch_stats, images, labels, capture_stats):
@@ -515,14 +551,15 @@ def make_train_step(
         )
         a_c = g_s = None
         if capture_stats:
-            loss, acc, grads, bs, a_c, g_s, _ = loss_and_grads_captured(
+            loss, acc, grads, bs, a_c, g_s, *_ = loss_and_grads_captured(
                 params, bs, images[-1], labels[-1]
             )
             gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
             lsum, asum = lsum + loss, asum + acc
         inv = 1.0 / accum_steps
         grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
-        return lsum * inv, asum * inv, grads, bs, a_c, g_s, {}
+        # no bank tape: one microbatch's rows do not make the summed gradient
+        return lsum * inv, asum * inv, grads, bs, a_c, g_s, {}, None
 
     @phase("model")
     def accum_loss_and_grads_all_stats(params, batch_stats, images, labels):
@@ -540,7 +577,7 @@ def make_train_step(
         def body(carry, xs):
             bs, gsum, lsum, asum, a_sum, g_sum = carry
             im, lb = xs
-            loss, acc, grads, new_bs, a_c, g_s, _ = loss_and_grads_captured(
+            loss, acc, grads, new_bs, a_c, g_s, *_ = loss_and_grads_captured(
                 params, bs, im, lb
             )
             gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
@@ -565,7 +602,7 @@ def make_train_step(
         with phase("kfac_capture"):
             a_c = jax.tree_util.tree_map(lambda a: a * inv, a_sum)
             g_s = jax.tree_util.tree_map(lambda g: g * inv, g_sum)
-        return lsum * inv, asum * inv, grads, bs, a_c, g_s, {}
+        return lsum * inv, asum * inv, grads, bs, a_c, g_s, {}, None
 
     def train_step(
         state: TrainState,
@@ -596,7 +633,7 @@ def make_train_step(
                 return loss_and_grads_captured(
                     params, batch_stats, images, labels
                 )
-            return loss_and_grads_plain(params, batch_stats, images, labels)
+            return loss_and_grads_plain(params, batch_stats, images, labels, taped=True)
 
         use_wrapper = (
             (grad_comm_dtype is not None or comm_active)
@@ -604,7 +641,7 @@ def make_train_step(
             and mesh.devices.size > 1
         )
         if use_wrapper:
-            loss, acc, grads, new_bs, a_c, g_s, scalars = _compressed_grads(
+            loss, acc, grads, new_bs, a_c, g_s, scalars, tape = _compressed_grads(
                 _compute,
                 mesh,
                 grad_comm_dtype if grad_comm_dtype is not None else jnp.float32,
@@ -612,15 +649,17 @@ def make_train_step(
                 factor_comm,
             )(state.params, state.batch_stats, images, labels)
         else:
-            loss, acc, grads, new_bs, a_c, g_s, scalars = _compute(
+            loss, acc, grads, new_bs, a_c, g_s, scalars, tape = _compute(
                 state.params, state.batch_stats, images, labels
             )
 
+        grad_scale = None
         if grad_clip:
             # between grad averaging and preconditioning, the reference's
             # clip point (pytorch_wikitext_rnn.py:297-300)
             with phase("grad_clip"):
-                grads = clip_by_global_norm(grads, grad_clip)
+                grad_scale = clip_scale(grads, grad_clip)
+                grads = jax.tree_util.tree_map(lambda g: g * grad_scale, grads)
 
         kfac_state = state.kfac_state
         if kfac is not None:
@@ -629,6 +668,8 @@ def make_train_step(
                 kfac_state,
                 a_contribs=a_c,
                 g_factor_stats=g_s,
+                bank_tape=tape,
+                grad_scale=grad_scale,
                 lr=lr,
                 damping=damping,
                 update_factors=update_factors,

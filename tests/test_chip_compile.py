@@ -227,3 +227,36 @@ def test_head_and_closed_form_loss_read_the_logits_once(one_chip):
             else "other"
         )
     assert sorted(kinds) == ["product", "product", "product", "reduction"], kinds
+
+
+def test_bank_preconditioned_from_its_rows_reads_the_tables_in_place(one_chip, monkeypatch):
+    """ops/precondition.py::precondition_bank_rows at GLM-4.7-Flash's gate
+    bank (8 experts, 2048 -> 1536, 8192 routed rows): three Mosaic calls (the
+    rows times iA, the cotangents times iG, one grouped outer product) at
+    float32, the kernels' nearest to the apply's `high`; the inverses read
+    where they lie in their tables (no slice of a table is copied out) and no
+    temporary above the three products' own outputs."""
+    from jax import lax
+
+    from kfac_pytorch_tpu.ops import grouped
+
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compile, not interpret
+    rows, a, m, e = 8192, 2048, 1536, 8
+    assert grouped._use_kernels(rows)
+    layout = {"iA": (a, 40, e), "iG": (m, 24, e)}
+
+    def fn(x, dy, sizes, table_a, table_g):
+        return precond_ops.precondition_bank_rows(
+            x, dy, sizes, {str(a): table_a, str(m): table_g}, layout, lax.Precision.HIGH)
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((rows, a), jnp.float32), ((rows, m), jnp.float32), ((e,), jnp.int32),
+        ((80, a, a), jnp.float32), ((64, m, m), jnp.float32))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("\nENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 3
+    assert f"f32[{e},{a},{a}]" not in hlo and f"f32[{e},{m},{m}]" not in hlo
+    outputs = 4 * rows * (a + m) + 4 * e * a * m
+    assert compiled.memory_analysis().temp_size_in_bytes < outputs + 8 * 2**20
