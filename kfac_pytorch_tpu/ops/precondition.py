@@ -796,30 +796,118 @@ def precondition_all_owner(
 #     v  = iG · grad · iA                       (2 matmuls per step)
 #
 # Per-step FLOPs and curvature-state HBM traffic HALVE vs the eigenbasis
-# path (docs/PERF.md), and the amortized inverse computation is a Cholesky
-# solve (~n³/3) instead of an eigendecomposition (~10n³). The tradeoffs:
+# path (docs/PERF.md), and the amortized inverse computation is one sweep of
+# matrix products (n³ multiply-adds, below) instead of an eigendecomposition
+# (~10n³). The tradeoffs:
 # (G ⊗ A + λ·I)⁻¹ is approximated by the factored damping, and a damping
 # schedule only takes effect at the next curvature refresh (the eigen path
 # applies λ fresh every step). Opt-in via KFAC(precond_method="inverse").
 # ---------------------------------------------------------------------------
 
 
-def _spd_inverse_stack(stack: jnp.ndarray) -> jnp.ndarray:
-    """Batched SPD inverse via Cholesky: ``[k, n, n] -> [k, n, n]``.
+# Pivot width of the refresh's sweep. A pivot step reads and writes the whole
+# stack (8·n² bytes a matrix) and multiplies n²·b at float32, six bf16 passes:
+# on a v5e (197 TFLOP/s, 819 GB/s) it is bound by memory below b ≈ 160, so
+# 256 is the least multiple of the 128-wide matrix unit that is bound by
+# arithmetic. A side of 256 or less is one pivot: a Cholesky of the whole. A
+# remainder is a narrower last pivot: joined to the pivot before it, it took
+# the temporaries of a [12, 3073, 3073] sweep compiled for a v5e from 0.95 GB
+# to 4.85 GB.
+SWEEP_BLOCK = 256
 
-    Runs under f32 matmul precision — bf16 dots inside the decomposition
-    corrupt the inverse the same way they corrupt eigenvectors (ops/eigh.py).
-    """
+# Trace-time counts behind the gauges of this module since the last
+# :func:`reset_tallies` (the precedent is ops/factors.py::_TALLY): banks
+# preconditioned from their routed rows, and serial pivot steps of the refresh.
+_TALLY = {"routed": 0, "pivots": 0}
+
+
+def reset_tallies() -> None:
+    """Start the counts behind ``kfac/apply_bank_routed`` and
+    ``kfac/refresh_sweep_pivots`` anew, at 0. The step builders call it where
+    a step program starts to trace, so the gauges describe that program (0 in
+    one that preconditions no bank by rows, or refreshes nothing)."""
+    _TALLY.update(routed=0, pivots=0)
+    get_telemetry().set_gauge("kfac/apply_bank_routed", 0)
+    get_telemetry().set_gauge("kfac/refresh_sweep_pivots", 0)
+
+
+def _count_pivots(side: int, sweeps: int = 1) -> None:
+    """Add ``sweeps`` inversions of side ``side`` to the refresh's pivot count."""
+    _TALLY["pivots"] += sweeps * -(-side // SWEEP_BLOCK)
+    get_telemetry().set_gauge("kfac/refresh_sweep_pivots", _TALLY["pivots"])
+
+
+def _cholesky_inverse(stack: jnp.ndarray) -> jnp.ndarray:
+    """Batched SPD inverse by a Cholesky and two triangular solves against the
+    identity: ``[k, n, n] -> [k, n, n]``, not symmetrised."""
     k, n, _ = stack.shape
     eye = jnp.broadcast_to(jnp.eye(n, dtype=stack.dtype), (k, n, n))
+    chol = lax.linalg.cholesky(stack)
+    y = lax.linalg.triangular_solve(chol, eye, left_side=True, lower=True)
+    return lax.linalg.triangular_solve(
+        chol, y, left_side=True, lower=True, transpose_a=True
+    )
+
+
+def _sweep_pivot(m: jnp.ndarray, lo, b: int) -> jnp.ndarray:
+    """One Gauss-Jordan pivot step over rows and columns ``lo .. lo + b`` of
+    the stack ``m`` ``[k, n, n]`` whose rows and columns before ``lo`` are
+    swept, as one rank-b update of the whole. With ``P = M[k,k]⁻¹`` the step
+    sets ``M[k,:] ← P·M[k,:]``, ``M[:,k] ← −M[:,k]·P``, ``M[k,k] ← P`` and
+    ``M[i,j] ← M[i,j] − M[i,k]·P·M[k,j]`` elsewhere, which is
+    ``M − (M[:,k] − E)·P·(M[k,:] + Eᵀ)`` with ``E`` the identity's columns
+    ``lo .. lo + b``.
+
+    The pivot is a Schur complement of an SPD matrix, so SPD itself:
+    ``M[k,k] = L·Lᵀ`` by a Cholesky of side b, and ``P = L⁻ᵀ·L⁻¹`` is split
+    between the two factors, as a Cholesky forms the Schur complement (with
+    ``P`` whole the complement loses a factor of the pivot's condition number:
+    NaN at 1e6 in float32). The sweep keeps the rows of ``M[:,k]`` the
+    negated (swept) or plain (not yet swept) transposes of ``M[k,:]``'s
+    columns, so the left factor is the right one transposed, its swept rows
+    negated and its pivot block ``(M[k,k] − I)·L⁻ᵀ = L − L⁻ᵀ``: the column
+    panel is never read."""
+    n = m.shape[-1]
+    eye = jnp.eye(b, dtype=m.dtype)
+    rows = lax.dynamic_slice_in_dim(m, lo, b, axis=1)
+    pivot = lax.dynamic_slice_in_dim(rows, lo, b, axis=2)
+    # its lower triangle alone: symmetrising it reads the stack in a second
+    # layout, and the compiler then copies the whole stack twice a pivot
+    chol = lax.linalg.cholesky(pivot, symmetrize_input=False)
+    chol_inv = lax.linalg.triangular_solve(
+        chol, jnp.broadcast_to(eye, pivot.shape), left_side=True, lower=True
+    )
+    right = chol_inv @ lax.dynamic_update_slice_in_dim(rows, pivot + eye, lo, axis=2)
+    sign = jnp.where(jnp.arange(n) < lo, -1.0, 1.0).astype(m.dtype)
+    left = jnp.swapaxes(right, -1, -2) * sign[:, None]
+    left = lax.dynamic_update_slice_in_dim(
+        left, chol - jnp.swapaxes(chol_inv, -1, -2), lo, axis=1
+    )
+    return m - left @ right
+
+
+def _spd_inverse_stack(stack: jnp.ndarray) -> jnp.ndarray:
+    """Batched SPD inverse: ``[k, n, n] -> [k, n, n]``, by an in-place sweep
+    of ``SWEEP_BLOCK``-wide pivots (:func:`_sweep_pivot`; a narrower last one
+    where the width does not divide the side), n³ multiply-adds a matrix in
+    batched products; one Cholesky of the whole where ``n <= SWEEP_BLOCK``.
+
+    Every product runs at float32 (six bf16 passes): bf16 dots corrupt the
+    inverse the same way they corrupt eigenvectors (ops/eigh.py).
+    """
+    n = stack.shape[-1]
+    full, rest = divmod(n, SWEEP_BLOCK)
     with jax.default_matmul_precision("float32"):
-        chol = lax.linalg.cholesky(stack)
-        y = lax.linalg.triangular_solve(
-            chol, eye, left_side=True, lower=True
-        )
-        inv = lax.linalg.triangular_solve(
-            chol, y, left_side=True, lower=True, transpose_a=True
-        )
+        if n <= SWEEP_BLOCK:
+            inv = _cholesky_inverse(stack)
+        else:
+            inv = lax.fori_loop(
+                0, full,
+                lambda j, m: _sweep_pivot(m, j * SWEEP_BLOCK, SWEEP_BLOCK),
+                stack,
+            )
+            if rest:
+                inv = _sweep_pivot(inv, full * SWEEP_BLOCK, rest)
     return 0.5 * (inv + jnp.swapaxes(inv, -1, -2))
 
 
@@ -830,7 +918,7 @@ def factored_inverse_all(
 ) -> Dict[str, Dict[str, jnp.ndarray]]:
     """``{layer: {'A', 'G'}} -> {layer: {'iA', 'iG'}}`` with π-corrected
     factored Tikhonov damping (see module comment above). Same-side factors
-    batch into one Cholesky inverse each (exact-shape grouping, like
+    batch into one sweep each (exact-shape grouping, like
     :func:`precondition_all`'s matmul batching)."""
     names = list(factors)
     sqrt_l = jnp.sqrt(damping.astype(jnp.float32))
@@ -851,7 +939,7 @@ def factored_inverse_all(
     out: Dict[str, Dict[str, jnp.ndarray]] = {n: {} for n in names}
     for n in names:
         if "A_diag" in factors[n]:
-            # diagonal A inverts elementwise; only G needs the Cholesky batch
+            # diagonal A inverts elementwise; only G needs the sweep
             out[n]["iA_diag"] = 1.0 / (
                 factors[n]["A_diag"].astype(jnp.float32) + pis[n] * sqrt_l
             )
@@ -867,6 +955,7 @@ def factored_inverse_all(
         )
         eye = jnp.eye(side, dtype=jnp.float32)
         inv = _spd_inverse_stack(stack + damps[:, None, None] * eye)
+        _count_pivots(side)
         for row, (n, f) in enumerate(batch):
             out[n]["iA" if f == "A" else "iG"] = inv[row]
     return out
@@ -882,10 +971,13 @@ def factored_inverse_all(
 # ---------------------------------------------------------------------------
 
 
-# Bytes of float32 matrices that one Cholesky batch of a table's refresh holds
-# (the compiler keeps some seven times that while it inverts a batch): 4
-# factors of side 2048, 7 of side 1536.
-TABLE_BATCH_BYTES = 64 * 2**20
+# Bytes of float32 matrices that one batch of a table's refresh holds: an
+# eighth of a v5e's 16 GiB. The sweep works in place, so a batch costs about
+# its own bytes in temporaries where it is the whole table and twice its bytes
+# beside the rest of the table (described-v5e compiles, PERF.md): a batch
+# holds at most a quarter of the chip in temporaries, where a Cholesky batch
+# cost some eight times its bytes.
+TABLE_BATCH_BYTES = 2 * 2**30
 
 
 def _batches(count: int, side: int) -> Tuple[int, int]:
@@ -941,7 +1033,7 @@ def factored_inverse_tables(
 ) -> Dict[str, jnp.ndarray]:
     """The refresh of :func:`factored_inverse_all` into the inverse tables
     ``{str(side): [K, side, side]}``, each written where it lies: a loop over
-    the table's batches (one batch's Cholesky program), each step damping its
+    the table's batches (one batch's sweep), each step damping its
     batch's factors (``lax.switch`` over the batches: which factors those
     are is static) and writing their inverses over the table's old rows, so
     that no second copy of a side's factors exists. A bank is damped (its own
@@ -983,9 +1075,7 @@ def factored_inverse_tables(
                 parts.append(jnp.broadcast_to(eye, (pad, side, side)))
             return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
-        if batches == 1:
-            out[str(side)] = _spd_inverse_stack(damped(0))
-            continue
+        _count_pivots(side, batches)
         branches = [partial(damped, j * per) for j in range(batches)]
 
         def body(j, table, branches=branches, per=per):
@@ -1012,19 +1102,6 @@ def precondition_all_inv_tables(
             inv[key] = rows_of if g.ndim == 3 else rows_of[0]
         out[name] = precondition_mat_inv(g, inv["iA"], inv["iG"], precision)
     return out
-
-
-# Trace-time count of the banks preconditioned from their routed rows since
-# the last :func:`reset_apply_tally` (the precedent is ops/factors.py::_TALLY).
-_TALLY = {"routed": 0}
-
-
-def reset_apply_tally() -> None:
-    """Start the count behind ``kfac/apply_bank_routed`` anew, at 0. The step
-    builders call it where a step program starts to trace, so the gauge
-    describes that program (0 in one that preconditions no bank by rows)."""
-    _TALLY["routed"] = 0
-    get_telemetry().set_gauge("kfac/apply_bank_routed", 0)
 
 
 def bank_rows_pay(rows: int, experts: int, a: int, m: int) -> bool:

@@ -327,9 +327,10 @@ RULES: Tuple[Rule, ...] = (
         drop=("eigh_chunks",),
         enforced_by="constructor",
         message="eigh_chunks > 1 pipelines the eigendecomposition refresh; "
-                "precond_method='inverse' refreshes via one batched Cholesky "
-                "~30x cheaper than the eigh it replaces — there is no spike to "
-                "spread, so refusing a config that implies one",
+                "precond_method='inverse' refreshes by one blocked sweep of "
+                "matrix products, about a tenth of the eigh's arithmetic — "
+                "there is no spike to spread, so refusing a config that "
+                "implies one",
     ),
     Rule(
         name="rsvd_vs_inverse",
@@ -339,7 +340,7 @@ RULES: Tuple[Rule, ...] = (
         enforced_by="constructor",
         message="a truncating solver ('rsvd'/'streaming') produces a truncated "
                 "eigenbasis consumed by the eigenbasis (Woodbury) apply path; "
-                "precond_method='inverse' preconditions with explicit Cholesky "
+                "precond_method='inverse' preconditions with explicit "
                 "inverses and would silently ignore the configured solver",
     ),
     Rule(
@@ -360,7 +361,7 @@ RULES: Tuple[Rule, ...] = (
         drop=("factor_sharding",),
         enforced_by="constructor",
         message="factor_sharding='owner' shards the eigenbasis state; "
-                "precond_method='inverse' keeps explicit Cholesky inverses that "
+                "precond_method='inverse' keeps explicit inverses that "
                 "this mode does not lay out — use the eigen method or "
                 "replicated sharding",
     ),
@@ -502,8 +503,8 @@ RULES: Tuple[Rule, ...] = (
         enforced_by="constructor",
         message="service_devices > 0 publishes factor snapshots to workers "
                 "that refresh an EIGENBASIS; precond_method='inverse' refreshes "
-                "~30x-cheaper Cholesky inverses in-step — there is no refresh "
-                "spike worth a carve",
+                "inverses in-step at about a tenth of the eigh's arithmetic — "
+                "there is no refresh spike worth a carve",
     ),
     Rule(
         name="service_vs_streaming",
@@ -569,7 +570,7 @@ RULES: Tuple[Rule, ...] = (
         enforced_by="constructor",
         message="shard-lens layers and MoE expert banks precondition per shard "
                 "block in the eigenbasis (shardwise.precondition); "
-                "precond_method='inverse' keeps whole-factor Cholesky inverses "
+                "precond_method='inverse' keeps whole-factor inverses "
                 "with no per-block layout — use the eigen method",
     ),
     Rule(

@@ -318,10 +318,11 @@ class KFAC:
         self.eigen_dtype = eigen_dtype
         # "eigen" (reference parity: exact (G⊗A+λI)⁻¹ in the eigenbasis,
         # damping fresh every step, 4 rotations/layer) or "inverse"
-        # (π-corrected factored Tikhonov damping + explicit Cholesky
-        # inverses: 2 matmuls/layer per step, half the curvature HBM
-        # stream, ~30x cheaper refresh; damping takes effect at the next
-        # refresh). See ops/precondition.py's inverse-method comment.
+        # (π-corrected factored Tikhonov damping + explicit inverses by a
+        # sweep of matrix products: 2 matmuls/layer per step, half the
+        # curvature HBM stream, ~10x less refresh arithmetic; damping takes
+        # effect at the next refresh). See ops/precondition.py's
+        # inverse-method comment.
         _validate(
             "precond_method", precond_method in ("eigen", "inverse"), precond_method
         )
@@ -1656,9 +1657,10 @@ class KFAC:
                 )
 
         if update_eigen and self.precond_method == "inverse":
-            # Curvature refresh, inverse method: π-damped Cholesky inverses.
-            # Computed replicated — a batched Cholesky solve is ~30x cheaper
-            # than the eigendecompositions (n³/3 vs ~10n³ per factor), so at
+            # Curvature refresh, inverse method: π-damped inverses by a
+            # blocked sweep of matrix products (ops/precondition.py::
+            # _spd_inverse_stack). Computed replicated — n³ multiply-adds a
+            # factor against the eigendecompositions' ~10n³, so at
             # kfac_update_freq amortization sharding it is not worth an
             # exchange; the EVERY-STEP solve still shards via
             # distribute_precondition.

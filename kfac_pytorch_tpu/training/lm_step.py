@@ -22,6 +22,7 @@ from kfac_pytorch_tpu.models.layers import KFAC_ACTS, PERTURBATIONS
 from kfac_pytorch_tpu.observability.diagnostics import diagnostic_metrics
 from kfac_pytorch_tpu.observability.phases import phase
 from kfac_pytorch_tpu.ops import factor_kernels, factors, flash_attention
+from kfac_pytorch_tpu.ops import precondition as precond_ops
 from kfac_pytorch_tpu.preconditioner import KFAC
 from kfac_pytorch_tpu.training.step import (
     TrainState,
@@ -79,6 +80,7 @@ def make_lm_train_step(
                 return _compute_captured(params, tokens, targets, carry, rngs)
         flash_attention.reset_flash_tally()  # the gauges count this program's kernels
         reset_loss_tally()  # and its losses
+        precond_ops.reset_tallies()  # and its refresh
 
         def loss_fn(params):
             logits, new_carry = model.apply(
@@ -98,6 +100,7 @@ def make_lm_train_step(
         factors.reset_capture_tally()  # the gauges count this program's products
         flash_attention.reset_flash_tally()  # and its attention kernels
         reset_loss_tally()  # and its losses
+        precond_ops.reset_tallies()  # and its refresh
 
         def loss_fn(params, perts):
             (logits, new_carry), mut = model.apply(

@@ -436,7 +436,7 @@ def make_train_step(
         factors.reset_capture_tally()  # the gauges count this program's products
         flash_attention.reset_flash_tally()  # and its attention kernels
         reset_loss_tally()  # and its losses
-        precond_ops.reset_apply_tally()  # and its banks preconditioned by rows
+        precond_ops.reset_tallies()  # and its banks preconditioned by rows, its refresh
         has_bn = bool(batch_stats)
         mutable = (["batch_stats"] if has_bn else []) + [KFAC_ACTS, KFAC_TAPE, STEP_SCALARS]
 
@@ -488,7 +488,7 @@ def make_train_step(
     def loss_and_grads_plain(params, batch_stats, images, labels, taped=False):
         flash_attention.reset_flash_tally()  # the gauges count this program's kernels
         reset_loss_tally()  # and its losses
-        precond_ops.reset_apply_tally()  # and its banks preconditioned by rows
+        precond_ops.reset_tallies()  # and its banks preconditioned by rows, its refresh
         has_bn = bool(batch_stats)
         mutable = (["batch_stats"] if has_bn else []) + [STEP_SCALARS]
         # ``taped``: the banks' tape (capture.bank_tape), read through

@@ -1,7 +1,8 @@
 """The trace-time gauges of a benchmark cell's step programs, at the cell's
 own size: each program is built by the cell's builder and traced (not
 lowered, not compiled) with telemetry on, and the gauges it set are printed,
-one JSON line a program (``kfac/apply_bank_routed``, ``kfac/capture_*``,
+one JSON line a program (``kfac/apply_bank_routed``,
+``kfac/refresh_sweep_pivots``, ``kfac/capture_*``,
 ``attention/flash_*``, ``loss/closed_form_calls``; docs/OBSERVABILITY.md).
 Tracing at full size takes host memory: run it through the chip tool.
 

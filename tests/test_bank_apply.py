@@ -223,7 +223,8 @@ def test_a_step_takes_the_same_parameters_either_way(grad_clip, monkeypatch):
 ])
 def test_the_gauge_script_reads_each_program_of_a_cell(cell, benchmark, want, capsys):
     """scripts/program_gauges.py, which reads the gauges of a cell's programs
-    at full size through the chip tool, at the benchmark's tiny cells."""
+    at full size on a TPU, at the benchmark's tiny cells:
+    ``kfac/apply_bank_routed`` and ``kfac/refresh_sweep_pivots``."""
     import importlib.util
     import json
     import os
@@ -242,6 +243,11 @@ def test_the_gauge_script_reads_each_program_of_a_cell(cell, benchmark, want, ca
     lines = [json.loads(l) for l in capsys.readouterr().out.splitlines() if l.startswith("{")]
     assert {l["program"]: l["gauges"]["kfac/apply_bank_routed"] for l in lines} == want
     assert all(l["gauges"]["loss/closed_form_calls"] == 1 for l in lines)
+    # the refresh's serial pivot steps: one a table (sides up to 64) or a stack
+    # (the tiny LM's six sides), in the refresh program alone
+    pivots = {"refresh": 4 if cell == "glm_tiny_t64" else 6}
+    assert {l["program"]: l["gauges"]["kfac/refresh_sweep_pivots"] for l in lines} == {
+        p: pivots.get(p, 0) for p in want}
 
 
 def test_a_data_parallel_step_takes_the_same_parameters_either_way(monkeypatch):

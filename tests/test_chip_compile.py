@@ -260,3 +260,39 @@ def test_bank_preconditioned_from_its_rows_reads_the_tables_in_place(one_chip, m
     assert f"f32[{e},{a},{a}]" not in hlo and f"f32[{e},{m},{m}]" not in hlo
     outputs = 4 * rows * (a + m) + 4 * e * a * m
     assert compiled.memory_analysis().temp_size_in_bytes < outputs + 8 * 2**20
+
+
+def _computations(hlo):
+    """``{name: body}`` of every computation in a compiled module's text."""
+    return dict(re.findall(r"\n(?:ENTRY )?%?([\w.-]+) [^\n]*\{\n(.*?)\n\}", hlo, re.S))
+
+
+def _reached(comps, roots):
+    """The computations that ``roots`` call, directly or through others."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += re.findall(r"(?:calls|body|condition|to_apply)=%?([\w.-]+)", comps[name])
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", comps[name]):
+            todo += re.findall(r"%?([\w.-]+)", group)
+    return seen
+
+
+@pytest.mark.parametrize("k,n", [(4, 1024), (4, 769)])
+def test_the_refresh_sweep_copies_no_stack_inside_its_loop(one_chip, k, n):
+    """ops/precondition.py::_spd_inverse_stack, the refresh's in-place sweep:
+    nothing that its loop runs copies the ``[k, n, n]`` stack. A pivot whose
+    Cholesky symmetrised its input read the stack in a second layout, and the
+    compiler then copied the whole stack twice a pivot, which ate the sweep's
+    gain on the chip (PERF.md, section 6, PR 38)."""
+    hlo = _compile(precond_ops._spd_inverse_stack, one_chip, _f32(k, n, n))
+    comps = _computations(hlo)
+    loops = _reached(comps, re.findall(r" while\([^\n]*body=%?([\w.-]+)", hlo))
+    assert loops
+    stack = f"f32[{k},{n},{n}]"
+    copies = [line for name in loops for line in comps[name].splitlines()
+              if re.search(r" copy(-start)?\(", line) and stack in line.split(" copy")[0]]
+    assert not copies, copies

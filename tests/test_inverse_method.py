@@ -1,7 +1,8 @@
 """Inverse-method preconditioning (precond_method="inverse").
 
 Validates the π-corrected factored-Tikhonov inverses against explicit numpy
-linear algebra, the 2-matmul solve against per-layer math (stacked and
+linear algebra, the refresh's blocked sweep against float64 inverses and the
+Cholesky path it replaced, the 2-matmul solve against per-layer math (stacked and
 unstacked layouts), the end-to-end KFAC.update pipeline against a numpy
 replay, and distributed == replicated on the 8-device CPU mesh.
 """
@@ -9,8 +10,10 @@ replay, and distributed == replicated on the 8-device CPU mesh.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from kfac_pytorch_tpu import KFAC
+from kfac_pytorch_tpu.observability.telemetry import configure, get_telemetry
 from kfac_pytorch_tpu.ops import precondition as P
 from kfac_pytorch_tpu.parallel.mesh import data_parallel_mesh
 
@@ -54,6 +57,98 @@ def test_factored_inverse_matches_numpy():
                                    rtol=1e-4, atol=1e-5)
         np.testing.assert_allclose(np.asarray(inv[n]["iG"]), ref[n]["iG"],
                                    rtol=1e-4, atol=1e-5)
+
+
+def _damped_factors(k, n, cond, seed):
+    """``k`` Gram factors of rank n/2 scaled to a largest eigenvalue of 1 and
+    damped by 1/cond: SPD with a condition number of about ``cond``."""
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((k, n, n // 2))
+    g = x @ x.transpose(0, 2, 1)
+    v = np.ones((k, n, 1))
+    for _ in range(30):  # the largest eigenvalue, by power iteration
+        v = g @ v
+        v /= np.linalg.norm(v, axis=1, keepdims=True)
+    g /= (v.transpose(0, 2, 1) @ g @ v)
+    return g + np.eye(n) / cond
+
+
+def _cholesky_path(stack):
+    """The refresh's inverse before the sweep: a Cholesky of side n and two
+    triangular solves against the identity, at float32, symmetrised."""
+    with jax.default_matmul_precision("float32"):
+        inv = P._cholesky_inverse(stack)
+    return 0.5 * (inv + jnp.swapaxes(inv, -1, -2))
+
+
+@pytest.mark.parametrize("cond", [1e2, 1e4, 1e6])
+@pytest.mark.parametrize("side", [64, 256, 257, 576, 767, 769, 1025])
+def test_the_sweep_inverts_as_the_cholesky_path_does(side, cond):
+    """One pivot (64, 256), a ragged last pivot of 1 (257, 769, 1025), 64
+    (576) or 255 (767) after 256-wide ones: against a float64 inverse of the same float32
+    input, a relative error and a residual ‖M·X − I‖ within 4x the Cholesky
+    path's and the error within 4x its bound κ·u; symmetric."""
+    m = jnp.asarray(_damped_factors(2, side, cond, side), jnp.float32)
+    exact = np.asarray(m, np.float64)
+    got = np.asarray(jax.jit(P._spd_inverse_stack)(m), np.float64)
+    chol = np.asarray(jax.jit(_cholesky_path)(m), np.float64)
+    want = np.linalg.inv(exact)
+    residual = lambda x: np.linalg.norm(exact @ x - np.eye(side), axis=(1, 2)).max()
+    error = lambda x: (np.linalg.norm(x - want, axis=(1, 2)) / np.linalg.norm(want, axis=(1, 2))).max()
+    assert residual(got) <= 4 * residual(chol), (residual(got), residual(chol))
+    assert error(got) <= 4 * error(chol), (error(got), error(chol))
+    assert error(got) < 4 * cond * 2.0 ** -24, error(got)
+    np.testing.assert_array_equal(got, got.transpose(0, 2, 1))
+
+
+def _pivots_traced(fn, *args):
+    """``kfac/refresh_sweep_pivots`` after tracing ``fn`` (no compile, no run)."""
+    tel = get_telemetry()
+    was = tel.enabled
+    try:
+        configure(enabled=True)
+        P.reset_tallies()
+        jax.eval_shape(fn, *args)
+        return tel.gauges.get("kfac/refresh_sweep_pivots")
+    finally:
+        configure(enabled=was)
+        tel.reset()
+
+
+def test_the_pivot_gauge_counts_gpt2_small_refresh():
+    """GPT-2 small's five stacks, sides 769 x36, 3073 x12, 2304 x12, 768 x24,
+    3072 x12: 4 + 13 + 9 + 3 + 12 serial pivot steps of 256."""
+    f = lambda a, g: {"A": jax.ShapeDtypeStruct((a, a), jnp.float32),
+                      "G": jax.ShapeDtypeStruct((g, g), jnp.float32)}
+    facs = {}
+    for i in range(12):
+        facs.update({f"h{i}/qkv": f(769, 2304), f"h{i}/proj": f(769, 768),
+                     f"h{i}/fc": f(769, 3072), f"h{i}/fc2": f(3073, 768)})
+    damping = jax.ShapeDtypeStruct((), jnp.float32)
+    assert _pivots_traced(P.factored_inverse_all, facs, damping) == 41
+
+
+@pytest.mark.parametrize("per", [1, 2, 5])
+def test_tables_past_one_pivot_equal_one_stack(per, monkeypatch):
+    """The tables' batched refresh at sides the sweep takes in two pivots (300
+    = 256 + 44) equals the inverses of one stack a side; the gauge counts each
+    batch's pivots."""
+    facs = {f"l{i}": {"A": jnp.asarray(_damped_factors(1, 300, 1e3, 10 * per + i)[0], jnp.float32),
+                      "G": jnp.asarray(_damped_factors(1, 40, 1e3, 20 * per + i)[0], jnp.float32)}
+            for i in range(5)}
+    whole = P.factored_inverse_all(facs, jnp.float32(0.003))
+    monkeypatch.setattr(P, "TABLE_BATCH_BYTES", per * 4 * 300 * 300)
+    shapes = {n: {k: tuple(v.shape) for k, v in f.items()} for n, f in facs.items()}
+    layout, rows = P.inverse_table_layout(shapes, {})
+    tables = {str(side): jnp.broadcast_to(jnp.eye(side), (k, side, side)) for side, k in rows.items()}
+    refresh = lambda t: P.factored_inverse_tables(facs, t, jnp.float32(0.003), 1e-10, {}, layout)
+    batches = -(-5 // per)
+    assert _pivots_traced(refresh, tables) == 2 * batches + 1
+    tables = refresh(tables)
+    for name, where in layout.items():
+        for key, (side, first, _) in where.items():
+            got, want = tables[str(side)][first], whole[name][key]
+            assert float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want)) < 1e-5, (name, key)
 
 
 def test_precondition_all_inv_stacked_matches_unstacked():
